@@ -49,7 +49,7 @@ smoke:
 # contract gate. Suppressions + baselines/ledgers in jaxlint.toml.
 # Runs on every PR via `make check`.
 LINT_PATHS := deepvision_tpu/ tools/ train.py train_dist.py serve.py \
-              bench.py predict.py evaluate.py
+              bench.py predict.py evaluate.py chip_smoke.py
 lint:
 	$(PY) -m tools.jaxlint $(LINT_PATHS)
 	$(PY) -m tools.jaxlint.evalcheck
@@ -466,7 +466,8 @@ check: lint lint-comms serve-smoke pipeline-smoke router-smoke stream-smoke swap
 bench:
 	$(PY) bench.py
 
-# the driver's multi-chip validation, runnable locally on 8 virtual CPUs
+# CPU sharding check: one full train step over 8 VIRTUAL CPU devices
+# (4x2 data x model mesh). Not a multi-chip run — chip_smoke.py is.
 dryrun:
 	$(PY) -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
 
@@ -494,7 +495,8 @@ convert:
 # synthetic task-metric gates: train to convergence on the hermetic
 # synthetic sets, then score with the real eval metrics (mAP / PCK).
 # Data sizes follow the measured r3/r4 scaling curve (mAP 0.67 @ 1024,
-# 0.856 @ 2048, 0.880 @ 4096, crossed 0.9 @ 8192+flip — EVIDENCE.md);
+# 0.856 @ 2048, 0.880 @ 4096, crossed 0.9 @ 8192+flip; record removed
+# in PR 21);
 # --keep-best retains the val-loss-ranked checkpoints so the peak epoch
 # can be scored with `evaluate.py --epoch` after the overfit knee
 # every gate tees train + eval into ONE timestamped file under logs/
@@ -519,7 +521,7 @@ gate_detection_16384:
 # evaluate.py's exact masked full-set eval. --num-classes 5: the
 # synthetic class signal aliases past 7 classes (data/synthetic.py)
 # MODEL=resnet50 runs the same recipe on the north-star architecture
-# (both scored held-out top-1 1.0 on-chip, EVIDENCE.md r5)
+# (both scored held-out top-1 1.0; record removed in PR 21)
 gate_classification: MODEL ?= resnet34
 gate_classification:
 	@mkdir -p logs; L="logs/gate_classification_$(MODEL)-$$(date +%Y-%m-%d-%H-%M-%S).log"; \
@@ -530,7 +532,7 @@ gate_classification:
 		--synthetic-size 4096 --train-batch-size 64 \
 		--workdir $(WORKDIR)/gates/$(MODEL) 2>&1 | tee -a "$$L"
 
-# two-phase recipe from EVIDENCE.md r4: the plateau scheduler never
+# two-phase recipe from round 4: the plateau scheduler never
 # fires on this task (val micro-improves each epoch), so the CenterNet-
 # paper x10 lr drop is applied manually via resume
 gate_centernet:
@@ -560,7 +562,7 @@ gate_gan:
 # assignment j%3 is ambiguous and no model can score high PCK.
 # 1024 images + lr 1e-3: 256 images generalization-capped PCK at ~0.5
 # (37% gross misses on held-out draws) and the config lr of 1e-4
-# converged 5x slower (EVIDENCE.md r4)
+# converged 5x slower (round 4)
 gate_pose:
 	@mkdir -p logs; L="logs/gate_pose-$$(date +%Y-%m-%d-%H-%M-%S).log"; \
 	$(PY) train.py -m hourglass104 --num-joints 3 --epochs 120 \

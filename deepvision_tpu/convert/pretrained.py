@@ -18,12 +18,7 @@ from pathlib import Path
 
 def file_digest(path: str | Path, algorithm: str = "sha256") -> str:
     with open(path, "rb") as fh:
-        if hasattr(hashlib, "file_digest"):  # python >= 3.11
-            return hashlib.file_digest(fh, algorithm).hexdigest()
-        h = hashlib.new(algorithm)
-        for chunk in iter(lambda: fh.read(1 << 20), b""):
-            h.update(chunk)
-        return h.hexdigest()
+        return hashlib.file_digest(fh, algorithm).hexdigest()
 
 
 def verify_artifact(

@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import os
 import re
+import tomllib
 from dataclasses import dataclass
 from pathlib import Path
 from typing import Any, Iterable, Sequence
@@ -42,8 +43,6 @@ from typing import Any, Iterable, Sequence
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from deepvision_tpu.minitoml import loads_toml
 
 # env override for where the rule table lives (tests, exported bundles);
 # default search: explicit arg > env > repo root (package-relative) > cwd
@@ -101,7 +100,7 @@ def load_partition_rules(path: str | Path | None = None
     silently falling back to all-replicated would un-declare every
     sharding decision the table exists to declare."""
     p = _find_rule_table(path)
-    data = loads_toml(p.read_text())
+    data = tomllib.loads(p.read_text())
     entries = data.get("shardcheck", {}).get("rule", [])
     if not entries:
         raise RuleError(

@@ -14,8 +14,29 @@ with the sum over a window of ``n`` channels centered at ``c``.
 
 from __future__ import annotations
 
+import sys
+
 import jax
 import jax.numpy as jnp
+
+
+def select_lrn_impl(backend: str, device_count: int) -> tuple[str, str]:
+    """-> (implementation, reason) for the default dispatch. The fused
+    Pallas kernel (ops/lrn_pallas.py — one VMEM-resident pass instead
+    of XLA's reduce_window + elementwise chain) runs on a single-device
+    TPU backend only; it compiled natively and matched the jnp lowering
+    at every LRN shape of the zoo on a v5e (alexnet1 55x55x96 and
+    27x27x256 at size 5, inception1 56x56x64 at size 64 and 56x56x192
+    at size 192, batch 128, bf16 and f32, forward and gradient; chip
+    run, PR 21), so no shape is excluded."""
+    if backend != "tpu":
+        return "jnp", f"backend is {backend!r}, the kernel is TPU-only"
+    if device_count != 1:
+        return "jnp", (
+            f"{device_count} devices: a bare pallas_call has no "
+            "partitioning rule and would force a gather under a "
+            "sharded jit")
+    return "pallas", "single-device TPU backend"
 
 
 def local_response_norm(
@@ -28,20 +49,18 @@ def local_response_norm(
 ) -> jax.Array:
     """NHWC input; normalizes over the trailing channel axis.
 
-    On a single-device TPU backend this dispatches to the fused Pallas
-    kernel (ops/lrn_pallas.py — one VMEM-resident pass instead of XLA's
-    reduce_window + elementwise chain). Multi-device stays on the jnp
-    lowering: a ``pallas_call`` has no GSPMD partitioning rule, so under
-    a sharded jit it would force a gather. ``impl`` overrides the
-    dispatch ("jnp" | "pallas"); both paths are parity-pinned by
-    tests/test_ops.py.
+    ``impl`` ("jnp" | "pallas") overrides :func:`select_lrn_impl`; left
+    to the default, the choice is announced once per trace, so a
+    four-chip host that runs the jnp lowering says so. Both paths are
+    parity-pinned by tests/test_ops.py.
     """
     if impl is None:
-        impl = (
-            "pallas"
-            if jax.default_backend() == "tpu" and jax.device_count() == 1
-            else "jnp"
-        )
+        impl, why = select_lrn_impl(jax.default_backend(),
+                                    jax.device_count())
+        # deliberately a trace-time print of STATIC facts (shape,
+        # window, backend): once per compilation, never per step
+        print(f"[lrn] {tuple(x.shape)} size={size}: {impl} ({why})",  # jaxlint: disable=JX106
+              file=sys.stderr, flush=True)
     if impl == "pallas":
         from deepvision_tpu.ops.lrn_pallas import local_response_norm_pallas
 

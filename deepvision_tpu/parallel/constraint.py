@@ -1,6 +1,6 @@
 """Spatial-sharding guard for thin feature maps.
 
-Round-5 finding (EVIDENCE.md): under GSPMD spatial partitioning (input
+Round-5 finding: under GSPMD spatial partitioning (input
 H sharded over the ``model`` mesh axis), XLA's SPMD partitioner
 miscomputes the BACKWARD of strided-conv → residual-block chains once a
 feature map's H shard thins to a single row — the forward is exact
@@ -69,7 +69,7 @@ def spatial_model_shards() -> int:
 # Minimum H rows per model-axis shard before a map is forced back to
 # data-only sharding. 1-row shards are the proven-broken regime; 2-row
 # shards measured exact in plain chains but NOT in the YOLO FPN's
-# upsample+concat graph (f64 parity harness, EVIDENCE.md r5) — 4 holds
+# upsample+concat graph (f64 parity harness, tests/test_spatial.py) — 4 holds
 # across every architecture tested and doubles as the point where halo
 # overhead stops paying for itself anyway.
 MIN_ROWS_PER_SHARD = 4

@@ -21,29 +21,10 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deepvision_tpu.core.mesh import AXIS_DATA, AXIS_MODEL
-
-# shard_map graduated from jax.experimental.shard_map to jax.shard_map
-# across the jaxlib builds this repo runs on; resolve the newest name
-# first so both work (same env-skew class as tests/conftest.py probes)
-shard_map = getattr(jax, "shard_map", None)
-if shard_map is None:  # pre-graduation jaxlib (e.g. 0.4.x)
-    from jax.experimental.shard_map import shard_map
-
-
-def _axis_size(axis_name: str) -> int:
-    """Static size of the mapped axis: ``lax.axis_size`` where it
-    exists (newer jax), else the constant-folded ``psum(1)`` idiom the
-    older builds document for the same purpose."""
-    size_fn = getattr(lax, "axis_size", None)
-    if size_fn is not None:
-        return int(size_fn(axis_name))
-    # psum of a literal constant-folds at trace time on the builds this
-    # branch serves — static by construction, not a host sync
-    return int(lax.psum(1, axis_name))  # jaxlint: disable=JX101
 
 
 def halo_exchange(x: jax.Array, halo: int, axis_name: str) -> jax.Array:
@@ -56,7 +37,7 @@ def halo_exchange(x: jax.Array, halo: int, axis_name: str) -> jax.Array:
     """
     if halo == 0:  # 1x1 kernels need no neighbor rows
         return x
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     zeros = jnp.zeros_like(x[:, :halo])
     if n == 1:

@@ -47,7 +47,8 @@ import numpy as np
 
 from deepvision_tpu.serve.admission import ShedError
 
-__all__ = ["ReplicaDeadError", "EngineReplica", "ProcessReplica"]
+__all__ = ["ReplicaDeadError", "EngineReplica", "ProcessReplica",
+           "process_replica_factory"]
 
 
 class ReplicaDeadError(RuntimeError):
@@ -179,10 +180,14 @@ class ProcessReplica:
         self._log_path = tmp / "replica.log"
         argv = self._argv + ["--port-file", str(port_file)]
         env = dict(self._env if self._env is not None else os.environ)
-        with open(self._log_path, "wb") as log:
-            self._proc = subprocess.Popen(
-                argv, stdout=log, stderr=subprocess.STDOUT,
-                stdin=subprocess.DEVNULL, env=env)
+        try:
+            with open(self._log_path, "wb") as log:
+                self._proc = subprocess.Popen(
+                    argv, stdout=log, stderr=subprocess.STDOUT,
+                    stdin=subprocess.DEVNULL, env=env)
+        except OSError:
+            self._dead = True  # never ran: nothing it held is held
+            raise
         if self._affinity and hasattr(os, "sched_setaffinity"):
             try:
                 os.sched_setaffinity(self._proc.pid, self._affinity)
@@ -243,6 +248,17 @@ class ProcessReplica:
     @property
     def pid(self) -> int | None:
         return self._proc.pid if self._proc is not None else None
+
+    @property
+    def exited(self) -> bool:
+        """The child process has come and gone (or the replica was
+        stopped before it ever spawned one): whatever it held — a TPU
+        chip, see :func:`process_replica_factory` — is free again. A
+        replica that is created but not yet spawned has NOT exited: its
+        chip is spoken for."""
+        if self._proc is None:
+            return self._dead
+        return self._proc.poll() is not None
 
     # -- HTTP plumbing ---------------------------------------------------
     def _http(self, method: str, path: str, body: str | None = None,
@@ -394,6 +410,51 @@ class ProcessReplica:
             raise RuntimeError(
                 f"{self.replica_id}: /metrics.json HTTP {status}")
         return json.loads(body)
+
+
+def process_replica_factory(make_argv: Callable[[str], list[str]], *,
+                            replicas: int, devices: dict
+                            ) -> Callable[[str], ProcessReplica]:
+    """-> the ``replica_factory(sid)`` a :class:`FleetRouter` over
+    ``serve.py`` children needs, given what a device probe found
+    (``startup.probe_devices()``: the router process itself stays off
+    JAX).
+
+    A TPU chip belongs to one process at a time, so on a TPU host every
+    live child is confined to a chip of its own (``startup.chip_env``);
+    a respawn takes the lowest chip whose previous holder has exited.
+    ``replicas`` — the most children that will ever live at once
+    (``--fleet-max``) — greater than the chips present raises
+    ValueError HERE, at start, instead of a child waiting out its
+    startup timeout on a held chip. Off TPU the children share the
+    host freely and inherit the environment unchanged."""
+    if devices["platform"] != "tpu":
+        return lambda sid: ProcessReplica(sid, make_argv(sid))
+    from deepvision_tpu.startup import chip_env
+
+    chips = devices["count"]
+    chip_env(replicas - 1, chips)  # raises: fewer chips than replicas
+    lock = threading.Lock()  # the router boots replicas on threads
+    holders: dict[int, ProcessReplica] = {}
+
+    def factory(sid: str) -> ProcessReplica:
+        with lock:
+            free = [c for c in range(chips)
+                    if c not in holders or holders[c].exited]
+            if not free:
+                raise ReplicaDeadError(
+                    f"{sid}: all {chips} chip(s) of this host are held "
+                    "by live replicas")
+            chip = free[0]
+            replica = ProcessReplica(
+                sid, make_argv(sid),
+                env={**os.environ, **chip_env(chip, chips)})
+            holders[chip] = replica
+        print(f"[fleet] replica {sid} -> chip {chip} of {chips}",
+              file=sys.stderr, flush=True)
+        return replica
+
+    return factory
 
 
 def replica_argv(model_specs: list[str], *, buckets: str | None = None,

@@ -365,18 +365,6 @@ class CheckpointManager:
         # item is registered with the Standard handler and PyTreeRestore
         # args would be rejected (orbax 0.11 registry semantics).
         mgr = ocp.CheckpointManager(self.directory)
-        # partial_restore landed in orbax 0.11; on older builds the
-        # documented sub-template idiom is transforms={} (keys absent
-        # from the template are dropped instead of raising a Dict key
-        # mismatch). Same semantics, version-gated.
-        import inspect
-
-        partial_kw = (
-            {"partial_restore": True}
-            if "partial_restore" in inspect.signature(
-                ocp.args.PyTreeRestore.__init__).parameters
-            else {"transforms": {}}
-        )
         try:
             restored = mgr.restore(
                 epoch,
@@ -389,7 +377,9 @@ class CheckpointManager:
                         restore_args=ocp.checkpoint_utils.construct_restore_args(
                             template
                         ),
-                        **partial_kw,
+                        # keys absent from the template (opt_state,
+                        # GAN pools) are dropped, not a key mismatch
+                        partial_restore=True,
                     ),
                     meta=ocp.args.JsonRestore(),
                 ),

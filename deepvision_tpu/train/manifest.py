@@ -34,8 +34,7 @@ _tmp_seq = itertools.count()
 
 
 def _hash_file(path: Path) -> str:
-    """Streaming SHA-256 — the repo's ONE implementation (incl. the
-    ``hashlib.file_digest`` fast path on 3.11+)."""
+    """Streaming SHA-256 — the repo's ONE implementation."""
     from deepvision_tpu.convert.pretrained import file_digest
 
     return file_digest(path, "sha256")

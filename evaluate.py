@@ -482,6 +482,9 @@ def main(argv=None):
     sp.set_defaults(fn=cmd_gan)
 
     args = p.parse_args(argv)
+    from deepvision_tpu.startup import init_runtime
+
+    init_runtime()
     args.fn(args)
 
 

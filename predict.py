@@ -368,6 +368,10 @@ def main(argv=None):
     sp.set_defaults(fn=cmd_export)
 
     args = p.parse_args(argv)
+    if args.fn is not cmd_curves:  # curves only re-plots stored metrics
+        from deepvision_tpu.startup import init_runtime
+
+        init_runtime()
     args.fn(args)
 
 

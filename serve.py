@@ -215,8 +215,12 @@ def build_fleet(args):
     """Router front tier over ``args.fleet`` child-process replicas —
     no jax in this process; each replica is this same CLI in
     single-engine HTTP mode on an ephemeral port."""
-    from deepvision_tpu.serve.replica import ProcessReplica, replica_argv
+    from deepvision_tpu.serve.replica import (
+        process_replica_factory,
+        replica_argv,
+    )
     from deepvision_tpu.serve.router import AutoscaleConfig, FleetRouter
+    from deepvision_tpu.startup import probe_devices
 
     if not (args.model or args.artifact or args.track):
         sys.exit("no models: pass -m NAME[=WORKDIR], --artifact, "
@@ -262,10 +266,25 @@ def build_fleet(args):
         + (["--trace-spool", args.trace_spool]
            if args.trace_spool else []))
 
-    def factory(sid: str):
+    fleet_max = args.fleet_max or args.fleet
+    # what the replicas will run on, asked in a child that has exited
+    # before the first replica starts (this process stays off jax). On
+    # a TPU host each replica gets a chip of its own, and a fleet larger
+    # than the host's chips is refused here — a second process on a
+    # held chip would otherwise wait out its startup timeout.
+    try:
+        devices = probe_devices()
         # each replica spools/dumps under its slot id, so the merged
         # fleet trace names its pid rows r1/r2/...
-        return ProcessReplica(sid, child_argv + ["--obs-role", sid])
+        factory = process_replica_factory(
+            lambda sid: child_argv + ["--obs-role", sid],
+            replicas=fleet_max, devices=devices)
+    except (RuntimeError, ValueError) as e:
+        sys.exit(f"--fleet {args.fleet}"
+                 + (f" --fleet-max {args.fleet_max}"
+                    if args.fleet_max else "") + f": {e}")
+    print(f"replicas run on {devices['count']} x {devices['kind']} "
+          f"({devices['platform']})", file=sys.stderr)
 
     injector = None
     if args.faults:
@@ -281,7 +300,6 @@ def build_fleet(args):
             slo[name] = float(sec)
         except ValueError:
             sys.exit(f"bad --slo spec {spec!r}; want NAME=SECONDS")
-    fleet_max = args.fleet_max or args.fleet
     autoscale = None
     if fleet_max > args.fleet:
         autoscale = AutoscaleConfig(min_replicas=args.fleet,
@@ -921,7 +939,9 @@ def main(argv=None):
         return
 
     from deepvision_tpu.obs.profiler import profile_session
+    from deepvision_tpu.startup import init_runtime
 
+    init_runtime()
     spool = _setup_obs(args, args.obs_role or "replica")
     engine = build_engine(args)
     try:
@@ -937,6 +957,13 @@ def main(argv=None):
         if spool is not None:
             spool.close()
         stats = engine.stats()
+        # grep-stable exit line (chip_smoke.py reads it): executed rows
+        # (rows + padded_rows) per batch say which buckets ran
+        tel = stats["telemetry"]
+        print(f"[serve] completed={tel['completed']} "
+              f"failed={tel['failed']} batches={tel['batches']} "
+              f"rows={tel['rows']} padded_rows={tel['padded_rows']}",
+              file=sys.stderr, flush=True)
         if stats.get("pipelines"):
             # grep-stable exit line: the pipeline smoke gate asserts
             # served counts and that the frozen cache saw zero

@@ -1,7 +1,8 @@
 """Data echoing (arXiv:1907.05550): N optimizer steps per transferred
 batch — the input-bound mitigation for hosts/links slower than the chip
-(EVIDENCE.md: the fed path sustains ~345 img/s against a 2600 img/s
-device rate, so echo directly multiplies delivered step throughput)."""
+(relay-era figures: the fed path sustained ~345 img/s against a 2600
+img/s device rate, so echo directly multiplies delivered step
+throughput)."""
 
 import numpy as np
 import pytest

@@ -7,6 +7,7 @@ from __future__ import annotations
 import subprocess
 import sys
 import textwrap
+import tomllib
 from pathlib import Path
 
 import pytest
@@ -15,7 +16,6 @@ from tools.jaxlint.config import (
     BaselineEntry,
     LintConfig,
     load_config,
-    loads_toml,
 )
 from tools.jaxlint.core import run_paths
 
@@ -997,43 +997,6 @@ def test_disabled_checker_is_skipped(tmp_path):
 
 
 # --------------------------------------------------- config parsing
-
-
-def test_minimal_toml_parser_roundtrip():
-    data = loads_toml(textwrap.dedent("""
-        # comment
-        [jaxlint]
-        traced_dirs = ["a/b", "c"]   # trailing comment
-        disable = []
-        threshold = 4
-
-        [[baseline]]
-        path = "x.py"
-        code = "JX103"
-        reason = "it's deliberate, see #7"
-
-        [[baseline]]
-        path = "y.py"
-        code = "JX10*"
-        match = "kdrop"
-        """))
-    assert data["jaxlint"]["traced_dirs"] == ["a/b", "c"]
-    assert data["jaxlint"]["disable"] == []
-    assert data["jaxlint"]["threshold"] == 4
-    assert len(data["baseline"]) == 2
-    assert data["baseline"][0]["reason"] == "it's deliberate, see #7"
-
-
-def test_toml_hash_and_escapes_inside_strings():
-    data = loads_toml(
-        '[t]\n'
-        'a = "issue #12, not a comment"\n'
-        'b = "say \\"hi\\" # still content"   # real comment\n'
-        'c = ["x # y", "z"]\n'
-    )
-    assert data["t"]["a"] == "issue #12, not a comment"
-    assert data["t"]["b"] == 'say "hi" # still content'
-    assert data["t"]["c"] == ["x # y", "z"]
 
 
 def test_load_config_applies_overrides(tmp_path):
@@ -3081,7 +3044,7 @@ def test_prune_baselines_removes_only_stale_blocks(tmp_path):
     assert stale
     new_text, removed = prune_baselines(toml, stale, fix=True)
     assert removed == 1
-    kept = loads_toml(toml.read_text())["baseline"]
+    kept = tomllib.loads(toml.read_text())["baseline"]
     assert [(b["path"], b["code"]) for b in kept] == [
         ("traced/model.py", "JX101"), ("traced/model.py", "JX999")]
     # the stale block's own comment went with it; the live ones stayed

@@ -7,11 +7,11 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from deepvision_tpu.core.mesh import create_mesh
 from deepvision_tpu.parallel import halo_exchange, spatial_conv2d
-from deepvision_tpu.parallel.spatial import shard_map  # version-tolerant
 
 
 @pytest.fixture(scope="module")

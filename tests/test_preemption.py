@@ -112,9 +112,9 @@ def test_preempt_resume_is_bit_identical(tmp_path, mesh8):
 def test_rss_limit_self_preempts(tmp_path, mesh8, monkeypatch):
     """Crossing --rss-limit-gb must route into the normal preemption
     path: mid-epoch save to ckpt_preempt/, .preempted set (the train.py
-    CLI then exits 143 for a supervised --resume relaunch). Guards the
-    mitigation for the relay client's per-transfer host memory leak
-    (multi-hour runs otherwise die in an OOM SIGKILL with no save).
+    CLI then exits 143 for a supervised --resume relaunch) — a run
+    that outgrows host memory otherwise dies in an OOM SIGKILL with no
+    save.
     DVTPU_FAKE_RSS trips the in-loop check deterministically; the
     ctor-time storm guard ignores the fake (honor_fake=False) so
     construction with a sane limit still succeeds."""

@@ -15,17 +15,11 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax import enable_x64
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deepvision_tpu.core import create_mesh
 from deepvision_tpu.core.step import compiler_options
-
-# enable_x64 graduated from jax.experimental to the jax namespace across
-# the jaxlib builds this repo runs on; resolve the newest name first
-# (same env-skew class as the conftest XLA-flag probes)
-enable_x64 = getattr(jax, "enable_x64", None)
-if enable_x64 is None:  # pre-graduation jaxlib (e.g. 0.4.x)
-    from jax.experimental import enable_x64
 from deepvision_tpu.train.state import create_train_state
 from deepvision_tpu.train.steps import (
     classification_train_step,

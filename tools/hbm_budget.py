@@ -37,8 +37,10 @@ Since ISSUE 10 the accounting half of this file is a LIBRARY consumed
 by the compiled-IR contract gate (``tools/jaxlint/ircheck.py``): the
 HBM-budget regression ledger compares :func:`hbm_gb_per_step` against
 the per-model baselines in ``jaxlint.toml`` so the 76 GB number can
-only go down. Import :func:`cost_analysis_dict`, :func:`strip_layouts`
+only go down. Import :func:`hbm_gb_per_step`, :func:`strip_layouts`
 and :func:`budget_report`; the CLI below stays the human entry point.
+:func:`device_peaks` is the one table of chip peaks every MFU and
+roofline figure divides by.
 """
 
 from __future__ import annotations
@@ -82,25 +84,37 @@ def shape_elements(shape_str: str) -> list[tuple[str, int]]:
             for dt, dims in _SHAPE_RE.findall(shape_str)]
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """Compiled-executable ``cost_analysis()`` as one flat dict across
-    jax versions — newer jax returns a dict, older (0.4.x) a list with
-    one per-device dict; ``{}`` when unavailable. The single seam every
-    consumer (bench.py, tools/profile_step.py, ircheck) goes through,
-    so version skew is handled once."""
+# Peak bf16 FLOP/s and HBM GB/s by ``device_kind`` (Google Cloud TPU
+# documentation, per-chip system-architecture tables). "TPU v5 lite" is
+# what jax 0.9.0 / libtpu 0.0.34 reports for a v5e chip (chip run, PR 21).
+DEVICE_PEAKS = {
+    "TPU v5 lite": (197e12, 819.0),
+    "TPU v5e": (197e12, 819.0),
+    "TPU v5p": (459e12, 2765.0),
+    "TPU v4": (275e12, 1228.0),
+    "TPU v6e": (918e12, 1640.0),
+    "TPU v6 lite": (918e12, 1640.0),
+}
+
+
+def device_peaks(kind: str) -> tuple[float, float]:
+    """-> (peak bf16 FLOP/s, peak HBM GB/s) of one chip of ``kind``.
+    An unknown kind is an error, not a default: a utilization figure
+    over an assumed peak is a made-up number."""
     try:
-        ca = compiled.cost_analysis()
-    except Exception:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca if isinstance(ca, dict) else {}
+        return DEVICE_PEAKS[kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device_kind {kind!r} in "
+            f"tools/hbm_budget.DEVICE_PEAKS (known: "
+            f"{sorted(DEVICE_PEAKS)}); add the chip with its source "
+            "before reporting MFU or roofline shares on it") from None
 
 
 def hbm_gb_per_step(compiled) -> float:
     """XLA's aggregate "bytes accessed" for one compiled step, in GB —
     the number the jaxlint.toml HBM-budget regression ledger pins."""
-    return float(cost_analysis_dict(compiled).get("bytes accessed", 0.0)) / 1e9
+    return float(compiled.cost_analysis()["bytes accessed"]) / 1e9
 
 
 def strip_layouts(hlo_text: str) -> str:

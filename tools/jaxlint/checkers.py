@@ -1075,9 +1075,8 @@ class F32WireChecker(Checker):
     """Host-side f32 pixel materialization feeding the device wire:
     ``x.astype(np.float32)`` (or ``np.asarray(x, np.float32)``) whose
     result flows into ``device_put``/``shard_batch``/the prefetcher
-    ships 4-byte pixels over the H2D link — the exact hazard BENCH_r04
-    measured as a 7x input bind (0.073 GB/s link = ~483 uint8 img/s,
-    ~121 f32 img/s). The pipeline contract is: the host ships uint8
+    ships 4-byte pixels over the H2D link — four times the bytes of
+    the uint8 wire. The pipeline contract is: the host ships uint8
     HWC; normalization (and augmentation) runs inside the compiled
     step (``ops/normalize.maybe_normalize``, ``data/device_aug.py``).
     Which call names count as wire sinks is the ``wire_funcs`` knob

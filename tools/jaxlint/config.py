@@ -1,21 +1,19 @@
-"""jaxlint configuration: ``jaxlint.toml`` loading + the LintConfig model.
-
-The TOML-subset reader lives in ``deepvision_tpu/minitoml.py`` (shared
-with the runtime sharding engine, which consumes the same
-``[[shardcheck.rule]]`` table — one reader, one dialect); this module
-re-exports it and carries the config dataclasses + loaders."""
+"""jaxlint configuration: ``jaxlint.toml`` loading (stdlib ``tomllib``,
+the same reader the runtime sharding engine uses for the
+``[[shardcheck.rule]]`` table) + the LintConfig model."""
 
 from __future__ import annotations
 
 import fnmatch
 import re
+import tomllib
 from dataclasses import dataclass, field
 from pathlib import Path
 
-# Re-exported names (loads_toml / TomlError were defined here before the
-# sharding engine moved the reader into the library): existing importers
-# (core.py, tests) keep working unchanged.
-from deepvision_tpu.minitoml import TomlError, loads_toml  # noqa: F401
+
+class TomlError(ValueError):
+    """A ``jaxlint.toml`` entry that parses but breaks the config's own
+    rules (missing ``reason``, unknown key, bad value)."""
 
 
 # ------------------------------------------------------------- LintConfig
@@ -319,7 +317,7 @@ def load_config(path: str | Path | None) -> LintConfig:
     path = Path(path)
     if not path.exists():
         return cfg
-    data = loads_toml(path.read_text())
+    data = tomllib.loads(path.read_text())
     table = data.get("jaxlint", {})
     for name in (
         "traced_dirs", "data_dirs", "parallel_dirs",
@@ -478,7 +476,7 @@ def load_ircheck_config(path: str | Path | None) -> IRCheckConfig:
     path = Path(path)
     if not path.exists():
         return cfg
-    data = loads_toml(path.read_text())
+    data = tomllib.loads(path.read_text())
     table = data.get("ircheck", {})
     for name in ("donation_min_fraction", "hbm_tolerance",
                  "diet_median_min"):
@@ -672,7 +670,7 @@ def load_shardcheck_config(path: str | Path | None) -> ShardCheckConfig:
     path = Path(path)
     if not path.exists():
         return cfg
-    data = loads_toml(path.read_text())
+    data = tomllib.loads(path.read_text())
     table = data.get("shardcheck", {})
     if "comms_tolerance" in table:
         cfg.comms_tolerance = float(table["comms_tolerance"])

@@ -45,12 +45,12 @@ import ast
 import fnmatch
 import re
 import sys
+import tomllib
 from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Iterable, Iterator
 
-from tools.jaxlint.config import (BaselineEntry, LintConfig, TomlError,
-                                  load_config, loads_toml)
+from tools.jaxlint.config import BaselineEntry, LintConfig, load_config
 
 __all__ = [
     "Checker", "Finding", "LintConfig", "ModuleContext", "ProjectContext",
@@ -1178,11 +1178,11 @@ def prune_blocks(config_path: str | Path, header: str,
         while end > i + 1 and not lines[end - 1].strip():
             end -= 1
         try:
-            node = loads_toml("".join(lines[i:end]))
+            node = tomllib.loads("".join(lines[i:end]))
             for p in parts:
                 node = node[p]
             entry = node[0]
-        except (TomlError, KeyError, IndexError):
+        except (tomllib.TOMLDecodeError, KeyError, IndexError):
             i = j
             continue
         key = key_of(entry)

@@ -819,11 +819,10 @@ def check_case(case: IRCase, ircfg: IRCheckConfig, *,
                     "updating in place; fix the donation or add a "
                     "reasoned [[ircheck.donation]] waiver")
 
-        # (e) HBM-budget regression ledger. 0.0 means the build's
-        # cost_analysis() is unavailable (the skew cost_analysis_dict
-        # absorbs) — comparing THAT against the band would read as a
-        # miraculous improvement and disarm the gate, and recording it
-        # would poison the ledger with 0.0 rows.
+        # (e) HBM-budget regression ledger. 0.0 means cost_analysis()
+        # reported no bytes — comparing THAT against the band would
+        # read as a miraculous improvement and disarm the gate, and
+        # recording it would poison the ledger with 0.0 rows.
         gb = round(hbm_gb_per_step(compiled), 3)
         base = ircfg.hbm_baseline(case.name, rep["platform"],
                                   mesh_str, case.batch)

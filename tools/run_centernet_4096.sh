@@ -1,12 +1,11 @@
 #!/bin/bash
 # CenterNet scaling-curve point at 4096 synthetic images (extends the
-# measured 1024 -> 2048 generalization curve, EVIDENCE.md r4/r5). Same
-# two-phase recipe as `make gate_centernet` (50 epochs, then +15 at the
-# CenterNet-paper x10 lr drop via --resume) at 2x data. Supervised
-# restarts: stall watchdog exits 75 on a wedged relay RPC,
-# --rss-limit-gb self-preempts (exit 143) ahead of the relay client's
-# per-transfer host leak (tools/leak_check.py); both relaunch into the
-# bit-exact --resume path.
+# 1024 -> 2048 generalization curve). Same two-phase recipe as
+# `make gate_centernet` (50 epochs, then +15 at the CenterNet-paper x10
+# lr drop via --resume) at 2x data. Supervised restarts: stall watchdog
+# exits 75 on a wedged runtime call, --rss-limit-gb self-preempts
+# (exit 143) ahead of an OOM kill; both relaunch into the bit-exact
+# --resume path.
 set -uo pipefail
 cd "$(dirname "$0")/.."
 L="logs/gate_centernet_4096-$(date +%Y-%m-%d-%H-%M-%S).log"

@@ -4,9 +4,8 @@
 # gate (lr 1e-3, batch 32, flip-augmented synthetic detection set,
 # --keep-best) at 2x data; 30 epochs is 2x the images-seen of the 8192
 # run's peak epoch (28/50). Supervised-restart loop: the stall watchdog
-# exits 75 (EX_TEMPFAIL) on a wedged relay RPC and we relaunch into the
-# bit-exact --resume path, the operational pattern from the r4
-# CenterNet 2048 run.
+# exits 75 (EX_TEMPFAIL) on a wedged runtime call and we relaunch into
+# the bit-exact --resume path.
 set -uo pipefail
 cd "$(dirname "$0")/.."
 L="logs/gate_yolo_16384-$(date +%Y-%m-%d-%H-%M-%S).log"
@@ -15,9 +14,8 @@ WORKDIR=runs/gates16k
 RESUME=""
 for attempt in $(seq 1 8); do
   echo "[supervisor] attempt $attempt (resume='$RESUME')" | tee -a "$L"
-  # --rss-limit-gb: outrun the relay client's per-transfer host leak
-  # (~9 MB/step; tools/leak_check.py) — self-preempt + relaunch resets
-  # the process RSS long before the box OOMs
+  # --rss-limit-gb: self-preempt + relaunch resets the process RSS
+  # long before the box OOMs
   python train.py -m yolov3 --num-classes 5 --lr 1e-3 --batch-size 32 \
     --epochs 30 --synthetic-size 16384 --keep-best \
     --stall-timeout 600 --stall-abort --rss-limit-gb 80 \

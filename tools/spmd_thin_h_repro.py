@@ -18,7 +18,7 @@ against the same computation on an 8x1 (data-only) mesh:
 
 Single blocks at the same shapes are exact — the chain is required —
 which is why this escaped the usual per-op SPMD unit tests. Found by
-tests/test_spatial.py's f64 YOLO parity test (EVIDENCE.md round 5).
+tests/test_spatial.py's f64 YOLO parity test (round 5).
 """
 
 import sys

@@ -54,9 +54,9 @@ def parse_args():
                         "the config table is the source of truth, this "
                         "flag the only override")
     p.add_argument("--platform", default=None,
-                   help="force a JAX platform (e.g. 'cpu' for smoke runs; "
-                        "jax.config wins over the JAX_PLATFORMS env var, "
-                        "which site hooks may pin)")
+                   help="force a JAX platform (e.g. 'cpu' for smoke "
+                        "runs); default: whatever JAX finds, named in "
+                        "the [device] start-up line")
     p.add_argument("--raw", dest="use_raw", action="store_true",
                    default=None,
                    help="require the pre-decoded raw-frame fast path "
@@ -109,10 +109,9 @@ def parse_args():
                         "hanging forever")
     p.add_argument("--rss-limit-gb", type=float, default=0.0,
                    help="self-preempt (mid-epoch save + exit 143) when "
-                        "host RSS crosses this many GB (0 = off) — "
-                        "outruns the relay client's per-transfer host "
-                        "memory leak on multi-hour runs; a supervisor "
-                        "relaunches into --resume with a fresh process")
+                        "host RSS crosses this many GB (0 = off); a "
+                        "supervisor relaunches into --resume with a "
+                        "fresh process")
     p.add_argument("--label-smooth", type=float, default=0.0,
                    help="one-sided label smoothing on the DCGAN "
                         "discriminator's real targets (Salimans et al. "
@@ -229,10 +228,10 @@ def main():
     args = parse_args()
 
     import jax
-    import jax.numpy as jnp
 
-    if args.platform:
-        jax.config.update("jax_platforms", args.platform)
+    from deepvision_tpu.startup import init_runtime
+
+    init_runtime(args.platform)
 
     from deepvision_tpu.core import create_mesh
     from deepvision_tpu.data.mnist import batches, load_mnist_idx, synthetic_mnist
@@ -592,7 +591,7 @@ def main():
         )
 
     mesh = create_mesh()
-    print(f"devices: {jax.devices()}  mesh: {mesh.shape}")
+    print(f"mesh: {dict(mesh.shape)}", flush=True)
     trainer = Trainer(
         model, cfg, mesh, train_data, val_data,
         workdir=args.workdir, steps_per_epoch=steps,
@@ -716,8 +715,6 @@ def _maybe_publish(args, ckpt_dir: str):
 
 def run_gan(args, cfg, policy):
     """GAN path: two-network state + fit_gan loop (train/gan.py)."""
-    import jax
-
     dtype = policy.compute_dtype
 
     from deepvision_tpu.core import create_mesh
@@ -820,7 +817,7 @@ def run_gan(args, cfg, policy):
             print(f"[device-aug] {aug} fused into the train step",
                   flush=True)
 
-    print(f"devices: {jax.devices()}  mesh: {mesh.shape}")
+    print(f"mesh: {dict(mesh.shape)}", flush=True)
     # SIGTERM -> stop at the next epoch boundary with an off-cadence save
     # (same contract as Trainer.install_preemption_handler)
     from deepvision_tpu.train.trainer import (

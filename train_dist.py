@@ -68,8 +68,7 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--process-id", type=int, default=None)
     p.add_argument("--platform", default=None,
                    help="force a JAX platform (e.g. 'cpu' for local "
-                        "multi-process testing; jax.config wins over the "
-                        "JAX_PLATFORMS env var, which site hooks may pin)")
+                        "multi-process testing)")
     p.add_argument("--init-timeout-s", type=float, default=300.0,
                    help="bound on jax.distributed.initialize — a missing "
                         "peer fails the join with a clear per-host error "
@@ -124,6 +123,22 @@ def run_supervisor(dist_args, train_argv) -> int:
         split_schedule,
     )
 
+    if dist_args.supervise > 1 and dist_args.platform != "cpu":
+        # the N workers are LOCAL processes and none is confined to a
+        # chip: on a TPU host they would all open the same chips, and a
+        # chip belongs to one process at a time. Ask a child (this
+        # process stays off jax) and refuse before anything spawns.
+        from deepvision_tpu.startup import probe_devices
+
+        devices = probe_devices()
+        if devices["platform"] == "tpu":
+            raise SystemExit(
+                f"--supervise {dist_args.supervise}: the "
+                f"{dist_args.supervise} local workers would all open "
+                f"this host's {devices['count']} TPU chip(s), and a "
+                "chip belongs to one process at a time. Run one worker "
+                "per host (--supervise 1, or worker mode on every "
+                "host), or pass --platform cpu for a local drill.")
     injector = None
     if dist_args.faults:
         mine, rest = split_schedule(dist_args.faults, CLUSTER_SITES)
@@ -182,14 +197,10 @@ def run_worker(dist_args, train_argv) -> None:
                 or os.environ.get("JAX_PLATFORMS", ""))
     if "cpu" in platform:
         # multiprocess CPU computations need an explicit collectives
-        # backend on this jax (without it every cross-process psum —
-        # orbax's sync barriers included — fails with "Multiprocess
-        # computations aren't implemented on the CPU backend")
-        try:
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-        except Exception:
-            pass  # option absent on this jax: defaults already work
+        # backend (without it every cross-process psum — orbax's sync
+        # barriers included — fails with "Multiprocess computations
+        # aren't implemented on the CPU backend")
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     kwargs = {}
     if dist_args.coordinator:
         kwargs = dict(
@@ -197,13 +208,9 @@ def run_worker(dist_args, train_argv) -> None:
             num_processes=dist_args.num_processes,
             process_id=dist_args.process_id,
         )
-    import inspect
-
-    bounded = "initialization_timeout" in inspect.signature(
-        jax.distributed.initialize).parameters
     who = (f"process {dist_args.process_id}/{dist_args.num_processes}"
            if dist_args.process_id is not None else "this process")
-    # banner BEFORE the join: some jax builds hard-abort (absl FATAL,
+    # banner BEFORE the join: this jax hard-aborts (absl FATAL,
     # SIGABRT) on DEADLINE_EXCEEDED instead of raising, so the per-host
     # context must already be in the log when the process dies
     print(f"[cluster] {who}: joining coordinator "
@@ -214,12 +221,9 @@ def run_worker(dist_args, train_argv) -> None:
     try:
         # bounded join (jaxlint JX115): a blocking cluster join without
         # a timeout hangs forever on a missing peer
-        if bounded:
-            jax.distributed.initialize(
-                initialization_timeout=int(dist_args.init_timeout_s),
-                **kwargs)
-        else:  # ancient jax: no bounded join available
-            jax.distributed.initialize(**kwargs)  # jaxlint: disable=JX115
+        jax.distributed.initialize(
+            initialization_timeout=int(dist_args.init_timeout_s),
+            **kwargs)
     except Exception as e:
         print(
             f"[cluster] {who}: jax.distributed.initialize failed after "
