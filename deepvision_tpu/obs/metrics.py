@@ -96,10 +96,6 @@ class Gauge:
         with self._lock:
             self._value = float(value)
 
-    def inc(self, delta: float = 1.0) -> None:
-        with self._lock:
-            self._value += delta
-
     @property
     def value(self) -> float:
         with self._lock:
@@ -163,11 +159,6 @@ class Histogram:
                     "total": self._total,
                     "samples": [float(s) for s in self._samples]}
 
-    def export(self, qs=(0.5, 0.95, 0.99)) -> dict:
-        """Base-unit (seconds) view for the Prometheus rendering: one
-        locked read yields a coherent (count, sum, quantiles) triple."""
-        return histogram_export(self.dump(), qs)
-
     def summary(self) -> dict:
         return histogram_summary(self.dump())
 
@@ -202,14 +193,6 @@ class Registry:
         with self._lock:
             self._metrics[name] = metric
         return metric
-
-    def unregister(self, name: str) -> None:
-        with self._lock:
-            self._metrics.pop(name, None)
-
-    def clear(self) -> None:
-        with self._lock:
-            self._metrics.clear()
 
     def _get_or_create(self, name: str, cls, factory):
         with self._lock:

@@ -35,8 +35,13 @@ __all__ = [
 ]
 
 # memory_stats() fields promoted to metrics (names vary by backend;
-# these three are the PJRT-stable core: live HBM, high-water mark, cap)
-_MEM_FIELDS = ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+# the first three are the PJRT-stable core: live HBM, high-water mark,
+# cap). `peak_bytes_in_use` counts live arrays only; an executable's
+# temporaries show in `peak_bytes_reserved` (8.8 GB of a ResNet-50 b256
+# step on a v5e, PERF.md Findings, PR 23): a process's peak is the two
+# together
+_MEM_FIELDS = ("bytes_in_use", "peak_bytes_in_use", "bytes_limit",
+               "peak_bytes_reserved")
 
 
 def device_memory_stats() -> dict[str, float]:

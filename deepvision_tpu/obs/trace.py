@@ -18,8 +18,19 @@ cheap enough to leave compiled into every loop:
 Design points:
 
 - **disabled-by-default, near-zero cost**: ``span()`` returns a shared
-  no-op context manager unless the tracer is enabled, so the feed and
-  step loops carry their spans unconditionally;
+  no-op context manager unless the tracer is enabled or a
+  ``jax.profiler`` trace is running, so the feed and step loops carry
+  their spans unconditionally;
+- **one clock with the device trace**: while a ``jax.profiler`` trace
+  runs (whoever started it), a span is also a
+  ``jax.profiler.TraceAnnotation`` named ``<cat>/<name>``
+  (``serve/pack``, ``feed/host_next``, ``train/step``) with its scalar
+  ``args`` as the event's stats, so it lands among the host events of
+  the ``.xplane.pb`` beside the device's operations. A span that
+  encloses other spans on its thread says so where it is opened
+  (``encloses=True``: ``train/epoch``, ``train/eval``) and stays off
+  the profiler: an event covering its children would take every idle
+  gap that they should name;
 - **monotonic clock** (``time.perf_counter``) — wall-clock steps from
   NTP can never produce negative spans;
 - **thread-aware**: every span records its thread id/name and its
@@ -43,7 +54,8 @@ Design points:
   OR a sink is attached, so an always-on flight recorder doesn't
   require the in-memory ring/export machinery to be on;
 - **retroactive spans**: :meth:`Tracer.record_span` records a span
-  from explicit ``perf_counter`` stamps — for code that already times
+  from explicit ``perf_counter`` stamps (host clock only: a profiler
+  annotation cannot be back-dated) — for code that already times
   a region with its own clock reads (the serve engine's per-request
   queue-wait, measured as ``t_dispatch - t_submit``) and wants the
   interval on the trace without restructuring into a ``with`` block;
@@ -64,6 +76,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -90,19 +103,48 @@ class _NoopSpan:
 
 _NOOP = _NoopSpan()
 
+_ANNOTATION = None  # jax.profiler.TraceAnnotation, bound on first use
+
+
+def _profile_running() -> bool:
+    """Is a ``jax.profiler`` trace running in this process? A process
+    that never imported jax (the fleet router's parent) cannot be under
+    one, and is not made to import it here."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        if "jax" not in sys.modules:
+            return False
+        from jax.profiler import TraceAnnotation
+
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION.is_enabled()
+
 
 class Span:
-    """One live ``with`` region; created by :meth:`Tracer.span`."""
+    """One live ``with`` region; created by :meth:`Tracer.span` or
+    :meth:`Tracer.timed`. After the region ``t0`` and ``dur`` hold its
+    ``perf_counter`` start and length: the one pair of reads the ring,
+    the caller's ``observe`` and (to the call's own cost) the profiler
+    annotation share."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_sync", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_sync", "_observe",
+                 "_annotation", "t0", "dur")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 args: dict | None, device_sync):
+                 args: dict | None, device_sync, observe=None,
+                 profiled: bool = False):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
         self._sync = device_sync
+        self._observe = observe
+        self._annotation = None
+        if profiled:
+            # scalars only: an annotation's stats are key=value text
+            stats = {k: v for k, v in (args or {}).items()
+                     if isinstance(v, (str, int, float))}
+            self._annotation = _ANNOTATION(f"{cat}/{name}", **stats)
 
     def device_sync(self, value):
         """Mark ``value`` (array/pytree) to be ``block_until_ready``-ed
@@ -113,7 +155,9 @@ class Span:
 
     def __enter__(self):
         self._tracer._push()
-        self._t0 = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
@@ -124,10 +168,14 @@ class Span:
                 jax.block_until_ready(self._sync)
             except Exception:
                 pass  # a failed sync must not mask the body's exception
-        t1 = time.perf_counter()
+        self.dur = time.perf_counter() - self.t0
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
         depth = self._tracer._pop()
-        self._tracer._record(self.name, self.cat, self._t0,
-                             t1 - self._t0, depth, self.args)
+        if self._observe is not None:
+            self._observe(self.dur)
+        self._tracer._record(self.name, self.cat, self.t0, self.dur,
+                             depth, self.args)
         return False
 
 
@@ -209,11 +257,26 @@ class Tracer:
 
     # -- recording -------------------------------------------------------
     def span(self, name: str, cat: str = "app", args: dict | None = None,
-             device_sync=None):
-        """Context manager timing its body; no-op while inactive."""
-        if not self.active:
+             device_sync=None, *, encloses: bool = False):
+        """Context manager timing its body; the shared no-op while the
+        tracer is inactive and no profile runs. ``encloses``: this span
+        wraps other spans on its thread, so it stays off the profiler
+        (see the module docstring)."""
+        profiled = not encloses and _profile_running()
+        if not (profiled or self.active):
             return _NOOP
-        return Span(self, name, cat, args, device_sync)
+        return Span(self, name, cat, args, device_sync, profiled=profiled)
+
+    def timed(self, name: str, cat: str = "app", args: dict | None = None,
+              observe=None) -> Span:
+        """A span that always measures, for a caller that keeps the
+        seconds whatever else records them: it reads ``t0``/``dur`` back
+        after the region, or hands ``observe`` (called with the seconds
+        as the span closes) a sink of its own. The serve engine's phases
+        use it: one measurement for the registry's histogram, the ring
+        and the profile."""
+        return Span(self, name, cat, args, None, observe=observe,
+                    profiled=_profile_running())
 
     def _push(self) -> None:
         self._local.depth = getattr(self._local, "depth", 0) + 1
@@ -359,9 +422,10 @@ def get_tracer() -> Tracer:
 
 
 def span(name: str, cat: str = "app", args: dict | None = None,
-         device_sync=None):
+         device_sync=None, *, encloses: bool = False):
     """``with span("step"): ...`` against the default tracer."""
-    return _TRACER.span(name, cat=cat, args=args, device_sync=device_sync)
+    return _TRACER.span(name, cat=cat, args=args, device_sync=device_sync,
+                        encloses=encloses)
 
 
 # ------------------------------------------------------- trace analysis

@@ -744,6 +744,19 @@ class InferenceEngine:
                 backoff = min(backoff * 2, self._restart_backoff_max_s)
                 self.telemetry.record_dispatcher_restart()
 
+    def _phase(self, name: str, args: dict | None = None):
+        """One phase of the dispatcher's cycle (``wait``,
+        ``fill_window``, ``pack``, ``device_put``, ``resolve``; the
+        sixth, ``device``, goes through ``record_batch``): a span whose
+        one pair of clock reads feeds the phase's ``serve_<name>_time``
+        histogram always, the ring tracer when it is on, and a
+        ``serve/<name>`` profiler annotation when a profile runs. The
+        phases are flat and consecutive on the dispatcher thread; what
+        lies between two of them is microseconds."""
+        return get_tracer().timed(
+            name, cat="serve", args=args,
+            observe=self.telemetry.phase_time[name].record)
+
     def _dispatch_loop(self) -> None:
         pending = self._pending
         rr = list(self._models)  # round-robin cursor over models
@@ -754,8 +767,13 @@ class InferenceEngine:
                 # poll tick instead of waking on the stop event
                 self._stop.wait(0.002)
                 continue
-            self._drain_inbound(
-                pending, block=not any(pending.values()))
+            if any(pending.values()):
+                self._drain_inbound(pending, block=False)
+            else:
+                # nothing pending: blocked until a request arrives (or
+                # the poll tick) is the cycle's `wait` phase
+                with self._phase("wait"):
+                    self._drain_inbound(pending, block=True)
             if self._stop.is_set() or self._paused.is_set():
                 continue
             name = self._next_model(pending, rr)
@@ -859,17 +877,18 @@ class InferenceEngine:
         if self._window <= 0:
             return
         until = pending[name][0].t_submit + self._window
-        while len(pending[name]) < ladder_max \
-                and not self._stop.is_set():
-            remaining = until - time.perf_counter()
-            if remaining <= 0:
-                return
-            try:
-                item = self._q.get(timeout=remaining)
-            except queue.Empty:
-                return
-            if item is not _WAKE:
-                pending[item.model].append(item)
+        with self._phase("fill_window"):
+            while len(pending[name]) < ladder_max \
+                    and not self._stop.is_set():
+                remaining = until - time.perf_counter()
+                if remaining <= 0:
+                    return
+                try:
+                    item = self._q.get(timeout=remaining)
+                except queue.Empty:
+                    return
+                if item is not _WAKE:
+                    pending[item.model].append(item)
 
     def _expire(self, reqs: list[_Request]) -> list[_Request]:
         now = time.perf_counter()
@@ -902,28 +921,58 @@ class InferenceEngine:
         if getattr(served, "is_stateful", False):
             self._run_stateful_batch(served, reqs)
             return
-        t_dispatch = time.perf_counter()
         n = len(reqs)
         bucket = self._bucket_for(served, n)
-        x = np.zeros((bucket, *served.input_shape), served.input_dtype)
-        for i, r in enumerate(reqs):
-            x[i] = r.x
+        tracer = get_tracer()
+        batch_args = {"model": served.name, "bucket": bucket, "rows": n}
+        traces = [r.trace for r in reqs if r.trace]
+        with self._phase("pack", batch_args) as sp:
+            x = np.zeros((bucket, *served.input_shape), served.input_dtype)
+            for i, r in enumerate(reqs):
+                x[i] = r.x
+        t_dispatch = sp.t0
         try:
-            # residency first: a cold tenant's weights come back to the
-            # device (and LRU victims leave) BEFORE the executable runs
-            for tn in self._tenant_names(served):
-                self._tenancy.ensure_resident(tn)
-            runner = self._bucket_runner(served, bucket)
-            xd = jax.device_put(x, data_sharding(self._mesh, x.ndim))
-            t0 = time.perf_counter()
-            host = jax.device_get(runner(xd))
-            t_dev = time.perf_counter() - t0
+            with self._phase("device_put", {"bucket": bucket}):
+                # residency first: a cold tenant's weights come back to
+                # the device (and LRU victims leave) BEFORE the
+                # executable runs
+                for tn in self._tenant_names(served):
+                    self._tenancy.ensure_resident(tn)
+                runner = self._bucket_runner(served, bucket)
+                xd = jax.device_put(x, data_sharding(self._mesh, x.ndim))
+            # the half of the distributed request timeline that runs on
+            # the replica's chip. It measures completed compute:
+            # device_get drains the dispatch before the end stamp, the
+            # JX112/JX117 contract. `serve_device_time` gets the span's
+            # own seconds
+            with tracer.timed(
+                    "device", cat="serve",
+                    args={**batch_args,
+                          **({"traces": traces} if traces else {})},
+                    ) as sp_dev:
+                host = jax.device_get(runner(xd))
         except Exception as e:  # device/compile failure: fail the batch
             for r in reqs:
                 r.future.set_exception(e)
                 self.telemetry.record_failure()
                 self._admission.release(r.model)
             return
+        with self._phase("resolve", {"rows": n}):
+            self._resolve_batch(served, reqs, host, bucket, t_dispatch,
+                                sp_dev.dur, traces)
+            # the batch's buffers go inside the phase: unmapping the
+            # packed array of a full YOLOv3-608 bucket (283 MB) held
+            # the dispatcher thread for 13 ms after `resolve` (chip
+            # trace, PR 25)
+            del x, xd, host
+
+    def _resolve_batch(self, served, reqs, host, bucket: int,
+                       t_dispatch: float, t_dev: float,
+                       traces: list) -> None:
+        """The `resolve` phase of a stateless batch: counters, then per
+        request the host post-process, ``set_result`` (which runs the
+        client's callbacks on this thread) and the admission release."""
+        n = len(reqs)
         self.telemetry.record_batch(bucket=bucket, rows=n, device_s=t_dev)
         self._admission.observe_batch(t_dev, n)
         is_pipeline = getattr(served, "is_pipeline", False)
@@ -949,17 +998,9 @@ class InferenceEngine:
                     expired.add(id(r))
         tracer = get_tracer()
         if tracer.active:
-            # retroactive spans from the stamps this loop already takes
-            # (obs/trace.py record_span — same perf_counter clock): the
-            # replica half of the distributed request timeline. The
-            # device span already measured completed compute —
-            # device_get above drained the dispatch, the JX112/JX117
-            # contract
-            traces = [r.trace for r in reqs if r.trace]
-            tracer.record_span(
-                "device", t0, t0 + t_dev, cat="serve",
-                args={"model": served.name, "bucket": bucket, "rows": n,
-                      **({"traces": traces} if traces else {})})
+            # retroactive per-request spans from the stamps this loop
+            # already takes (obs/trace.py record_span — same
+            # perf_counter clock)
             if is_pipeline:
                 # one span per DAG stage, stamped with every request
                 # trace id in the batch: the trace ids flow router ->
@@ -1047,46 +1088,60 @@ class InferenceEngine:
 
         n = len(group)
         bucket = self._bucket_for(served, n)
-        x = np.zeros((bucket, *served.input_shape), served.input_dtype)
-        for i, (r, _f) in enumerate(group):
-            x[i] = r.x
+        tracer = get_tracer()
+        batch_args = {"model": served.name, "bucket": bucket, "rows": n}
+        traces = [r.trace for r, _f in group if r.trace]
+        with self._phase("pack", batch_args):
+            x = np.zeros((bucket, *served.input_shape), served.input_dtype)
+            for i, (r, _f) in enumerate(group):
+                x[i] = r.x
         try:
-            for tn in self._tenant_names(served):
-                self._tenancy.ensure_resident(tn)
-            runner = self._bucket_runner(served, bucket)
-            zero = runner.zero_slates()
-            # stack per-session device rows (zero rows for fresh/reset
-            # streams and padding) into the batched slate pytree
-            slates = {
-                k: jnp.stack([
-                    group[i][1].entry.state[k]
-                    if i < n and group[i][1].entry.state is not None
-                    else zero[k][i]
-                    for i in range(bucket)])
-                for k in zero}
-            xd = jax.device_put(x, data_sharding(self._mesh, x.ndim))
-            t0 = time.perf_counter()
-            if mode == "detect":
-                new_slates, out = runner.update(slates, runner.detect(xd))
-            else:
-                new_slates, out = runner.advance(slates)
-            host = jax.device_get(out)  # ONE host sync for the batch
-            t_dev = time.perf_counter() - t0
+            with self._phase("device_put", {"bucket": bucket}):
+                for tn in self._tenant_names(served):
+                    self._tenancy.ensure_resident(tn)
+                runner = self._bucket_runner(served, bucket)
+                zero = runner.zero_slates()
+                # stack per-session device rows (zero rows for
+                # fresh/reset streams and padding) into the batched
+                # slate pytree
+                slates = {
+                    k: jnp.stack([
+                        group[i][1].entry.state[k]
+                        if i < n and group[i][1].entry.state is not None
+                        else zero[k][i]
+                        for i in range(bucket)])
+                    for k in zero}
+                xd = jax.device_put(x, data_sharding(self._mesh, x.ndim))
+            with tracer.timed(
+                    "device", cat="serve",
+                    args={**batch_args, "mode": mode,
+                          "sessions": [r.session for r, _f in group],
+                          **({"traces": traces} if traces else {})},
+                    ) as sp_dev:
+                if mode == "detect":
+                    new_slates, out = runner.update(slates,
+                                                    runner.detect(xd))
+                else:
+                    new_slates, out = runner.advance(slates)
+                host = jax.device_get(out)  # ONE host sync for the batch
         except Exception as e:  # device/compile failure: fail the group
             for r, _f in group:
                 self._fail_request(r, e)
             return
+        with self._phase("resolve", {"rows": n}):
+            self._resolve_stateful_group(
+                served, store, group, mode, host, new_slates, bucket,
+                t_dispatch, sp_dev.dur)
+
+    def _resolve_stateful_group(self, served, store, group, mode: str,
+                                host, new_slates, bucket: int,
+                                t_dispatch: float, t_dev: float) -> None:
+        """The `resolve` phase of a stateful group: counters, then per
+        frame the state commit, the answer and the admission release."""
+        n = len(group)
         self.telemetry.record_batch(bucket=bucket, rows=n, device_s=t_dev)
         self._admission.observe_batch(t_dev, n)
         tracer = get_tracer()
-        if tracer.active:
-            traces = [r.trace for r, _f in group if r.trace]
-            sessions = [r.session for r, _f in group]
-            tracer.record_span(
-                "device", t0, t0 + t_dev, cat="serve",
-                args={"model": served.name, "bucket": bucket, "rows": n,
-                      "mode": mode, "sessions": sessions,
-                      **({"traces": traces} if traces else {})})
         now = time.perf_counter()
         for i, (r, f) in enumerate(group):
             # commit state FIRST: the stream's lineage advances even if

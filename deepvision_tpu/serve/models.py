@@ -254,17 +254,26 @@ class ServedModel:
 
 # ------------------------------------------------------- task forwards
 
+# Every served program names its two halves (``jax.named_scope``), so
+# that each device operation's ``op_name`` metadata, and with it every
+# `XLA Ops` event of a profile, says which half it belongs to. Metadata
+# only: the compiled code is the same.
+FORWARD_SCOPE = "served/forward"
+POSTPROCESS_SCOPE = "served/postprocess"
+
 
 def _classify_forward(apply_fn, top_k: int):
     import jax
     import jax.numpy as jnp
 
     def forward(variables, x):
-        logits = apply_fn(variables, x, train=False)
-        if isinstance(logits, (tuple, list)):
-            logits = logits[0]  # aux-head models (inception) -> main
-        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-        top_probs, top_classes = jax.lax.top_k(probs, top_k)
+        with jax.named_scope(FORWARD_SCOPE):
+            logits = apply_fn(variables, x, train=False)
+        with jax.named_scope(POSTPROCESS_SCOPE):
+            if isinstance(logits, (tuple, list)):
+                logits = logits[0]  # aux-head models (inception) -> main
+            probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+            top_probs, top_classes = jax.lax.top_k(probs, top_k)
         return {"probs": top_probs, "classes": top_classes}
 
     return forward
@@ -277,14 +286,18 @@ def _classify_post(host: dict, i: int) -> dict:
 
 def _yolo_forward(apply_fn, num_classes: int, score_thresh: float,
                   iou_thresh: float):
+    import jax
+
     from deepvision_tpu.ops.yolo_postprocess import yolo_postprocess
 
     def forward(variables, x):
-        preds = apply_fn(variables, x, train=False)
-        boxes, scores, classes, valid, _ = yolo_postprocess(
-            preds, num_classes,
-            score_thresh=score_thresh, iou_thresh=iou_thresh,
-        )
+        with jax.named_scope(FORWARD_SCOPE):
+            preds = apply_fn(variables, x, train=False)
+        with jax.named_scope(POSTPROCESS_SCOPE):
+            boxes, scores, classes, valid, _ = yolo_postprocess(
+                preds, num_classes,
+                score_thresh=score_thresh, iou_thresh=iou_thresh,
+            )
         return {"boxes": boxes, "scores": scores, "classes": classes,
                 "valid": valid}
 
@@ -302,26 +315,34 @@ def _detect_post(host: dict, i: int) -> dict:
 
 
 def _centernet_forward(apply_fn, score_thresh: float, top_k: int = 100):
+    import jax
+
     from deepvision_tpu.ops.centernet_decode import decode_centernet
     from deepvision_tpu.ops.iou import xywh_to_corners
 
     def forward(variables, x):
-        heat, wh, off = apply_fn(variables, x, train=False)[-1]
-        det = decode_centernet(heat, wh, off, top_k=top_k)
-        # normalize to the same corner-box contract as the YOLO head
-        det["boxes"] = xywh_to_corners(det["boxes"])
-        det["valid"] = det["scores"] > score_thresh
+        with jax.named_scope(FORWARD_SCOPE):
+            heat, wh, off = apply_fn(variables, x, train=False)[-1]
+        with jax.named_scope(POSTPROCESS_SCOPE):
+            det = decode_centernet(heat, wh, off, top_k=top_k)
+            # normalize to the same corner-box contract as the YOLO head
+            det["boxes"] = xywh_to_corners(det["boxes"])
+            det["valid"] = det["scores"] > score_thresh
         return det
 
     return forward
 
 
 def _pose_forward(apply_fn):
+    import jax
+
     from deepvision_tpu.ops.heatmap import decode_heatmaps
 
     def forward(variables, x):
-        heatmaps = apply_fn(variables, x, train=False)[-1]  # last stack
-        kx, ky, conf = decode_heatmaps(heatmaps)
+        with jax.named_scope(FORWARD_SCOPE):
+            heatmaps = apply_fn(variables, x, train=False)[-1]  # last stack
+        with jax.named_scope(POSTPROCESS_SCOPE):
+            kx, ky, conf = decode_heatmaps(heatmaps)
         return {"x": kx, "y": ky, "conf": conf}
 
     return forward

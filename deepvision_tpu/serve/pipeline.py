@@ -144,8 +144,19 @@ class ModelStage:
             return self.precompiled
         x_spec = jax.ShapeDtypeStruct(
             (bucket, *self.input_shape), self.input_dtype)
+        forward = self.forward
+
+        def served_forward(variables, x):
+            return forward(variables, x)
+
+        # one name for every model: it is the executable's name in a
+        # profile (`XLA Modules`: jit_served_forward) and part of the
+        # persistent compile cache's key, which the named scopes inside
+        # `forward` are not. A program fetched under the old name
+        # `jit_forward` would carry no scope; two tenants of one
+        # architecture still share their entries
         fn = jax.jit(
-            self.forward,
+            served_forward,
             in_shardings=(replicated_sharding(mesh),
                           data_sharding(mesh, 1 + len(self.input_shape))),
             donate_argnums=(1,) if donate else (),

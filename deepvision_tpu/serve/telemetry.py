@@ -87,6 +87,11 @@ _COUNTER_FIELDS = (
     "dispatcher_restarts",
 )
 
+# the dispatcher's cycle beside ``device_time``: one histogram a phase
+# (``serve_<phase>_time``), fed by the engine's phase spans, so /stats
+# and /metrics give the cycle's split with no profiler running
+_PHASE_FIELDS = ("wait", "fill_window", "pack", "device_put", "resolve")
+
 
 class ServeTelemetry:
     """Counters + per-stage histograms for one engine's lifetime.
@@ -115,6 +120,10 @@ class ServeTelemetry:
             hist=reg.register("serve_device_time", Histogram()))
         self.e2e = LatencyStats(          # admitted -> future resolved
             hist=reg.register("serve_e2e_latency", Histogram()))
+        self.phase_time = {               # per dispatcher phase
+            f: LatencyStats(hist=reg.register(f"serve_{f}_time",
+                                              Histogram()))
+            for f in _PHASE_FIELDS}
 
     # -- recording (dispatcher + submit threads) -------------------------
     def record_submit(self) -> None:
@@ -159,8 +168,9 @@ class ServeTelemetry:
     def snapshot(self) -> dict:
         """One JSON-able dict: counters, pad overhead, and p50/p95/p99
         blocks per stage (the serving analog of
-        ``FeedTelemetry.summary``) — key-for-key identical to the
-        pre-obs shape (the ``/stats`` contract)."""
+        ``FeedTelemetry.summary``) — every key of the pre-obs shape
+        (the ``/stats`` contract), then one ``<phase>_time`` block per
+        dispatcher phase."""
         with self._lock:
             vals = {f: c.value for f, c in self._c.items()}
             executed = vals["rows"] + vals["padded_rows"]
@@ -178,6 +188,8 @@ class ServeTelemetry:
                 "queue_wait": self.queue_wait.summary(),
                 "device_time": self.device_time.summary(),
                 "e2e_latency": self.e2e.summary(),
+                **{f"{f}_time": h.summary()
+                   for f, h in self.phase_time.items()},
             }
 
 

@@ -623,7 +623,8 @@ def _gan_epoch_loop(state, step, train_data, mesh, start_epoch, epochs,
         # Spans (obs/trace.py) mirror the Trainer's epoch/step/drain
         # attribution; no-ops unless the tracer is enabled (--trace).
         tel = FeedTelemetry()
-        with span("epoch", cat="train", args={"epoch": int(epoch)}):
+        with span("epoch", cat="train", args={"epoch": int(epoch)},
+                  encloses=True):
             feed = DevicePrefetcher(train_data(epoch), mesh,
                                     depth=prefetch_depth, telemetry=tel)
             try:

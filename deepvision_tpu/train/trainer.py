@@ -803,7 +803,10 @@ class Trainer:
         # window tools/trace_summary.py attributes; "step"/"fetch"/
         # "drain" (+ the producer thread's host_next/shard) are the
         # leaves inside it. All no-ops unless the tracer is enabled
-        # (train.py --trace). NOTE on async backends (TPU): the "step"
+        # (train.py --trace) or a profile runs (--profile-steps: the
+        # leaves are then host events of the profile, `train/step`;
+        # "epoch" and "eval" enclose them and stay off it). NOTE on
+        # async backends (TPU): the "step"
         # span deliberately does NOT device_sync — a per-step block
         # would serialize the overlapped feed this loop exists for —
         # so it measures dispatch + queue backpressure (converging to
@@ -811,7 +814,8 @@ class Trainer:
         # residual compute drains into the "drain" spans; exact
         # per-step device time is --profile-steps' job.
         tel = FeedTelemetry()
-        with span("epoch", cat="train", args={"epoch": int(epoch)}):
+        with span("epoch", cat="train", args={"epoch": int(epoch)},
+                  encloses=True):
             feed = DevicePrefetcher(counted(), self.mesh,
                                     depth=self.prefetch_depth,
                                     telemetry=tel,
@@ -1089,7 +1093,7 @@ class Trainer:
     def _fit(self, epochs: int | None = None) -> Loggers:
         total = epochs or self.config.get("total_epochs", 1)
         if self.start_epoch == 0 and self.start_step == 0:
-            with span("eval", cat="train"):
+            with span("eval", cat="train", encloses=True):
                 # pre-train validation (ref: train.py:390)
                 val = self.validate()
             if val:
@@ -1149,7 +1153,7 @@ class Trainer:
                 # honest history: this epoch's train aggregates cover only
                 # the post-resume tail of the epoch
                 tr["train_from_step"] = float(start_step)
-            with span("eval", cat="train"):
+            with span("eval", cat="train", encloses=True):
                 val = self.validate()
             epoch_metrics = {**tr, **val}
             self.loggers.log_metrics(epoch, epoch_metrics)

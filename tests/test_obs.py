@@ -163,6 +163,104 @@ def test_tracer_disabled_is_noop_and_enabled_records_depth():
             and e["name"] == "thread_name"]
 
 
+def _profiled_host_events(tmp_path, body) -> list:
+    """Run ``body`` on a worker thread under a ``jax.profiler`` trace
+    with the Python tracer off (as the benchmark's traced serving run
+    has it); -> the host plane's events as (name, start, end, stats)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        t = threading.Thread(target=body)
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                events += [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                            dict(e.stats)) for e in line.events]
+    return events
+
+
+def test_a_span_is_a_profiler_annotation_named_cat_slash_name(tmp_path):
+    """Ring off, no sink: under a running profile a span still lands in
+    the profile's host events as ``<cat>/<name>`` with its scalar args
+    as stats, on the device trace's clock."""
+    tr = Tracer()
+
+    def body():
+        with tr.span("pack", cat="serve",
+                     args={"model": "m", "bucket": 64, "rows": 40,
+                           "traces": ["not", "a", "scalar"]}):
+            time.sleep(0.003)
+
+    events = _profiled_host_events(tmp_path, body)
+    (ev,) = [e for e in events if e[0] == "serve/pack"]
+    assert ev[3] == {"model": "m", "bucket": 64, "rows": 40}
+    assert ev[2] - ev[1] >= 3e6     # ns
+    assert len(tr) == 0             # the ring stayed off
+
+
+def test_an_enclosing_span_stays_off_the_profile(tmp_path):
+    """``encloses=True`` (train/epoch, train/eval): the ring gets the
+    span, the profile only its children, so that no host event covers
+    them when idle gaps are named."""
+    tr = Tracer().enable()
+
+    def body():
+        with tr.span("epoch", cat="train", encloses=True):
+            for _ in range(2):
+                with tr.span("step", cat="train"):
+                    time.sleep(0.002)
+
+    events = _profiled_host_events(tmp_path, body)
+    names = [e[0] for e in events]
+    assert names.count("train/step") == 2
+    assert "train/epoch" not in names
+    steps = [e for e in events if e[0] == "train/step"]
+    lo, hi = min(e[1] for e in steps), max(e[2] for e in steps)
+    assert not [e for e in events if e[0].startswith("train/")
+                and e[1] <= lo and e[2] >= hi]
+    ring = {e["name"] for e in tr.chrome_events() if e["ph"] == "X"}
+    assert ring == {"epoch", "step"}
+
+
+def test_span_builds_nothing_with_ring_and_profile_off(monkeypatch):
+    from deepvision_tpu.obs import trace
+
+    built = []
+    real = trace.Span.__init__
+    monkeypatch.setattr(
+        trace.Span, "__init__",
+        lambda self, *a, **k: (built.append(a), real(self, *a, **k))[1])
+    tr = Tracer()
+    assert tr.span("x", cat="serve") is tr.span("y", encloses=True)
+    assert not built                # the shared no-op, no Span
+    with tr.timed("z", cat="serve") as sp:  # always measures
+        pass
+    assert len(built) == 1 and sp.dur >= 0 and len(tr) == 0
+
+
+def test_timed_span_feeds_observer_ring_and_caller_one_measurement():
+    tr = Tracer().enable()
+    seen = []
+    with tr.timed("pack", cat="serve", args={"rows": 3},
+                  observe=seen.append) as sp:
+        time.sleep(0.001)
+    ((name, _cat, _ts, dur, *_rest),) = list(tr._events)
+    assert name == "pack" and seen == [sp.dur] and dur == sp.dur
+
+
 def test_tracer_export_chrome_format_and_threads(tmp_path):
     tr = Tracer()
     tr.enable()
@@ -262,6 +360,26 @@ def test_device_memory_stats_graceful_and_gauged():
         assert reg.names() == []
 
 
+def test_device_memory_stats_reads_the_reserved_peak(monkeypatch):
+    """`peak_bytes_in_use` counts live arrays only; the temporaries of a
+    running executable are in `peak_bytes_reserved`, which the gauges
+    left out (they under-read a ResNet-50 b256 process by 8.8 GB)."""
+    import jax
+
+    from deepvision_tpu.obs import profiler
+
+    class _Dev:
+        def memory_stats(self):
+            return {"bytes_in_use": 1.0, "peak_bytes_in_use": 2.0,
+                    "bytes_limit": 9.0, "peak_bytes_reserved": 7.0,
+                    "num_allocs": 3}
+
+    monkeypatch.setattr(jax, "local_devices", lambda: [_Dev()])
+    assert profiler.device_memory_stats() == {
+        "mem_bytes_in_use_dev0": 1.0, "mem_peak_bytes_in_use_dev0": 2.0,
+        "mem_bytes_limit_dev0": 9.0, "mem_peak_bytes_reserved_dev0": 7.0}
+
+
 def test_profile_window_start_stop_and_spec_validation(monkeypatch):
     from deepvision_tpu.obs import profiler as prof
 
@@ -315,12 +433,16 @@ def test_serve_telemetry_snapshot_keys_and_registry_names():
     tel.record_request(queue_wait_s=0.001, e2e_s=0.006)
     snap = tel.snapshot()
     # the exact PR 3 /stats shape, key order included
-    assert list(snap) == [
+    assert list(snap)[:15] == [
         "submitted", "completed", "timed_out", "failed", "shed",
         "batches", "rows", "padded_rows", "dispatcher_crashes",
         "dispatcher_restarts", "pad_overhead_frac", "mean_batch_rows",
         "queue_wait", "device_time", "e2e_latency",
     ]
+    # then the dispatcher's phases (PR 25): keys gained, none lost
+    assert list(snap)[15:] == [
+        "wait_time", "fill_window_time", "pack_time", "device_put_time",
+        "resolve_time"]
     assert snap["pad_overhead_frac"] == 0.25
     # attribute-style reads (engine/tests rely on these)
     assert tel.submitted == 1 and tel.batches == 1 and tel.rows == 3
@@ -509,6 +631,12 @@ def test_metrics_endpoint_renders_live_engine(tmp_path):
             assert samples["serve_completed_total"] >= 1
             assert samples["serve_e2e_latency_count"] >= 1
             assert 'serve_e2e_latency{quantile="0.99"}' in samples
+            # the dispatcher's phases (PR 25) beside serve_device_time
+            for phase in ("wait", "fill_window", "pack", "device_put",
+                          "resolve"):
+                assert f"serve_{phase}_time_count" in samples, phase
+            for phase in ("pack", "device_put", "device", "resolve"):
+                assert samples[f"serve_{phase}_time_count"] >= 1, phase
         finally:
             server.shutdown()
             server.server_close()

@@ -715,3 +715,133 @@ def test_serve_saturation_throughput_vs_sequential():
     # saturation actually filled the big buckets (each backlogged
     # 256-request burst over a max-64 ladder -> 4 full batches)
     assert burst_rows / burst_batches > 32
+
+
+# ------------------------------------------- the dispatcher's phases
+
+_PHASES = ("wait", "fill_window", "pack", "device_put", "device",
+           "resolve")
+# what /stats held before the phases came (PR 25 adds keys, loses none)
+_STATS_KEYS = ("models", "pipelines", "buckets", "warmup_s", "health",
+               "queue", "cache", "tenancy", "warmed_from_store",
+               "telemetry")
+_TELEMETRY_KEYS = (
+    "submitted", "completed", "timed_out", "failed", "shed", "batches",
+    "rows", "padded_rows", "dispatcher_crashes", "dispatcher_restarts",
+    "pad_overhead_frac", "mean_batch_rows", "queue_wait", "device_time",
+    "e2e_latency")
+
+
+def _lenet_phase_run():
+    """A lenet engine under the ring's sink: six full bucket-16 batches
+    back to back out of a backlog, an idle stretch, then three requests
+    one at a time. -> (the dispatcher thread's phase spans in order,
+    the engine's telemetry, its /stats)."""
+    from deepvision_tpu.obs.metrics import Registry
+    from deepvision_tpu.obs.trace import get_tracer
+    from deepvision_tpu.serve import InferenceEngine, ServeTelemetry
+    from deepvision_tpu.serve.models import load_served
+
+    served = load_served("lenet5", None, num_classes=10, top_k=5)
+    xs = np.random.default_rng(0).normal(
+        size=(16, 32, 32, 1)).astype(np.float32)
+    records = []
+    tracer = get_tracer()
+    tracer.add_sink(records.append)     # before the dispatcher starts
+    try:
+        with InferenceEngine(
+                [served], buckets=(4, 16), batch_window_s=0.002,
+                telemetry=ServeTelemetry(registry=Registry())) as eng:
+            called = []
+            eng.pause()
+            futs = [eng.submit(xs[i % 16]) for i in range(96)]
+            for f in futs:  # a client's callback runs inside `resolve`
+                f.add_done_callback(lambda _f: called.append(1))
+            eng.resume()
+            for f in futs:
+                f.result(timeout=120)
+            time.sleep(0.3)             # nothing offered: `wait`
+            for i in range(3):
+                eng.submit(xs[i]).result(timeout=120)
+            stats = eng.stats()
+            tel = eng.telemetry
+        assert len(called) == 96
+    finally:
+        tracer.remove_sink(records.append)
+    spans = sorted((r for r in records if r["tname"] == "serve-dispatch"
+                    and r["cat"] == "serve" and r["name"] in _PHASES),
+                   key=lambda r: r["ts"])
+    return spans, tel, stats
+
+
+@pytest.fixture(scope="module")
+def lenet_phases():
+    return _lenet_phase_run()
+
+
+def test_phases_are_flat_consecutive_and_in_order(lenet_phases):
+    import re
+
+    spans, tel, _ = lenet_phases
+    for a, b in zip(spans, spans[1:]):
+        assert b["ts"] >= a["ts"] + a["dur"] - 1e-9, (a, b)  # none nested
+    letters = "".join({"wait": "w", "fill_window": "f", "pack": "p",
+                       "device_put": "u", "device": "d",
+                       "resolve": "r"}[s["name"]] for s in spans)
+    # every batch: the window held open, then pack, put, device,
+    # resolve; `wait` only between batches
+    assert re.fullmatch(r"w*(fpudrw*)+", letters), letters
+    assert letters.count("p") == tel.batches == 9
+    assert "r" + "fpudr" * 5 in letters     # the backlog: no wait between
+    assert "rw" in letters and "wfpudr" in letters
+    by = {s["name"]: s for s in spans}
+    assert by["pack"]["args"] == {"model": "lenet5", "bucket": 16,
+                                  "rows": 16} or by["pack"]["args"][
+        "bucket"] == 4
+    assert set(by["device_put"]["args"]) == {"bucket"}
+    assert set(by["device"]["args"]) == {"model", "bucket", "rows"}
+    assert set(by["resolve"]["args"]) == {"rows"}
+
+
+def test_phases_cover_the_dispatchers_time_but_for_one_percent(
+        lenet_phases):
+    """From the first batch's `pack` to the last `resolve` the phases
+    leave under 1% of the dispatcher thread's time between them. Up to
+    three runs: what lies between two phases is tens of microseconds
+    here, but one preemption of the thread there reads as the
+    engine's."""
+    shares = []
+    for run in (lambda: lenet_phases, _lenet_phase_run, _lenet_phase_run):
+        spans = run()[0]
+        first = next(i for i, s in enumerate(spans) if s["name"] == "pack")
+        last = max(i for i, s in enumerate(spans)
+                   if s["name"] == "resolve")
+        cut = spans[first:last + 1]
+        extent = cut[-1]["ts"] + cut[-1]["dur"] - cut[0]["ts"]
+        shares.append(1.0 - sum(s["dur"] for s in cut) / extent)
+        if shares[-1] < 0.01:
+            break
+    assert min(shares) < 0.01, shares
+
+
+def test_phase_histograms_are_the_spans_own_measurements(lenet_phases):
+    spans, tel, _ = lenet_phases
+    for name, hist in tel.phase_time.items():
+        durs = [s["dur"] for s in spans if s["name"] == name]
+        assert hist.count == len(durs) > 0, name
+        assert hist.total_s == pytest.approx(sum(durs), rel=1e-12), name
+    durs = [s["dur"] for s in spans if s["name"] == "device"]
+    assert tel.device_time.count == len(durs) == tel.batches
+    assert tel.device_time.total_s == pytest.approx(sum(durs), rel=1e-12)
+    assert {f"serve_{p}_time" for p in _PHASES if p != "device"} \
+        | {"serve_device_time"} <= set(tel.registry.names())
+
+
+def test_stats_holds_every_key_it_held(lenet_phases):
+    _, _, stats = lenet_phases
+    assert list(stats)[:len(_STATS_KEYS)] == list(_STATS_KEYS)
+    assert list(stats["telemetry"])[:len(_TELEMETRY_KEYS)] == list(
+        _TELEMETRY_KEYS)
+    for p in _PHASES:
+        if p != "device":
+            assert stats["telemetry"][f"{p}_time"]["count"] > 0

@@ -3,22 +3,20 @@
 Usage: python tools/profile_step.py [model] [batch_per_chip] [steps]
 
 Captures a ``jax.profiler`` trace of the compiled train step running
-device-resident synthetic batches, then parses the XPlane protobuf
-directly (no TensorBoard needed) and prints the top ops by self time on
-the TPU op plane — the per-op breakdown VERDICT r2 asked for. Also prints
-the step's XLA cost analysis (flops, HBM bytes) and the arithmetic
-intensity so compute- vs memory-bound is attributable at a glance.
+device-resident synthetic batches, reduces it with the benchmark's own
+trace reduction (``benchmark/reduce/xplane.py``; no TensorBoard, no
+TensorFlow) and prints the top operations by device time per step — the
+per-op breakdown VERDICT r2 asked for. Also prints the step's XLA cost
+analysis (flops, HBM bytes) and the arithmetic intensity so compute- vs
+memory-bound is attributable at a glance.
 """
 
 from __future__ import annotations
 
-import glob
-import os
 import json
+import os
 import sys
 import time
-from collections import defaultdict
-from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -56,37 +54,23 @@ def build(model_name: str, batch: int):
     return state, db, compiled
 
 
-def parse_xplane(trace_dir: str, top: int = 25):
-    """Aggregate self-times per op on the TPU xplanes."""
-    try:
-        from tensorflow.tsl.profiler.protobuf import xplane_pb2
-    except ImportError:
-        from tensorflow.core.profiler.protobuf import xplane_pb2
+def print_device_ops(trace_dir: str, steps: int, top: int = 25) -> None:
+    """The trace through the benchmark's own reduction
+    (``benchmark/reduce/xplane.py``: busy time as the union of the
+    ``XLA Ops`` intervals, nothing after ``stop_trace``; no TensorFlow):
+    one JSON line of busy/window/matrix-unit seconds, then the ``top``
+    operations by summed device time, per step."""
+    from benchmark.reduce import xplane
 
-    paths = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
-    if not paths:
-        print("no xplane.pb found under", trace_dir)
+    path = xplane.newest_xplane(trace_dir)
+    reduced = xplane.reduce(path, top=top) if path else {"chips": 0}
+    if not reduced["chips"]:
+        print("no device operation in a trace under", trace_dir)
         return
-    xspace = xplane_pb2.XSpace()
-    xspace.ParseFromString(Path(sorted(paths)[-1]).read_bytes())
-    for plane in xspace.planes:
-        if "TPU" not in plane.name and "/device:" not in plane.name:
-            continue
-        ev_meta = {m.id: m.name for m in plane.event_metadata.values()}
-        by_line = defaultdict(lambda: (defaultdict(float), defaultdict(int)))
-        for line in plane.lines:
-            totals, counts = by_line[line.name]
-            for ev in line.events:
-                name = ev_meta.get(ev.metadata_id, "?")
-                totals[name] += ev.duration_ps / 1e6  # -> us
-                counts[name] += 1
-        for lname, (totals, counts) in by_line.items():
-            if not totals:
-                continue
-            print(f"\n== plane: {plane.name} line: {lname!r} "
-                  f"(total {sum(totals.values())/1e3:.2f} ms) ==")
-            for name, us in sorted(totals.items(), key=lambda kv: -kv[1])[:top]:
-                print(f"  {us/1e3:9.3f} ms  x{counts[name]:<5d}  {name[:140]}")
+    print(json.dumps({k: reduced[k] for k in
+                      ("chips", "busy_s", "window_s", "conv_s")}))
+    for name, seconds in reduced["device_ops"]:
+        print(f"  {seconds * 1e3 / steps:9.3f} ms/step  {name}")
 
 
 def main():
@@ -129,7 +113,7 @@ def main():
         "img_per_sec_per_chip": batch * n * steps / dt / n,
         "mfu": round(flops * steps / dt / peak, 4),
     }))
-    parse_xplane(trace_dir)
+    print_device_ops(trace_dir, steps)
 
 
 if __name__ == "__main__":
