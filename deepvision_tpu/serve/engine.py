@@ -186,6 +186,10 @@ class InferenceEngine:
         self._pending: dict[str, list[_Request]] = {
             name: [] for name in self._models}
         self._in_flight: list[_Request] = []
+        # packed host batches, kept for the engine's life (`_stage`):
+        # (bucket, input shape, input dtype) -> [buffer, rows the last
+        # batch in it wrote]
+        self._staging: dict[tuple, list] = {}
         self._recovering = threading.Event()
         # guards _recover_until: written by the supervisor thread,
         # read by health() probes from any thread (jaxlint JX118 — the
@@ -219,7 +223,7 @@ class InferenceEngine:
                 # miss is a hidden request-time compile — fail loudly
                 self._cache.freeze()
         self._thread = threading.Thread(
-            target=self._supervise, name="serve-dispatch", daemon=True
+            target=self._dispatcher_main, name="serve-dispatch", daemon=True
         )
         self._thread.start()
 
@@ -701,6 +705,16 @@ class InferenceEngine:
         self._q.put(_WAKE)
 
     # -- dispatcher ------------------------------------------------------
+    def _dispatcher_main(self) -> None:
+        """The dispatcher thread's body: the supervised loop, and on its
+        way out (``close()`` joins it) the staging buffers go with the
+        only thread that ever touched them."""
+        try:
+            self._supervise()
+        finally:
+            self._staging.clear()
+            self.telemetry.stage_bytes.set(0)
+
     def _supervise(self) -> None:
         """Run the dispatch loop under crash supervision: an unexpected
         exception (anything ``_run_batch``'s per-batch containment did
@@ -913,6 +927,50 @@ class InferenceEngine:
                 return b
         return max(self.ladder(served))
 
+    def _stage(self, served, bucket: int, rows: list) -> np.ndarray:
+        """The packed host batch of one dispatch: ``rows`` copied into
+        rows ``0 .. n`` of a staging buffer the engine keeps, rows
+        ``n .. bucket`` exact zeros (pad isolation does not lean on a
+        model's rows being independent). One buffer per ``(bucket,
+        input shape, input dtype)`` — two tenants of one shape share
+        it — made on first use, held until ``close()`` ends the
+        dispatcher (``_dispatcher_main``): a fresh ``np.zeros`` every
+        batch paid page faults on its first touch (4.4 ms a 4.4 MB row
+        of YOLOv3-608; chip run, PR 25) and an unmapping at the batch's
+        end. Only ``n .. <rows the last batch wrote>`` is zeroed again.
+
+        INVARIANT: the buffer handed out is written again by the next
+        ``_stage`` call of its key, so a batch must have its results on
+        the host (or be dead) before the next one packs. The serial
+        dispatcher holds that by construction — ``jax.device_get(
+        runner(xd))`` returns before the next ``pack``, and a crashed
+        loop restarts from a failed batch. Packing batch n+1 while
+        batch n runs needs two buffers a key, not this one shared. The
+        CPU backend's ``device_put`` is zero-copy for an aligned numpy
+        array (``may_alias=False`` does not change that), so ``xd`` can
+        BE this buffer there; no executable forwards its input buffer
+        into an output (a zero-copy buffer cannot be donated), which
+        tests/test_serve.py pins: a result a client holds never shares
+        memory with the buffer."""
+        key = (bucket, tuple(served.input_shape),
+               np.dtype(served.input_dtype))
+        slot = self._staging.get(key)
+        if slot is None:
+            slot = self._staging[key] = [
+                np.zeros((bucket, *served.input_shape),
+                         served.input_dtype), 0]
+            self.telemetry.record_stage(held_bytes=sum(
+                s[0].nbytes for s in self._staging.values()))
+        else:
+            self.telemetry.record_stage()
+        buf, last_n = slot
+        n = slot[1] = len(rows)
+        for i, x in enumerate(rows):
+            buf[i] = x
+        if last_n > n:
+            buf[n:last_n] = 0
+        return buf
+
     def _run_batch(self, served: ServedModel, reqs: list[_Request]) -> None:
         import jax
 
@@ -927,9 +985,7 @@ class InferenceEngine:
         batch_args = {"model": served.name, "bucket": bucket, "rows": n}
         traces = [r.trace for r in reqs if r.trace]
         with self._phase("pack", batch_args) as sp:
-            x = np.zeros((bucket, *served.input_shape), served.input_dtype)
-            for i, r in enumerate(reqs):
-                x[i] = r.x
+            x = self._stage(served, bucket, [r.x for r in reqs])
         t_dispatch = sp.t0
         try:
             with self._phase("device_put", {"bucket": bucket}):
@@ -960,11 +1016,11 @@ class InferenceEngine:
         with self._phase("resolve", {"rows": n}):
             self._resolve_batch(served, reqs, host, bucket, t_dispatch,
                                 sp_dev.dur, traces)
-            # the batch's buffers go inside the phase: unmapping the
-            # packed array of a full YOLOv3-608 bucket (283 MB) held
-            # the dispatcher thread for 13 ms after `resolve` (chip
-            # trace, PR 25)
-            del x, xd, host
+            # the batch's device input and fetched outputs go inside
+            # the phase, so their release is on the cycle's clock; the
+            # packed host array is the engine's staging buffer and
+            # stays (`_stage`)
+            del xd, host
 
     def _resolve_batch(self, served, reqs, host, bucket: int,
                        t_dispatch: float, t_dev: float,
@@ -1092,9 +1148,7 @@ class InferenceEngine:
         batch_args = {"model": served.name, "bucket": bucket, "rows": n}
         traces = [r.trace for r, _f in group if r.trace]
         with self._phase("pack", batch_args):
-            x = np.zeros((bucket, *served.input_shape), served.input_dtype)
-            for i, (r, _f) in enumerate(group):
-                x[i] = r.x
+            x = self._stage(served, bucket, [r.x for r, _f in group])
         try:
             with self._phase("device_put", {"bucket": bucket}):
                 for tn in self._tenant_names(served):

@@ -28,6 +28,7 @@ import threading
 
 from deepvision_tpu.obs.metrics import (
     Counter,
+    Gauge,
     Histogram,
     Registry,
     default_registry,
@@ -87,6 +88,11 @@ _COUNTER_FIELDS = (
     "dispatcher_restarts",
 )
 
+# the engine's staging buffers (``InferenceEngine._stage``): buffers
+# made and batches packed into a kept one. They come after the phase
+# blocks in /stats, with the gauge ``stage_bytes`` (host bytes held)
+_STAGE_FIELDS = ("stage_allocs", "stage_reuses")
+
 # the dispatcher's cycle beside ``device_time``: one histogram a phase
 # (``serve_<phase>_time``), fed by the engine's phase spans, so /stats
 # and /metrics give the cycle's split with no profiler running
@@ -113,7 +119,8 @@ class ServeTelemetry:
         self.registry = reg
         self._lock = threading.Lock()
         self._c = {f: reg.register(f"serve_{f}", Counter())
-                   for f in _COUNTER_FIELDS}
+                   for f in _COUNTER_FIELDS + _STAGE_FIELDS}
+        self.stage_bytes = reg.register("serve_stage_bytes", Gauge())
         self.queue_wait = LatencyStats(   # admitted -> batch dispatch
             hist=reg.register("serve_queue_wait", Histogram()))
         self.device_time = LatencyStats(  # compiled forward, per batch
@@ -158,6 +165,16 @@ class ServeTelemetry:
             self._c["padded_rows"].inc(bucket - rows)
             self.device_time.record(device_s)
 
+    def record_stage(self, *, held_bytes: int | None = None) -> None:
+        """One batch packed into a staging buffer: one just made
+        (``held_bytes``: what the engine then holds) or a kept one."""
+        with self._lock:
+            if held_bytes is None:
+                self._c["stage_reuses"].inc()
+            else:
+                self._c["stage_allocs"].inc()
+                self.stage_bytes.set(held_bytes)
+
     def record_request(self, *, queue_wait_s: float, e2e_s: float) -> None:
         with self._lock:
             self._c["completed"].inc()
@@ -170,9 +187,9 @@ class ServeTelemetry:
         blocks per stage (the serving analog of
         ``FeedTelemetry.summary``) — every key of the pre-obs shape
         (the ``/stats`` contract), then one ``<phase>_time`` block per
-        dispatcher phase."""
+        dispatcher phase, then the staging buffers' counters."""
         with self._lock:
-            vals = {f: c.value for f, c in self._c.items()}
+            vals = {f: self._c[f].value for f in _COUNTER_FIELDS}
             executed = vals["rows"] + vals["padded_rows"]
             return {
                 **vals,
@@ -190,13 +207,15 @@ class ServeTelemetry:
                 "e2e_latency": self.e2e.summary(),
                 **{f"{f}_time": h.summary()
                    for f, h in self.phase_time.items()},
+                **{f: self._c[f].value for f in _STAGE_FIELDS},
+                "stage_bytes": int(self.stage_bytes.value),
             }
 
 
 # attribute-style counter reads (eng.telemetry.batches, .timed_out, ...)
 # are part of the public surface — generate one read-only property per
 # counter field instead of ten hand-rolled copies
-for _f in _COUNTER_FIELDS:
+for _f in _COUNTER_FIELDS + _STAGE_FIELDS:
     setattr(ServeTelemetry, _f,
             property(lambda self, _f=_f: self._c[_f].value))
 del _f

@@ -440,9 +440,12 @@ def test_serve_telemetry_snapshot_keys_and_registry_names():
         "queue_wait", "device_time", "e2e_latency",
     ]
     # then the dispatcher's phases (PR 25): keys gained, none lost
-    assert list(snap)[15:] == [
+    assert list(snap)[15:20] == [
         "wait_time", "fill_window_time", "pack_time", "device_put_time",
         "resolve_time"]
+    # then the staging buffers' counters and gauge (PR 26)
+    assert list(snap)[20:] == [
+        "stage_allocs", "stage_reuses", "stage_bytes"]
     assert snap["pad_overhead_frac"] == 0.25
     # attribute-style reads (engine/tests rely on these)
     assert tel.submitted == 1 and tel.batches == 1 and tel.rows == 3

@@ -845,3 +845,209 @@ def test_stats_holds_every_key_it_held(lenet_phases):
     for p in _PHASES:
         if p != "device":
             assert stats["telemetry"][f"{p}_time"]["count"] > 0
+
+
+# ------------------------------------------- staging buffers (PR 26)
+
+
+def whole_batch_model(name, forward_y, dim=3):
+    """A model whose post-process hands the client a VIEW of the fetched
+    output's row ``i`` (no ``tolist``): what a client holds is the
+    engine's own host array."""
+    from deepvision_tpu.serve import ServedModel
+
+    return ServedModel(
+        name=name, task="classify",
+        forward=lambda variables, x: {"y": forward_y(x)},
+        variables={"w": np.float32(1.0)}, input_shape=(dim,),
+        postprocess=lambda host, i: {"y": np.asarray(host["y"][i])})
+
+
+def batch_sum_model(name="bsum", dim=3):
+    """Every row answers the sum over ALL rows of the executed batch: a
+    stale row left in the padding changes every answer."""
+    import jax.numpy as jnp
+
+    return whole_batch_model(
+        name, lambda x: jnp.broadcast_to(
+            jnp.sum(x, axis=0, keepdims=True), x.shape), dim)
+
+
+def identity_model(name="ident", dim=3):
+    return whole_batch_model(name, lambda x: x, dim)
+
+
+def _one_batch(eng, xs, **submit_kw):
+    """``xs`` through the engine as ONE batch -> each request's ``y``."""
+    eng.pause()
+    futs = [eng.submit(x, **submit_kw) for x in xs]
+    eng.resume()
+    return [f.result(timeout=30)["y"] for f in futs]
+
+
+def _rows(n, dim=3, seed=0):
+    # whole numbers: a float32 sum of them is exact in any order
+    return np.random.default_rng(seed).integers(
+        1, 100, size=(n, dim)).astype(np.float32)
+
+
+def _private_telemetry():
+    from deepvision_tpu.obs.metrics import Registry
+    from deepvision_tpu.serve import ServeTelemetry
+
+    return ServeTelemetry(registry=Registry())
+
+
+@pytest.mark.parametrize("first,then", [(4, 3), (16, 5), (3, 3), (4, 2)])
+def test_padding_is_zero_again_after_a_larger_batch(first, then):
+    """``then`` rows after ``first`` rows in the same staging buffer
+    read exactly what ``then`` rows alone read: rows ``then .. first``
+    were zeroed, and only those had to be."""
+    with make_engine([batch_sum_model()], buckets=(4, 16),
+                     telemetry=_private_telemetry()) as eng:
+        _one_batch(eng, _rows(first, seed=1))
+        xs = _rows(then, seed=2)
+        got = _one_batch(eng, xs)
+        for y in got:
+            np.testing.assert_array_equal(y, xs.sum(axis=0))
+        (slot,) = eng._staging.values()
+        buf, last_n = slot
+        assert last_n == then
+        np.testing.assert_array_equal(buf[:then], xs)
+        assert not buf[then:].any()
+        assert (eng.telemetry.stage_allocs,
+                eng.telemetry.stage_reuses) == (1, 1)
+
+
+def _aligned_like(a, align=64):
+    raw = np.zeros(a.nbytes + align, np.uint8)
+    off = (-raw.ctypes.data) % align
+    out = raw[off:off + a.nbytes].view(a.dtype).reshape(a.shape)
+    out[...] = a
+    return out
+
+
+@pytest.mark.parametrize("aligned", [True, False])
+def test_a_held_result_never_aliases_the_staging_buffer(aligned):
+    """The CPU backend's ``device_put`` is zero-copy for a 64-byte
+    aligned numpy array, so there the device input IS the staging
+    buffer; with an identity model the fetched output must still be
+    memory of its own, or a client's result would change when the next
+    batch is packed."""
+    import jax
+
+    with make_engine([identity_model()], buckets=(4,),
+                     telemetry=_private_telemetry()) as eng:
+        _one_batch(eng, _rows(4, seed=1))
+        (slot,) = eng._staging.values()
+        if aligned:
+            slot[0] = _aligned_like(slot[0])
+            # the hazard is real on this backend: nothing is copied in
+            assert np.shares_memory(
+                np.asarray(jax.device_put(slot[0])), slot[0])
+        xs = _rows(3, seed=2)
+        held = _one_batch(eng, xs)
+        _one_batch(eng, _rows(4, seed=3))
+        _one_batch(eng, _rows(2, seed=4))
+        for y, x in zip(held, xs):
+            assert not np.shares_memory(y, slot[0])
+            np.testing.assert_array_equal(y, x)
+        assert eng.telemetry.stage_reuses == 3
+
+
+def test_stage_counters_over_mixed_sizes_and_close_drops_the_buffers():
+    """One buffer per distinct (bucket, input shape, dtype) — two
+    models of one shape share it — and every other batch a reuse."""
+    tel = _private_telemetry()
+    eng = make_engine(
+        [batch_sum_model("a"), batch_sum_model("b"),
+         batch_sum_model("c", dim=5)], telemetry=tel)
+    try:
+        for n in (3, 4, 1, 16, 2, 5):       # buckets 4 4 1 16 4 16
+            _one_batch(eng, _rows(n, seed=n), model="a")
+        _one_batch(eng, _rows(2), model="b")            # a's bucket-4
+        _one_batch(eng, _rows(2, dim=5), model="c")     # a new shape
+        assert sorted((b, s) for b, s, _d in eng._staging) == [
+            (1, (3,)), (4, (3,)), (4, (5,)), (16, (3,))]
+        assert tel.batches == 8
+        assert (tel.stage_allocs, tel.stage_reuses) == (4, 4)
+        held = 4 * ((1 + 4 + 16) * 3 + 4 * 5)
+        assert tel.stage_bytes.value == held
+        snap = eng.stats()["telemetry"]
+        assert (snap["stage_allocs"], snap["stage_reuses"],
+                snap["stage_bytes"]) == (4, 4, held)
+        reg = tel.registry
+        assert reg.value_of("serve_stage_allocs") == 4
+        assert reg.value_of("serve_stage_reuses") == 4
+        assert reg.value_of("serve_stage_bytes") == held
+    finally:
+        eng.close()
+    assert eng._staging == {}
+    assert tel.stage_bytes.value == 0
+    assert (tel.stage_allocs, tel.stage_reuses) == (4, 4)
+
+
+def _pipeline_traffic():
+    sys.path.insert(0, str(Path(__file__).parent))
+    from test_pipeline import entry_image, make_pipe_engine
+
+    eng, _pipe = make_pipe_engine(telemetry=_private_telemetry())
+    return eng, [lambda i=i: eng.submit(entry_image(i), model="detpose")
+                 for i in range(6)]
+
+
+def _stateful_traffic(tmp_path):
+    sys.path.insert(0, str(Path(__file__).parent))
+    from test_sessions import frame, tracking_engine
+
+    eng, _store = tracking_engine(tmp_path)
+    rng = np.random.default_rng(0)
+    return eng, [lambda s=s, q=q: eng.submit(
+        frame(rng), model="track", session=f"s{s}", seq=q)
+        for q in range(5) for s in range(3)]
+
+
+@pytest.mark.parametrize("kind", ["pipeline", "stateful"])
+def test_pipelines_and_stateful_models_pack_through_the_same_helper(
+        kind, tmp_path):
+    eng, sends = (_pipeline_traffic() if kind == "pipeline"
+                  else _stateful_traffic(tmp_path))
+    with eng:
+        tel = eng.telemetry
+        before = (tel.stage_allocs, tel.stage_reuses, tel.batches)
+        for send in sends:
+            send().result(timeout=60)
+        allocs, reuses, batches = (
+            a - b for a, b in zip(
+                (tel.stage_allocs, tel.stage_reuses, tel.batches), before))
+        assert allocs == len(eng._staging) >= 1
+        assert reuses >= 1
+        assert allocs + reuses == batches
+
+
+def test_batch_after_a_dispatcher_crash_packs_over_the_dead_one():
+    """Dispatch 1 crashes between a 4-row batch and a 2-row one: the
+    restarted loop packs into the same buffer and its padding is
+    zero."""
+    from deepvision_tpu.resilience import FaultInjector
+
+    with make_engine([batch_sum_model()], buckets=(4,),
+                     fault_injector=FaultInjector("crash@1"),
+                     restart_backoff_s=0.02,
+                     telemetry=_private_telemetry()) as eng:
+        _one_batch(eng, _rows(4, seed=1))
+        eng.pause()
+        doomed = [eng.submit(x) for x in _rows(3, seed=2)]
+        eng.resume()
+        for f in doomed:
+            with pytest.raises(RuntimeError, match="dispatcher crashed"):
+                f.result(timeout=30)
+        deadline = time.monotonic() + 30
+        while eng.telemetry.dispatcher_restarts < 1:
+            assert time.monotonic() < deadline
+            time.sleep(0.01)
+        xs = _rows(2, seed=3)
+        for y in _one_batch(eng, xs):
+            np.testing.assert_array_equal(y, xs.sum(axis=0))
+        assert (eng.telemetry.stage_allocs,
+                eng.telemetry.stage_reuses) == (1, 1)
