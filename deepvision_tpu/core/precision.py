@@ -26,6 +26,13 @@ training surface threads through here is:
   surfaces with wide dynamic range (heatmap MSE, GAN couplings), not as
   the fp16 necessity.
 
+- **float32 islands under bf16 compute** (the token models,
+  ``models/transformer.py``): a multiply takes compute-dtype operands
+  and accumulates in f32 (:func:`compute_dot`, :func:`compute_einsum`);
+  norms, every softmax, the router's logits (:func:`float32_dot`: f32
+  operands at ``Precision.HIGHEST``, because the top-k behind them is
+  discrete), the indexer's scores and the loss are kept in f32.
+
 Models take ``dtype``/``param_dtype`` in the Flax convention so tests
 can force full f32 for parity checks against the PyTorch reference.
 Per-model remat policies (the other half of the HBM diet) are declared
@@ -120,6 +127,29 @@ class DynamicLossScale:
                              jnp.zeros((), jnp.int32))
         return self.replace(scale=new_scale, good_steps=new_good,
                             last_finite=grads_finite.astype(jnp.float32))
+
+
+def compute_dot(x, w, dtype) -> jax.Array:
+    """``x @ w`` with both operands in the compute ``dtype`` and float32
+    accumulation; the caller casts the float32 result where it stores
+    an activation."""
+    return jnp.dot(x.astype(dtype), w.astype(dtype),
+                   preferred_element_type=jnp.float32)
+
+
+def compute_einsum(spec: str, a, b, dtype) -> jax.Array:
+    """:func:`compute_dot` for an einsum of two operands."""
+    return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def float32_dot(x, w) -> jax.Array:
+    """``x @ w`` in float32 at ``Precision.HIGHEST`` whatever the compute
+    dtype: for the small products a discrete choice hangs on (router
+    logits), where a bf16 operand would flip choices."""
+    return jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
 
 
 def all_finite(tree) -> jax.Array:

@@ -1,5 +1,5 @@
-"""Hermetic synthetic classification set shared by train.py and
-evaluate.py.
+"""Hermetic synthetic sets shared by train.py and evaluate.py: the
+classification set, and the image-plus-tokens set of the token models.
 
 One generator, used by BOTH CLIs, so the held-out split evaluate.py
 scores is bit-identical to the one train.py held out — the same
@@ -33,3 +33,23 @@ def synthetic_classification(
         imgs[i, :, :, 0] += (labels[i] % 7) * 0.3
     split = max(batch_size, int(n * 0.1))
     return imgs, labels, split
+
+
+def synthetic_vlm(
+    n: int, image_size: int, text_len: int, vocab_size: int,
+    batch_size: int,
+) -> tuple[np.ndarray, np.ndarray, int]:
+    """-> (images, tokens, split) for the vision-language token model:
+    one image at the head of ``text_len`` tokens. Learnable: a sample's
+    tokens count upwards (mod the vocabulary) from a start the image's
+    brightness gives away, so both the next-token loss and the image
+    carry signal. ``[:split]`` is the held-out slice."""
+    r = np.random.default_rng(0)
+    start = r.integers(0, vocab_size, n)
+    step = r.integers(1, 4, n)
+    tokens = ((start[:, None] + step[:, None] * np.arange(text_len))
+              % vocab_size).astype(np.int32)
+    imgs = r.normal(0, 1, (n, image_size, image_size, 3)).astype(np.float32)
+    imgs += (start / vocab_size)[:, None, None, None].astype(np.float32)
+    split = max(batch_size, int(n * 0.1))
+    return imgs, tokens, split

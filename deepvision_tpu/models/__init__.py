@@ -11,6 +11,7 @@ from deepvision_tpu.models import (  # noqa: F401
     mobilenet,
     resnet,
     shufflenet,
+    transformer,
     vgg,
     yolo,
 )

@@ -53,6 +53,7 @@ __all__ = [
     "default_registry",
     "histogram_export",
     "histogram_summary",
+    "record_token_step",
     "render_family",
     "start_exposition_server",
 ]
@@ -421,3 +422,25 @@ def start_exposition_server(port: int, registry: Registry | None = None,
                               name="metrics-exposition")
     thread.start()
     return server, server.server_address[1]
+
+
+# Routing and selection counts of a token-model train step
+# (train/steps.vlm_train_step puts them in the step's metrics): totals as
+# counters, the last step's load as gauges.
+TOKEN_STEP_COUNTERS = ("moe_local_assignments", "moe_dropped",
+                       "dsa_selected_pairs")
+TOKEN_STEP_GAUGES = ("moe_expert_tokens_max", "moe_expert_tokens_mean")
+
+
+def record_token_step(metrics: dict, registry: Registry | None = None
+                      ) -> None:
+    """Fold one fetched step's ``metrics`` (host floats) into the
+    registry; a step without these keys (every conv model's) is left
+    alone."""
+    if TOKEN_STEP_COUNTERS[0] not in metrics:
+        return
+    reg = registry if registry is not None else default_registry()
+    for name in TOKEN_STEP_COUNTERS:
+        reg.counter(name).inc(int(metrics[name]))
+    for name in TOKEN_STEP_GAUGES:
+        reg.gauge(name).set(metrics[name])

@@ -292,6 +292,57 @@ TRAINING_CONFIG: dict[str, dict] = {
         "scheduler_params": {"factor": 0.1, "mode": "max", "patience": 10},
         "total_epochs": 100,
     },
+    # Keye-VL-2.0-30B-A3B (models/transformer.py): a ViT tower, sparse
+    # attention behind a learned indexer, 128 experts top-8. Published
+    # widths; the three entries differ in how much of the model one
+    # process holds. Adam (f32 parameter, gradient and two moments: 16
+    # bytes a parameter), a linear warm-up over 2,000 steps to a constant
+    # 1e-4. Adam's first steps are about lr x sign(gradient) whatever the
+    # gradient's size: with no warm-up, tens of steps at 1e-4 or at 1e-5
+    # on a small resident set built up a part of the stream that every
+    # token shares, and the routing collapsed with it (one chip's experts
+    # drew 5-7 x their share within 35 steps; PERF.md, PR 28).
+    "keye_vl2": {
+        "precision": "bf16",
+        "batch_size": 1,
+        "input_size": 448,
+        "text_len": 7936,
+        "dataset": "vlm",
+        "steps": "vlm",
+        "optimizer": "adam",
+        "optimizer_params": {"lr": 1e-4},
+        "scheduler": "warmup",
+        "scheduler_params": {"warmup_steps": 2000},
+        "total_epochs": 1,
+    },
+    # one chip's share of an 8-chip expert-parallel layer: 16 of 128
+    # experts, 18,992 of 151,936 vocabulary rows, 5 + 6 layers (the
+    # benchmark's keye_vl2_30b_a3b.train_seq8k)
+    "keye_vl2_ep8": {
+        "precision": "bf16",
+        "batch_size": 2,
+        "input_size": 448,
+        "text_len": 7936,
+        "dataset": "vlm",
+        "steps": "vlm",
+        "optimizer": "adam",
+        "optimizer_params": {"lr": 1e-4},
+        "scheduler": "warmup",
+        "scheduler_params": {"warmup_steps": 2000},
+        "total_epochs": 1,
+    },
+    # CPU-sized preset of the same layers (tests, smoke runs)
+    "keye_vl2_tiny": {
+        "precision": "bf16",
+        "batch_size": 8,
+        "input_size": 16,
+        "text_len": 60,
+        "dataset": "vlm",
+        "steps": "vlm",
+        "optimizer": "adam",
+        "optimizer_params": {"lr": 1e-3},
+        "total_epochs": 2,
+    },
 }
 
 

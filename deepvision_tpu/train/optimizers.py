@@ -69,6 +69,8 @@ def make_optimizer(cfg: dict, steps_per_epoch: int):
     elif sched_name == "linear_decay":
         lr = schedules.linear_decay(base_lr, sched_p["total_steps"],
                                     sched_p["decay_start"])
+    elif sched_name == "warmup":
+        lr = schedules.linear_warmup(base_lr, sched_p["warmup_steps"])
     elif sched_name in (None, "constant"):
         lr = base_lr
     else:

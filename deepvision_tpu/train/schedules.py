@@ -9,6 +9,10 @@
   the jitted step never sees Python control flow.
 - LinearDecay for CycleGAN (constant, then linear to 0 —
   ref: CycleGAN/tensorflow/utils.py:5-28).
+- Linear warm-up to a constant peak for the token model (Adam's first
+  steps are about lr x sign(gradient) whatever the gradient's size;
+  without a warm-up the first tens of steps move every weight by the
+  peak rate along one direction).
 """
 
 from __future__ import annotations
@@ -46,6 +50,14 @@ def linear_decay(base_lr: float, total_steps: int, decay_start: int) -> optax.Sc
             0.0, 1.0,
         )
         return base_lr * (1.0 - frac)
+    return schedule
+
+
+def linear_warmup(peak_lr: float, warmup_steps: int) -> optax.Schedule:
+    """Update ``n`` (from 0) takes ``peak_lr * (n + 1) / warmup_steps``,
+    from update ``warmup_steps - 1`` on the peak."""
+    def schedule(count):
+        return peak_lr * jnp.minimum((count + 1) / warmup_steps, 1.0)
     return schedule
 
 

@@ -304,6 +304,70 @@ def centernet_eval_step(state: TrainState, batch: dict) -> dict:
     }
 
 
+def _vlm_losses(out: dict, index_loss_weight: float):
+    """(loss, language loss, alignment loss) from the token model's
+    per-sample results: mean next-token cross-entropy over every text
+    position plus the indexer's alignment loss (summed over layers and
+    positions inside the model) averaged over the sequences."""
+    lm = jnp.mean(out["nll"])
+    index = jnp.mean(out["index_kl"])
+    return lm + index_loss_weight * index, lm, index
+
+
+def vlm_train_step(state: TrainState, batch: dict, key: jax.Array,
+                   index_loss_weight: float = 1.0):
+    """One step of the vision-language token model on {'image',
+    'tokens'} (``models/transformer.KeyeVL2``): next-token loss on the
+    text over the vocabulary the model holds, plus the indexer's
+    alignment loss, which alone reaches the indexer's parameters (its
+    input and its target are detached inside the model).
+
+    ``metrics`` carries the routing and selection counts of the step:
+    ``moe_local_assignments`` (token-expert choices that fell on the
+    experts held here, all layers), ``moe_expert_tokens_max`` / ``_mean``
+    (tokens of the busiest held expert of any layer / of the average
+    one), ``moe_dropped`` (0: the expert layer drops nothing) and
+    ``dsa_selected_pairs`` (query-key pairs attention ran over)."""
+    del key                                     # no dropout in this family
+    inputs = {"image": batch["image"], "tokens": batch["tokens"]}
+
+    def loss_fn(params):
+        out = state.apply_fn({"params": params}, inputs, train=True)
+        loss, lm, index = _vlm_losses(out, index_loss_weight)
+        return state.scale_loss(loss), (loss, lm, index, out)
+
+    (_, (loss, lm, index, out)), grads = jax.value_and_grad(
+        loss_fn, has_aux=True)(state.params)
+    new_state = state.apply_gradients(grads)
+    per_expert = jnp.sum(out["expert_tokens"], 0)        # [layers, held]
+    metrics = {
+        "loss": loss, "lm_loss": lm, "index_loss": index,
+        "moe_local_assignments": jnp.sum(per_expert),
+        "moe_expert_tokens_max": jnp.max(per_expert),
+        "moe_expert_tokens_mean": jnp.mean(per_expert.astype(jnp.float32)),
+        "moe_dropped": jnp.max(out["moe_dropped"]),
+        "dsa_selected_pairs": jnp.sum(out["selected_pairs"]),
+        **precision_metrics(new_state),
+    }
+    return new_state, metrics
+
+
+def vlm_eval_step(state: TrainState, batch: dict,
+                  index_loss_weight: float = 1.0) -> dict:
+    """Count-weighted sums over one batch of {'image', 'tokens'}."""
+    mask = batch.get("mask")
+    if mask is None:
+        mask = jnp.ones(batch["tokens"].shape[0], jnp.float32)
+    out = state.apply_fn(
+        {"params": state.params},
+        {"image": batch["image"], "tokens": batch["tokens"]}, train=False)
+    lm = jnp.mean(out["nll"], -1)
+    return {"loss_sum": jnp.sum((lm + index_loss_weight * out["index_kl"])
+                                * mask),
+            "lm_loss_sum": jnp.sum(lm * mask),
+            "count": jnp.sum(mask)}
+
+
 def aggregate_eval_parts(parts) -> tuple[dict, float]:
     """Sum an iterable of eval-step outputs (count-weighted sums + a
     'count' key) into ``(val_* means, total count)`` — the one masked

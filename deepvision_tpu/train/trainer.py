@@ -37,6 +37,7 @@ from deepvision_tpu.core.step import (
     compile_train_step,
 )
 from deepvision_tpu.data.prefetch import DevicePrefetcher, FeedTelemetry
+from deepvision_tpu.obs.metrics import record_token_step
 from deepvision_tpu.obs.profiler import ProfileWindow, sample_memory_gauges
 from deepvision_tpu.obs.trace import span
 from deepvision_tpu.resilience.recovery import (
@@ -181,10 +182,14 @@ class Trainer:
         self.tx, self.plateau = make_optimizer(
             config, (steps_per_epoch or 1000) * self.data_echo
         )
-        size = config.get("input_size", 224)
-        sample = np.zeros(
-            (1, size, size, config.get("channels", 3)), np.float32
-        )
+        if hasattr(model, "sample_input"):
+            # token models take a dict of image and tokens
+            sample = model.sample_input()
+        else:
+            size = config.get("input_size", 224)
+            sample = np.zeros(
+                (1, size, size, config.get("channels", 3)), np.float32
+            )
         # numerics policy (core/precision.py): the config's explicit
         # "precision" declaration (train.py resolves CLI > config);
         # a scaling policy attaches the DynamicLossScale to the state
@@ -768,6 +773,7 @@ class Trainer:
                 for step_idx, m in pending:
                     host = {k: float(v) for k, v in m.items()}
                     fetched.append(host)
+                    record_token_step(host)
                     if self._watchdog:
                         self._watchdog.beat()
                     if self.sentinel is not None:

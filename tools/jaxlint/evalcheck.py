@@ -46,6 +46,19 @@ class ModelSpec:
     kwargs: dict = field(default_factory=dict)
     init_rngs: tuple[str, ...] = ("params", "dropout")
     train_rngs: tuple[str, ...] = ("dropout",)
+    # further inputs of a model that takes a dict (the token models):
+    # {name: (shape without the batch dim, dtype)}; ``input_shape`` is
+    # then the dict's "image"
+    extra_inputs: dict = field(default_factory=dict)
+
+    def inputs(self, batch: int):
+        image = jax.ShapeDtypeStruct((batch, *self.input_shape),
+                                     self.input_dtype)
+        if not self.extra_inputs:
+            return image
+        return {"image": image,
+                **{k: jax.ShapeDtypeStruct((batch, *shape), dtype)
+                   for k, (shape, dtype) in self.extra_inputs.items()}}
 
 
 def _config_spec(config_name: str) -> ModelSpec:
@@ -54,6 +67,10 @@ def _config_spec(config_name: str) -> ModelSpec:
     cfg = get_config(config_name)
     size, ch = cfg["input_size"], cfg["channels"]
     kwargs = dict(cfg.get("model_kwargs", {}))
+    if cfg["dataset"] == "vlm":
+        return ModelSpec(
+            input_shape=(size, size, ch), kwargs=kwargs,
+            extra_inputs={"tokens": ((cfg["text_len"],), jnp.int32)})
     if "num_heatmaps" in cfg:
         kwargs["num_heatmaps"] = cfg["num_heatmaps"]
     else:
@@ -111,7 +128,7 @@ def _trace(module, spec: ModelSpec, batch: int):
     batch_stats: outputs must SCALE with the batch dim, running stats
     must be batch-INDEPENDENT."""
     key_struct = jax.ShapeDtypeStruct((), jax.random.key(0).dtype)
-    x = jax.ShapeDtypeStruct((batch, *spec.input_shape), spec.input_dtype)
+    x = spec.inputs(batch)
 
     def init_fn(rngs, xx):
         return module.init(rngs, xx, train=True)

@@ -539,6 +539,41 @@ def _cyclegan_build():
     return build
 
 
+def _vlm_build(cfg_name: str):
+    """Token-model family (models/transformer.py): the tiny preset's
+    geometry and step; the input is a dict of image and tokens, so the
+    state comes from the model's own sample input."""
+
+    def build(batch: int, precision: str | None = None):
+        import jax
+        import numpy as np
+
+        from deepvision_tpu.core.precision import get_policy
+        from deepvision_tpu.models import get_model
+        from deepvision_tpu.train.configs import get_config
+        from deepvision_tpu.train.optimizers import make_optimizer
+        from deepvision_tpu.train.state import create_train_state
+        from deepvision_tpu.train.steps import vlm_train_step
+
+        cfg = get_config(cfg_name)
+        policy = get_policy(precision or cfg["precision"])
+        model = get_model(cfg_name, dtype=policy.compute_dtype,
+                          **cfg.get("model_kwargs", {}))
+        tx, _ = make_optimizer(cfg, steps_per_epoch=100)
+        SDS = jax.ShapeDtypeStruct
+        sample = jax.tree.map(lambda a: SDS(a.shape, a.dtype),
+                              model.sample_input())
+        state = jax.eval_shape(
+            lambda s: create_train_state(model, tx, s, policy=policy),
+            sample)
+        size = cfg["input_size"]
+        batch_sds = {"image": SDS((batch, size, size, 3), np.float32),
+                     "tokens": SDS((batch, cfg["text_len"]), np.int32)}
+        return state, batch_sds, vlm_train_step
+
+    return build
+
+
 def make_cases() -> dict[str, IRCase]:
     """Every registry entry mapped to its real-step lowering case (the
     GAN component models share their trainer's composite case; the
@@ -599,6 +634,14 @@ def make_cases() -> dict[str, IRCase]:
     cases["cyclegan"] = IRCase(
         "cyclegan", ("cyclegan_generator", "cyclegan_discriminator"), 2,
         _cyclegan_build(), "two-phase G+D update, f32 [-1,1] reals")
+    # the three keye_vl2 entries are one module at three sizes: the
+    # tiny preset lowers here, the published widths on the chip
+    # (benchmark cell keye_vl2_30b_a3b.train_seq8k)
+    cases["keye_vl2_tiny"] = IRCase(
+        "keye_vl2_tiny", ("keye_vl2_tiny", "keye_vl2_ep8", "keye_vl2"), 2,
+        _vlm_build("keye_vl2_tiny"),
+        "token model: sparse attention + expert share + ViT tower, "
+        "f32 image wire, int32 tokens")
     return cases
 
 
