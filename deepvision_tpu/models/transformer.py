@@ -34,7 +34,12 @@ fits: queries go in blocks of ``key_block`` against the keys up to the
 block's end and in chunks of ``q_chunk`` inside a block, each chunk
 recomputed on the way back; the selection (``lm/attn/select``) runs
 once, ahead of the chunks, and is kept across the layer's
-recomputation. Named scopes (``vlm/vision``, ``vlm/projector``,
+recomputation. On one TPU chip, where the shapes tile (heads of 128,
+chunks that fill lane rows), the attention itself runs as the Pallas
+kernels of ``ops/dsa_attention.py`` (:func:`kernel_attention`), which
+keep the ``[heads, queries, keys]`` tiles on the chip; everywhere else
+as the XLA form (:func:`sparse_attention`), which the tests hold the
+kernels to. Named scopes (``vlm/vision``, ``vlm/projector``,
 ``lm/attn/proj|indexer|select|sparse``, ``lm/index_loss``,
 ``lm/moe/route|experts``, ``lm/head``) put every device operation's
 ``op_name`` under the part it belongs to.
@@ -43,6 +48,7 @@ recomputation. Named scopes (``vlm/vision``, ``vlm/projector``,
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Sequence
 
@@ -216,10 +222,15 @@ def _blocks(t: int, key_block: int, q_chunk: int):
 def _map_chunks(fn, arrays, b0: int, block: int, chunk: int):
     """``fn(chunk of each array, first query's index)`` over the chunks
     of rows ``b0 .. b0 + block``, one after another."""
+    return lax.map(fn, _chunks_of(arrays, b0, block, chunk))
+
+
+def _chunks_of(arrays, b0: int, block: int, chunk: int):
+    """The chunks of rows ``b0 .. b0 + block`` of each array, stacked,
+    and each chunk's first row's index."""
     n = block // chunk
-    split = lambda a: a[b0:b0 + block].reshape(n, chunk, *a.shape[1:])
-    return lax.map(fn, (tuple(split(a) for a in arrays),
-                        b0 + chunk * jnp.arange(n)))
+    return (tuple(a[b0:b0 + block].reshape(n, chunk, *a.shape[1:])
+                  for a in arrays), b0 + chunk * jnp.arange(n))
 
 
 def selection_thresholds(qi, ki, w, *, topk: int, key_block: int,
@@ -358,6 +369,193 @@ def gathered_attention(q, k, v, qi, ki, w, *, topk: int, dtype):
         target = lax.stop_gradient(jnp.sum(probs, (1, 2))) * (1.0 / heads)
         kl = _alignment_loss(target, picked, live)
     return out.reshape(t, heads * hd).astype(dtype), kl, jnp.sum(live)
+
+
+# The same attention through the Pallas kernels of ops/dsa_attention.py,
+# which keep the [heads, Tq, Tk] tiles on the chip. Which of the two a
+# call site takes is read from the backend and the shapes
+# (:func:`kernel_engages`), and two registry counters say which it was.
+
+
+def _on_one_tpu() -> bool:
+    # a bare pallas_call has no partitioning rule: under a sharded jit
+    # it would force a gather (as ops/lrn.select_lrn_impl)
+    return jax.default_backend() == "tpu" and jax.device_count() == 1
+
+
+def kernel_engages(t: int, heads: int, groups: int, head_dim: int,
+                   key_block: int, q_chunk: int) -> bool:
+    """The kernels take lane-wide heads (128), whole groups of query
+    heads and chunks of queries that fill lane rows, on one TPU chip;
+    everything else is :func:`sparse_attention`'s."""
+    chunk = _blocks(t, key_block, q_chunk)[1]
+    return (_on_one_tpu() and head_dim == 128 and heads % groups == 0
+            and chunk % 128 == 0)
+
+
+def _kernel_forward(q, k, v, qi, ki, w, thr, key_block, q_chunk, dtype):
+    """:func:`sparse_attention` of every sequence, a chunk of queries a
+    kernel call; the indexer's scores, the mask's count and the
+    alignment loss stay XLA's. -> (``[B, T, heads x dim]``, loss ``[B]``,
+    selected pairs ``[B]``, log-sum-exp ``[B, chunks, heads, chunk]``)"""
+    from deepvision_tpu.ops import dsa_attention as dsa
+
+    def sequence(args):
+        q, k, v, qi, ki, w, thr = args
+        t = q.shape[0]
+        block, chunk = _blocks(t, key_block, q_chunk)
+        q, k, v = (a.reshape(t, -1) for a in (q, k, v))
+        outs, lses, kl, pairs = [], [], 0.0, 0
+        for b0 in range(0, t, block):
+            end = b0 + block
+
+            def one(args, end=end):
+                (qc, qic, wc, thr_c), t0 = args
+                scores = index_scores(qic, ki[:end], wc, dtype)
+                with jax.named_scope("lm/attn/sparse"):
+                    o, lse, target = dsa.forward(qc, k[:end], v[:end],
+                                                 scores, thr_c, t0)
+                mask = _causal(t0, chunk, end) & (scores >= thr_c[:, None])
+                with jax.named_scope("lm/index_loss"):
+                    kl_c = _alignment_loss(target, scores, mask)
+                return o, lse, kl_c, jnp.sum(mask)
+
+            o, lse, kl_b, n_b = _map_chunks(one, (q, qi, w, thr), b0, block,
+                                            chunk)
+            outs.append(o.reshape(block, -1))
+            lses.append(lse)
+            kl, pairs = kl + jnp.sum(kl_b), pairs + jnp.sum(n_b)
+        return jnp.concatenate(outs), kl, pairs, jnp.concatenate(lses)
+
+    return lax.map(sequence, (q, k, v, qi, ki, w, thr))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def kernel_attention(q, k, v, qi, ki, w, thr, key_block, q_chunk, dtype):
+    """Sparse attention of a batch through the kernels: arguments as
+    :func:`sparse_attention`'s with a leading batch axis. -> (``[B, T,
+    heads x dim]``, alignment loss ``[B]``, selected pairs ``[B]``).
+
+    The backward is written out (``custom_vjp``): what the forward
+    keeps is the output and each row's log-sum-exp, named ``attn_out``
+    and ``dsa_lse`` so that a recomputed layer keeps them too and never
+    runs the forward kernels twice; chunk by chunk it computes the
+    indexer's scores again (their third time in a step, as in the XLA
+    form), runs the backward kernel, which also returns the alignment
+    target, and pulls the alignment loss's gradient back through the
+    scores. ``dk``, ``dv`` and the indexer keys' cotangent are summed
+    over the chunks in float32."""
+    return _kernel_forward(q, k, v, qi, ki, w, thr, key_block, q_chunk,
+                           dtype)[:3]
+
+
+def _kernel_attention_fwd(q, k, v, qi, ki, w, thr, key_block, q_chunk,
+                          dtype):
+    o, kl, pairs, lse = _kernel_forward(q, k, v, qi, ki, w, thr, key_block,
+                                        q_chunk, dtype)
+    o, lse = checkpoint_name(o, "attn_out"), checkpoint_name(lse, "dsa_lse")
+    return (o, kl, pairs), (q, k, v, qi, ki, w, thr, o, lse)
+
+
+def _kernel_attention_bwd(key_block, q_chunk, dtype, kept, cotangents):
+    from deepvision_tpu.ops import dsa_attention as dsa
+
+    def sequence(args):
+        q, k, v, qi, ki, w, thr, o, lse, do, dkl = args
+        t, heads, _hd = q.shape
+        block, chunk = _blocks(t, key_block, q_chunk)
+        shapes = q.shape, k.shape, v.shape
+        q, k, v = (a.reshape(t, -1) for a in (q, k, v))
+        f32 = jnp.float32
+        dk, dv = jnp.zeros(k.shape, f32), jnp.zeros(v.shape, f32)
+        dki = jnp.zeros(ki.shape, f32)
+        dq, dqi, dw = [], [], []
+        for b0 in range(0, t, block):
+            end = b0 + block
+
+            def one(carry, args, end=end):
+                dk, dv, dki = carry
+                (qc, qic, wc, thr_c, o_c, do_c), t0 = args
+                scores, pull = jax.vjp(
+                    lambda *a: index_scores(*a, dtype), qic, ki[:end], wc)
+                with jax.named_scope("lm/attn/sparse"):
+                    di = jnp.sum((o_c.astype(f32) * do_c.astype(f32)).reshape(
+                        chunk, heads, -1), -1).T
+                    dq_c, dk, dv, target = dsa.backward(
+                        qc, k[:end], v[:end], scores, thr_c, t0,
+                        lse[t0 // chunk], di, do_c, dk, dv)
+                mask = _causal(t0, chunk, end) & (scores >= thr_c[:, None])
+                with jax.named_scope("lm/index_loss"):
+                    dscores = dkl * jax.grad(_alignment_loss, 1)(
+                        target, scores, mask)
+                dqi_c, dki_c, dw_c = pull(dscores)
+                dki = dki.at[:end].add(dki_c.astype(f32))
+                return (dk, dv, dki), (dq_c, dqi_c, dw_c)
+
+            (dk, dv, dki), parts = lax.scan(
+                one, (dk, dv, dki),
+                _chunks_of((q, qi, w, thr, o, do), b0, block, chunk))
+            for out, part in zip((dq, dqi, dw), parts):
+                out.append(part.reshape(block, *part.shape[2:]))
+        dq, dqi, dw = (jnp.concatenate(a) for a in (dq, dqi, dw))
+        return (dq.reshape(shapes[0]), dk.astype(k.dtype).reshape(shapes[1]),
+                dv.astype(v.dtype).reshape(shapes[2]), dqi,
+                dki.astype(ki.dtype), dw)
+
+    q, k, v, qi, ki, w, thr, o, lse = kept
+    do, dkl, _pairs = cotangents
+    grads = lax.map(sequence, (q, k, v, qi, ki, w, thr, o, lse, do, dkl))
+    return (*grads, jnp.zeros_like(thr))
+
+
+kernel_attention.defvjp(_kernel_attention_fwd, _kernel_attention_bwd)
+
+
+def selection_mask(qi, ki, w, thresholds, *, key_block: int, q_chunk: int,
+                   dtype):
+    """``[T, T]``: the pairs :func:`sparse_attention` attends over (what
+    its ``capture`` returns), for the path whose kernels form the mask
+    a tile at a time and never hold it."""
+    t = qi.shape[0]
+    block, chunk = _blocks(t, key_block, q_chunk)
+    rows = []
+    for b0 in range(0, t, block):
+        end = b0 + block
+
+        def one(args, end=end):
+            (qic, wc, thr), t0 = args
+            scores = index_scores(qic, ki[:end], wc, dtype)
+            return _causal(t0, chunk, end) & (scores >= thr[:, None])
+
+        m = _map_chunks(one, (qi, w, thresholds), b0, block, chunk)
+        rows.append(jnp.pad(m.reshape(block, end), ((0, 0), (0, t - end))))
+    return jnp.concatenate(rows)
+
+
+def batched_sparse_attention(q, k, v, qi, ki, w, thr, *, key_block: int,
+                             q_chunk: int, dtype, capture: bool = False):
+    """:func:`sparse_attention` of a batch, through the kernels where
+    :func:`kernel_engages` and through the XLA form elsewhere; either
+    way the output is named ``attn_out`` for a recomputed layer to
+    keep. -> (``[B, T, heads x dim]``, loss ``[B]``, pairs ``[B]``, and
+    with ``capture`` the masks ``[B, T, T]``)"""
+    from deepvision_tpu.obs.metrics import record_attention_site
+
+    blocks = dict(key_block=key_block, q_chunk=q_chunk, dtype=dtype)
+    by_kernel = kernel_engages(q.shape[1], q.shape[2], k.shape[2],
+                               q.shape[3], key_block, q_chunk)
+    record_attention_site(by_kernel)
+    if not by_kernel:
+        o, kl, pairs, mask = lax.map(lambda a: sparse_attention(
+            *a, capture=capture, **blocks), (q, k, v, qi, ki, w, thr))
+        # kept across the layer's recomputation, like the thresholds:
+        # the way back then recomputes each chunk once, not twice
+        return checkpoint_name(o, "attn_out"), kl, pairs, mask
+    o, kl, pairs = kernel_attention(q, k, v, qi, ki, w, thr, key_block,
+                                    q_chunk, dtype)
+    mask = lax.map(lambda a: selection_mask(*a, **blocks),
+                   (qi, ki, w, thr)) if capture else None
+    return o, kl, pairs, mask
 
 
 # ------------------------------------------------------ mixture of experts
@@ -699,11 +897,8 @@ class _Attention(nn.Module):
         thr = lax.map(lambda a: selection_thresholds(
             *a, topk=c.topk, **blocks), (qi, ki, w))
         thr = checkpoint_name(thr, "dsa_threshold")
-        o, kl, pairs, mask = lax.map(lambda a: sparse_attention(
-            *a, capture=c.capture, **blocks), (q, k, v, qi, ki, w, thr))
-        # kept across the layer's recomputation, like the thresholds:
-        # the way back then recomputes each chunk once, not twice
-        o = checkpoint_name(o, "attn_out")
+        o, kl, pairs, mask = batched_sparse_attention(
+            q, k, v, qi, ki, w, thr, capture=c.capture, **blocks)
         with jax.named_scope("lm/attn/proj"):
             out = compute_dot(o, wo, dt).astype(dt)
         stats = {"index_kl": kl, "selected_pairs": pairs}
@@ -807,7 +1002,7 @@ class KeyeVL2(nn.Module):
             layer = nn.remat(
                 DecoderLayer, prevent_cse=False,
                 policy=jax.checkpoint_policies.save_only_these_names(
-                    "dsa_threshold", "attn_out"))
+                    "dsa_threshold", "attn_out", "dsa_lse"))
         cfg = LayerConfig(
             self.heads, self.kv_heads, self.head_dim, self.indexer_heads,
             self.indexer_dim, self.topk, self.num_experts,

@@ -444,3 +444,17 @@ def record_token_step(metrics: dict, registry: Registry | None = None
         reg.counter(name).inc(int(metrics[name]))
     for name in TOKEN_STEP_GAUGES:
         reg.gauge(name).set(metrics[name])
+
+
+# Which lowering each sparse-attention call site of a traced token model
+# took (models/transformer.batched_sparse_attention decides while the
+# step is traced, so these count sites of traced programs, not steps).
+ATTENTION_SITE_COUNTERS = ("dsa_kernel_sites", "dsa_xla_sites")
+
+
+def record_attention_site(by_kernel: bool, registry: Registry | None = None
+                          ) -> None:
+    """One call site lowered through the Pallas kernels
+    (``dsa_kernel_sites``) or through the XLA form (``dsa_xla_sites``)."""
+    reg = registry if registry is not None else default_registry()
+    reg.counter(ATTENTION_SITE_COUNTERS[0 if by_kernel else 1]).inc()
