@@ -14,11 +14,22 @@ seed. Every seed thus offers the same number of requests and the same
 set of gaps in another order: Poisson-like bursts, but the work does not
 depend on the seed. A request's latency runs from the moment it was due
 to its completion; how late the generator sent it is reported apart.
+
+A request the engine refuses (``ShedError``) is offered again, as the
+error's ``retry_after_s`` asks of a client: the generator holds at it,
+in order, until an answer has come back or that time has passed, and
+the wait reads as latency. The generator runs in the engine's process,
+so a stall of the whole process makes every request of those seconds
+due at once; a client that took the refusals of that burst for failures
+would report its own stall as the engine's. Only the answers that the
+comparison may read are kept: a server hands an answer over and forgets
+it, and a heap of five thousand kept answers is the client's own.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import gc
 import sys
 import threading
 import time
@@ -64,52 +75,110 @@ def build_engine(cfg: dict, traffic: dict, weights):
 
 
 class _Client:
-    """Submits on schedule and stamps each completion."""
+    """Submits on schedule, offers a refused request again, stamps each
+    completion and keeps the answers at the indices ``keep``."""
 
-    def __init__(self, engine, images, picks):
+    def __init__(self, engine, images, picks, keep):
         from deepvision_tpu.serve.engine import ShedError
 
         self.shed_error = ShedError     # imported outside the window
         self.engine, self.images, self.picks = engine, images, picks
+        self.keep = frozenset(int(i) for i in keep)
         n = len(picks)
         self.done_at = np.full(n, np.nan)
-        self.sent_late = np.zeros(n)
-        self.results: list = [None] * n
-        self.refused = 0
-        self._left = n
-        self._all_done = threading.Event()
-        self._lock = threading.Lock()
+        self.sent_late = np.zeros(n)    # first offer - due, less the holds
+        self.held = np.zeros(n)         # first offer to admission
+        self.refusals = np.zeros(n, np.int64)
+        self.results: dict = {}
+        self.refused = 0                # never admitted by the end
+        self._holds: list = []          # (start, end) of each hold, in order
+        self._answers = 0               # futures resolved, either way
+        self._cond = threading.Condition()
 
     def _finish(self, i, fut):
         t = time.perf_counter()
         exc = fut.exception()
-        with self._lock:
+        with self._cond:
             if exc is None:
                 self.done_at[i] = t
-                self.results[i] = fut.result()
-            self._left -= 1
-            if not self._left:
-                self._all_done.set()
+                if i in self.keep:
+                    self.results[i] = fut.result()
+            self._answers += 1
+            self._cond.notify_all()
 
-    def drive(self, due: np.ndarray, t0: float) -> None:
+    def _held_since(self, t: float) -> float:
+        """Seconds after ``t`` that the generator spent holding refused
+        requests: the engine's doing, not the generator's lateness."""
+        total = 0.0
+        for start, end in reversed(self._holds):
+            if end <= t:
+                break
+            total += end - max(start, t)
+        return total
+
+    def _offer(self, i, give_up_at: float):
+        """-> the request's future, or None where the engine still
+        refuses it at ``give_up_at``."""
+        while True:
+            seen = self._answers    # an answer after this wakes the hold
+            try:
+                return self.engine.submit(self.images[self.picks[i]])
+            except self.shed_error as e:
+                self.refusals[i] += 1
+                now = time.perf_counter()
+                if now >= give_up_at:
+                    return None
+                with self._cond:
+                    self._cond.wait_for(
+                        lambda: self._answers != seen,
+                        min(e.retry_after_s, give_up_at - now))
+
+    def drive(self, due: np.ndarray, t0: float, give_up_at: float) -> None:
         for i, d in enumerate(due):
             wait = t0 + d - time.perf_counter()
             if wait > 0:
                 time.sleep(wait)
-            self.sent_late[i] = time.perf_counter() - (t0 + d)
-            try:
-                fut = self.engine.submit(self.images[self.picks[i]])
-            except self.shed_error:
-                with self._lock:
-                    self.refused += 1
-                    self._left -= 1
-                    if not self._left:
-                        self._all_done.set()
-                continue
+            first = time.perf_counter()
+            self.sent_late[i] = first - (t0 + d) - self._held_since(t0 + d)
+            fut = self._offer(i, give_up_at)
+            if self.refusals[i]:
+                now = time.perf_counter()
+                self.held[i] = now - first
+                self._holds.append((first, now))
+            if fut is None:     # it and everything behind it: never sent
+                self.refused = len(due) - i
+                return
             fut.add_done_callback(lambda f, i=i: self._finish(i, f))
 
     def wait(self, until: float) -> None:
-        self._all_done.wait(max(0.0, until - time.perf_counter()))
+        with self._cond:
+            self._cond.wait_for(
+                lambda: self._answers + self.refused == len(self.picks),
+                max(0.0, until - time.perf_counter()))
+
+
+class _CollectorClock:
+    """Times every garbage collection while it is installed (the
+    window): (start, end, generation). It changes nothing of the
+    collector: the engine's process keeps the one a server has."""
+
+    def __init__(self):
+        self.events: list = []
+        self._start = 0.0
+
+    def __call__(self, phase, info):
+        if phase == "start":
+            self._start = time.perf_counter()
+        else:
+            self.events.append((self._start, time.perf_counter(),
+                                info["generation"]))
+
+    def __enter__(self):
+        gc.callbacks.append(self)
+        return self
+
+    def __exit__(self, *exc):
+        gc.callbacks.remove(self)
 
 
 def _telemetry(engine) -> dict:
@@ -284,18 +353,23 @@ def serve_checks(cfg, got: dict) -> list:
         checks.Check("over_cap_images", float(got["over_cap_images"]), 0.0)]
 
 
-def sample_requests(n_done: np.ndarray, count: int, seed: int) -> list:
-    """Indices of ``count`` finished requests, drawn from the seed."""
-    done = np.flatnonzero(n_done)
-    if len(done) <= count:
-        return done.tolist()
-    rng = np.random.default_rng([int(seed), 2])
-    return sorted(rng.choice(done, size=count, replace=False).tolist())
+def sample_order(n: int, seed: int) -> np.ndarray:
+    """All request indices in the seed's order: the sample is drawn from
+    its front, and the answers at its first ``KEPT_PER_CHECKED x
+    checked_requests`` indices are the ones a window keeps."""
+    return np.random.default_rng([int(seed), 2]).permutation(n)
+
+
+KEPT_PER_CHECKED = 4
 
 
 def window(engine, images, rate: float, seconds: float, seed: int,
-           trace_after: float | None = None, trace_dir: str = "") -> dict:
-    """Offer ``rate`` for ``seconds`` and wait for the answers. With
+           checked: int, trace_after: float | None = None,
+           trace_dir: str = "", grace_s: float = GRACE_S) -> dict:
+    """Offer ``rate`` for ``seconds`` and wait for the answers, at most
+    ``grace_s`` past the close. ``sample`` names the ``checked``
+    answered requests the comparison reads: the first of the seed's
+    order that were answered, among the answers kept. With
     ``trace_after`` the profiler starts that many seconds into the
     window and stops once every answer is in (writing the profile out
     stalls the host for seconds, which inside the window would be read
@@ -306,7 +380,8 @@ def window(engine, images, rate: float, seconds: float, seed: int,
     due = schedule(rate, seconds, seed)
     picks = np.random.default_rng([int(seed), 3]).integers(
         0, len(images), size=len(due))
-    client = _Client(engine, images, picks)
+    kept = sample_order(len(due), seed)[:KEPT_PER_CHECKED * checked]
+    client = _Client(engine, images, picks, kept)
     counted = {}
     tracer = None
     if trace_after is not None:
@@ -321,12 +396,13 @@ def window(engine, images, rate: float, seconds: float, seed: int,
 
         tracer = threading.Timer(trace_after, start)
     before = _telemetry(engine)
-    t0 = time.perf_counter()
-    if tracer is not None:
-        tracer.start()
-    client.drive(due, t0)
-    client.wait(t0 + seconds + GRACE_S)
-    t_end = time.perf_counter()
+    with _CollectorClock() as collections:
+        t0 = time.perf_counter()
+        if tracer is not None:
+            tracer.start()
+        client.drive(due, t0, t0 + seconds + grace_s)
+        client.wait(t0 + seconds + grace_s)
+        t_end = time.perf_counter()
     if tracer is not None:
         tracer.join()
         jax.profiler.stop_trace()
@@ -336,15 +412,50 @@ def window(engine, images, rate: float, seconds: float, seed: int,
     last_done = done[-1] if len(done) else t_end
     latency = np.where(answered, client.done_at - (t0 + due),
                        t_end - (t0 + due))
+    # the two stalls a run names, as intervals: the generator's largest
+    # lateness and the longest the engine went without completing
+    # anything (a sound run's is one dispatcher cycle, a stalled run's
+    # seconds)
+    latest = int(np.argmax(client.sent_late))
+    stalls = [(t0 + due[latest], t0 + due[latest] + client.sent_late[latest])]
+    done_gap_s = 0.0
+    if len(done) > 1:
+        gap = int(np.argmax(np.diff(done)))
+        stalls.append((done[gap], done[gap + 1]))
+        done_gap_s = float(done[gap + 1] - done[gap])
+    gen2 = [(a, b) for a, b, g in collections.events if g == 2]
     return {"due": due, "picks": picks, "answered": answered,
             "latency": latency, "late": client.sent_late,
+            "held": client.held, "refusals": client.refusals,
             "refused": client.refused, "results": client.results,
+            "sample": sorted([int(i) for i in kept if answered[i]][:checked]),
             "window_s": max(seconds, last_done - t0),
-            # the longest the engine went without completing anything: a
-            # sound run's is one dispatcher cycle, a stalled run's seconds
-            "done_gap_s": float(np.diff(done).max()) if len(done) > 1
-            else 0.0,
+            "done_gap_s": done_gap_s,
+            "gc_s_max": max((b - a for a, b, _ in collections.events),
+                            default=0.0),
+            "gc_gen2_count": len(gen2),
+            "gc_gen2_s_max": max((b - a for a, b in gen2), default=0.0),
+            "stall_in_gc": any(a < end and b > start
+                               for start, end in stalls for a, b in gen2),
             "telemetry": {k: after[k] - before[k] for k in after}}
+
+
+def client_notes(w: dict) -> dict:
+    """What the client saw of refusals and of the collector in a
+    window: requests refused at least once, refusals in all, the longest
+    a request was held from its first offer to its admission, requests
+    never admitted by the end (0 in a sound run); the longest
+    collection of any generation, the generation-2 collections, the
+    longest of them, and whether one overlaps the window's largest
+    generator lateness or its longest gap between answers."""
+    return {"refused": w["refused"],
+            "offered_again": int(np.sum(w["refusals"] > 0)),
+            "offers_refused": int(w["refusals"].sum()),
+            "held_ms_max": float(w["held"].max() * 1e3),
+            "gc_ms_max": w["gc_s_max"] * 1e3,
+            "gc_gen2_count": w["gc_gen2_count"],
+            "gc_gen2_ms_max": w["gc_gen2_s_max"] * 1e3,
+            "stall_in_gc": w["stall_in_gc"]}
 
 
 def run(run) -> dict:
@@ -360,6 +471,7 @@ def run(run) -> dict:
     compile_s = run.compiles.seconds
     setup_s = run.setup_seconds()
     w = window(engine, images, traffic["rate"], run.seconds, run.seed,
+               traffic["checked_requests"],
                trace_after=max(0.0, run.seconds - traffic["trace_seconds"])
                if run.trace else None, trace_dir=run.trace_dir)
     compiles_in_window = run.compiles.count - compiles_before
@@ -368,23 +480,26 @@ def run(run) -> dict:
                                     w["late"])
     limit_s = traffic["limit_ms"] / 1e3
     good = int(np.sum(answered & (latency <= limit_s)))
+    client = client_notes(w)
     print(f"[generator] requests {len(due)} late_ms p50 "
           f"{np.median(late) * 1e3:.3f} p99 "
           f"{np.percentile(late, 99) * 1e3:.3f} max {late.max() * 1e3:.3f} "
-          f"refused {w['refused']} longest_gap_between_answers_ms "
-          f"{w['done_gap_s'] * 1e3:.1f}", file=sys.stderr, flush=True)
+          + " ".join(f"{k} {v:.1f}" if isinstance(v, float) else f"{k} {v}"
+                     for k, v in client.items())
+          + f" longest_gap_between_answers_ms {w['done_gap_s'] * 1e3:.1f}",
+          file=sys.stderr, flush=True)
 
     memory = memory_peak_bytes(jax.devices()[:chips])
-    chosen = sample_requests(answered, traffic["checked_requests"], run.seed)
+    chosen = w["sample"]
     results = [w["results"][i] for i in chosen]
     engine.close()
     del engine, served
 
     got = judge(cfg, ref, jax.device_put(host_weights), images,
                 [int(w["picks"][i]) for i in chosen], results)
-    result_checks = serve_checks(cfg, got) + [checks.Check(
-        "unanswered", float(len(due) - int(answered.sum()) - w["refused"]),
-        0.0)]
+    failed = int(len(due) - answered.sum())     # due and never answered
+    result_checks = serve_checks(cfg, got) + [
+        checks.Check("unanswered", float(failed), 0.0)]
 
     d = w["telemetry"]
     window_s = w["window_s"]
@@ -396,7 +511,7 @@ def run(run) -> dict:
             "serve_goodput": good / run.seconds / chips,
             "setup_s": setup_s},
         "attempted": len(due),
-        "failed": int(len(due) - answered.sum()),
+        "failed": failed,
         "checks": result_checks,
         "memory_peak_bytes": memory,
         "compiles_in_window": compiles_in_window,
@@ -411,7 +526,7 @@ def run(run) -> dict:
             if d["batches"] else None},
         "compile_s": compile_s,
         "notes": {
-            "requests": len(due), "good": good, "refused": w["refused"],
+            "requests": len(due), "good": good, **client,
             "window_s": window_s,
             "latency_ms_p50": float(np.median(latency) * 1e3),
             "latency_ms_p99": float(np.percentile(latency, 99) * 1e3),
@@ -435,7 +550,11 @@ def calibrate(cell, seeds, *, control: bool, seconds, sweep=(), **_):
     configuration's control numerics put in the program's place and
     judged by the same checks, which it has to fail. ``sweep`` offers
     each of its rates for ``seconds`` on the first seed's engine: the
-    knee the cell's rate is four fifths of."""
+    knee the cell's rate is four fifths of. Above the knee the engine
+    refuses and the client offers again, so nothing is lost and the
+    window lengthens (``window_s``) until the last held request is
+    answered: the knee is ``done_per_s``, completions over that longer
+    window."""
     import jax
 
     from benchmark.harness import cells
@@ -446,10 +565,12 @@ def calibrate(cell, seeds, *, control: bool, seconds, sweep=(), **_):
         engine, served, host_weights, images = bring_up(cfg, traffic, ref,
                                                         seed)
         for rate in sweep if n == 0 else ():
-            w = window(engine, images, rate, seconds, seed)
+            w = window(engine, images, rate, seconds, seed,
+                       traffic["checked_requests"])
             d, lat = w["telemetry"], w["latency"] * 1e3
             yield {"reading": "sweep", "rate": rate,
-                   "requests": len(w["due"]), "refused": w["refused"],
+                   "requests": len(w["due"]), **client_notes(w),
+                   "window_s": float(w["window_s"]),
                    "p50_ms": float(np.median(lat)),
                    "p95_ms": float(np.percentile(lat, 95)),
                    "max_ms": float(lat.max()),
@@ -457,11 +578,10 @@ def calibrate(cell, seeds, *, control: bool, seconds, sweep=(), **_):
                    "rows_per_batch": d["rows"] / max(1, d["batches"]),
                    "device_ms_per_batch": 1e3 * d["device_s"]
                    / max(1, d["batches"])}
-        w = window(engine, images, traffic["rate"], seconds, seed)
-        chosen = sample_requests(w["answered"], traffic["checked_requests"],
-                                 seed)
-        picks = [int(w["picks"][i]) for i in chosen]
-        answers = {"program": [w["results"][i] for i in chosen]}
+        w = window(engine, images, traffic["rate"], seconds, seed,
+                   traffic["checked_requests"])
+        picks = [int(w["picks"][i]) for i in w["sample"]]
+        answers = {"program": [w["results"][i] for i in w["sample"]]}
         engine.close()
         del engine, served
         variables = jax.device_put(host_weights)

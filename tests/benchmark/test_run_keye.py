@@ -33,7 +33,7 @@ def test_the_cell_in_benchmark_json_loads_with_its_files():
     assert [m["name"] for m in cell.end_to_end] == ["train_img_per_s",
                                                     "setup_s"]
     reported = {m["name"] for m in cell.per_layer}
-    assert reported == {
+    assert reported >= {     # a later metric of this cell adds a name
         "compile_s", "step_mfu_pct.train", "conv_time_pct.train",
         "device_idle_pct.train", "indexer_time_pct.train",
         "select_time_pct.train", "attn_time_pct.train",

@@ -1,6 +1,10 @@
 """The whole of a serving run but the look for a chip, on the CPU at a toy
 image size: the result line, and the timed path broken underneath."""
 
+import functools
+import itertools
+from concurrent.futures import Future
+
 import pytest
 
 import bench_helpers  # noqa: F401  puts the checkout on sys.path
@@ -27,7 +31,12 @@ def test_serve_result_line_has_the_contracts_keys(sound_serve):
     assert result["attempted"] == 20 and result["failed"] == 0
     assert set(result["metrics"]) == {"serve_p95_ms", "serve_goodput",
                                       "setup_s"}
-    assert result["notes"]["served_detections"] > 0
+    notes = result["notes"]
+    assert notes["served_detections"] > 0
+    assert notes["refused"] == notes["offered_again"] == 0
+    assert notes["offers_refused"] == 0 and notes["held_ms_max"] == 0.0
+    assert {"gc_ms_max", "gc_gen2_count", "gc_gen2_ms_max",
+            "stall_in_gc"} <= set(notes)
 
 
 def test_an_answer_altered_where_it_is_produced_is_not_correct(
@@ -52,6 +61,65 @@ def test_an_answer_altered_where_it_is_produced_is_not_correct(
     result = execute(cell, seconds=1.0)
     assert result["correct"] is False
     assert not result["checks"]["det_gap_p99"]["ok"]
+
+
+def _with_submit(monkeypatch, wrap):
+    """``build_engine`` whose engine's ``submit`` is ``wrap(submit)``
+    once the warm-up is over (one request a row of every bucket); the
+    wrapped one takes the offer's number, from 1."""
+    real = serve_open_loop.build_engine
+
+    def build(cfg, traffic, weights):
+        engine, served = real(cfg, traffic, weights)
+        submit, calls = engine.submit, itertools.count(1)
+        wrapped, offers = wrap(submit), itertools.count(1)
+
+        def counted(x):
+            warm = next(calls) <= sum(traffic["buckets"])
+            return submit(x) if warm else wrapped(x, next(offers))
+
+        engine.submit = counted
+        return engine, served
+
+    monkeypatch.setattr(serve_open_loop, "build_engine", build)
+
+
+def test_a_request_refused_and_admitted_later_is_no_failed_operation(
+        sound_serve, monkeypatch):
+    from deepvision_tpu.serve.engine import ShedError
+
+    cell, _ = sound_serve
+
+    def refuse_every_other(submit):
+        def offer(x, n):
+            if n % 2:
+                raise ShedError("planted", 0.01)
+            return submit(x)
+        return offer
+
+    _with_submit(monkeypatch, refuse_every_other)
+    result = execute(cell, seconds=1.0)
+    assert result["correct"] is True
+    assert result["attempted"] == 20 and result["failed"] == 0
+    notes = result["notes"]
+    assert notes["offered_again"] == notes["offers_refused"] == 20
+    assert notes["refused"] == 0 and notes["held_ms_max"] > 0
+
+
+def test_an_admitted_request_that_is_never_answered_is_not_correct(
+        sound_serve, monkeypatch):
+    cell, _ = sound_serve
+
+    def swallow_the_third(submit):
+        return lambda x, n: Future() if n == 3 else submit(x)
+
+    _with_submit(monkeypatch, swallow_the_third)
+    monkeypatch.setattr(serve_open_loop, "window", functools.partial(
+        serve_open_loop.window, grace_s=1.0))
+    result = execute(cell, seconds=1.0)
+    assert result["correct"] is False and result["failed"] == 1
+    assert not result["checks"]["unanswered"]["ok"]
+    assert result["notes"]["refused"] == 0
 
 
 def test_the_bf16_control_in_the_programs_place_is_not_correct(sound_serve):
