@@ -37,9 +37,12 @@ once, ahead of the chunks, and is kept across the layer's
 recomputation. On one TPU chip, where the shapes tile (heads of 128,
 chunks that fill lane rows), the attention itself runs as the Pallas
 kernels of ``ops/dsa_attention.py`` (:func:`kernel_attention`), which
-keep the ``[heads, queries, keys]`` tiles on the chip; everywhere else
-as the XLA form (:func:`sparse_attention`), which the tests hold the
-kernels to. Named scopes (``vlm/vision``, ``vlm/projector``,
+keep the ``[heads, queries, keys]`` tiles on the chip, and a chunk's
+indexer scores as those of ``ops/dsa_indexer.py``
+(:func:`chunk_scores`), which do the same for the indexer's heads;
+everywhere else as the XLA forms (:func:`sparse_attention`,
+:func:`index_scores`), which the tests hold the kernels to. Named
+scopes (``vlm/vision``, ``vlm/projector``,
 ``lm/attn/proj|indexer|select|sparse``, ``lm/index_loss``,
 ``lm/moe/route|experts``, ``lm/head``) put every device operation's
 ``op_name`` under the part it belongs to.
@@ -233,6 +236,97 @@ def _chunks_of(arrays, b0: int, block: int, chunk: int):
                   for a in arrays), b0 + chunk * jnp.arange(n))
 
 
+# One chunk's scores are computed three times a training step (the
+# selection's thresholds, the attention's forward, its backward) and a
+# threshold of the first is compared with ``>=`` in the other two: every
+# call site takes :func:`chunk_scores`, so that the three are the same
+# function of the same bits.
+
+
+def _on_one_tpu() -> bool:
+    # a bare pallas_call has no partitioning rule: under a sharded jit
+    # it would force a gather (as ops/lrn.select_lrn_impl)
+    return jax.default_backend() == "tpu" and jax.device_count() == 1
+
+
+def indexer_engages(chunk: int, heads: int, dim: int) -> bool:
+    """The indexer's kernels (``ops/dsa_indexer.py``) take chunks of
+    queries that fill lane rows and heads of half a lane row or of whole
+    ones, side by side in whole lane rows, on one TPU chip; everything
+    else is :func:`index_scores`'s."""
+    return (_on_one_tpu() and chunk % 128 == 0 and dim % 64 == 0
+            and (heads * dim) % 128 == 0)
+
+
+def chunk_scores(qi, ki, w, t0, dtype):
+    """:func:`index_scores` of one chunk of queries, the first at
+    position ``t0`` among the keys: through the kernels where
+    :func:`indexer_engages` (zeros then stand in the key tiles above the
+    chunk's last query, which no caller takes for causal), through the
+    XLA form elsewhere. A registry counter says which it was."""
+    from deepvision_tpu.obs.metrics import record_indexer_site
+
+    by_kernel = indexer_engages(*qi.shape)
+    record_indexer_site(by_kernel)
+    if not by_kernel:
+        return index_scores(qi, ki, w, dtype)
+    return kernel_scores(qi.astype(dtype), ki.astype(dtype), w, t0)
+
+
+@jax.custom_vjp
+def kernel_scores(qi, ki, w, t0):
+    """``I [Tq, keys]`` by ``ops/dsa_indexer.py``'s forward kernel; its
+    backward is the second kernel, which forms the per-head products
+    again and keeps nothing but the inputs."""
+    from deepvision_tpu.ops import dsa_indexer
+
+    with jax.named_scope("lm/attn/indexer"):
+        return dsa_indexer.forward(qi.reshape(qi.shape[0], -1), ki, w, t0)
+
+
+def _kernel_scores_pull(qi, ki, w, t0, dscores, dki):
+    """-> (``dqi``, ``dw``, float32 ``dki`` with this chunk's part added
+    to its first rows in place)."""
+    from deepvision_tpu.ops import dsa_indexer
+
+    with jax.named_scope("lm/attn/indexer"):
+        dqi, dw, dki = dsa_indexer.backward(
+            qi.reshape(qi.shape[0], -1), ki, w, t0, dscores, dki)
+    return dqi.reshape(qi.shape), dw.astype(w.dtype), dki
+
+
+def _kernel_scores_bwd(kept, dscores):
+    qi, ki, w, t0 = kept
+    dqi, dw, dki = _kernel_scores_pull(
+        qi, ki, w, t0, dscores, jnp.zeros(ki.shape, jnp.float32))
+    return dqi, dki.astype(ki.dtype), dw, None
+
+
+def _kernel_scores_fwd(qi, ki, w, t0):
+    return kernel_scores(qi, ki, w, t0), (qi, ki, w, t0)
+
+
+kernel_scores.defvjp(_kernel_scores_fwd, _kernel_scores_bwd)
+
+
+def _scores_and_pull(qi, ki, w, t0, dtype):
+    """:func:`chunk_scores` and its pull ``(dscores, dki) -> (dqi, dw,
+    dki)``: ``dki`` float32 ``[>= keys, dim]``, the sum over the chunks
+    so far, comes back with this chunk's part added to its first
+    rows."""
+    if indexer_engages(*qi.shape):
+        qi, ki = qi.astype(dtype), ki.astype(dtype)
+        return (chunk_scores(qi, ki, w, t0, dtype),
+                functools.partial(_kernel_scores_pull, qi, ki, w, t0))
+    scores, vjp = jax.vjp(lambda *a: chunk_scores(*a, t0, dtype), qi, ki, w)
+
+    def pull(dscores, dki):
+        dqi, dki_c, dw = vjp(dscores)
+        return dqi, dw, dki.at[:ki.shape[0]].add(dki_c.astype(jnp.float32))
+
+    return scores, pull
+
+
 def selection_thresholds(qi, ki, w, *, topk: int, key_block: int,
                          q_chunk: int, dtype):
     """``[T]``: each query's ``topk``-th largest causal score, ``-inf``
@@ -249,7 +343,7 @@ def selection_thresholds(qi, ki, w, *, topk: int, key_block: int,
 
         def one(args, end=end):
             (qc, wc), t0 = args
-            scores = index_scores(qc, ki[:end], wc, dtype)
+            scores = chunk_scores(qc, ki[:end], wc, t0, dtype)
             with jax.named_scope("lm/attn/select"):
                 masked = jnp.where(_causal(t0, chunk, end), scores, NEG)
                 return kth_largest(masked, topk)
@@ -329,7 +423,7 @@ def sparse_attention(q, k, v, qi, ki, w, thresholds, *, key_block: int,
         @jax.checkpoint
         def one(args, end=end):
             (qc, qic, wc, thr), t0 = args
-            scores = index_scores(qic, ki[:end], wc, dtype)
+            scores = chunk_scores(qic, ki[:end], wc, t0, dtype)
             mask = _causal(t0, chunk, end) & (scores >= thr[:, None])
             o, kl_c = _attend(qc, k[:end], v[:end], scores, mask, dtype)
             return o, kl_c, jnp.sum(mask), (mask if capture else None)
@@ -377,12 +471,6 @@ def gathered_attention(q, k, v, qi, ki, w, *, topk: int, dtype):
 # (:func:`kernel_engages`), and two registry counters say which it was.
 
 
-def _on_one_tpu() -> bool:
-    # a bare pallas_call has no partitioning rule: under a sharded jit
-    # it would force a gather (as ops/lrn.select_lrn_impl)
-    return jax.default_backend() == "tpu" and jax.device_count() == 1
-
-
 def kernel_engages(t: int, heads: int, groups: int, head_dim: int,
                    key_block: int, q_chunk: int) -> bool:
     """The kernels take lane-wide heads (128), whole groups of query
@@ -395,9 +483,9 @@ def kernel_engages(t: int, heads: int, groups: int, head_dim: int,
 
 def _kernel_forward(q, k, v, qi, ki, w, thr, key_block, q_chunk, dtype):
     """:func:`sparse_attention` of every sequence, a chunk of queries a
-    kernel call; the indexer's scores, the mask's count and the
-    alignment loss stay XLA's. -> (``[B, T, heads x dim]``, loss ``[B]``,
-    selected pairs ``[B]``, log-sum-exp ``[B, chunks, heads, chunk]``)"""
+    kernel call; the mask's count and the alignment loss stay XLA's.
+    -> (``[B, T, heads x dim]``, loss ``[B]``, selected pairs ``[B]``,
+    log-sum-exp ``[B, chunks, heads, chunk]``)"""
     from deepvision_tpu.ops import dsa_attention as dsa
 
     def sequence(args):
@@ -411,7 +499,7 @@ def _kernel_forward(q, k, v, qi, ki, w, thr, key_block, q_chunk, dtype):
 
             def one(args, end=end):
                 (qc, qic, wc, thr_c), t0 = args
-                scores = index_scores(qic, ki[:end], wc, dtype)
+                scores = chunk_scores(qic, ki[:end], wc, t0, dtype)
                 with jax.named_scope("lm/attn/sparse"):
                     o, lse, target = dsa.forward(qc, k[:end], v[:end],
                                                  scores, thr_c, t0)
@@ -440,11 +528,11 @@ def kernel_attention(q, k, v, qi, ki, w, thr, key_block, q_chunk, dtype):
     keeps is the output and each row's log-sum-exp, named ``attn_out``
     and ``dsa_lse`` so that a recomputed layer keeps them too and never
     runs the forward kernels twice; chunk by chunk it computes the
-    indexer's scores again (their third time in a step, as in the XLA
-    form), runs the backward kernel, which also returns the alignment
-    target, and pulls the alignment loss's gradient back through the
-    scores. ``dk``, ``dv`` and the indexer keys' cotangent are summed
-    over the chunks in float32."""
+    indexer's scores again (their third time in a step), runs the
+    backward kernel, which also returns the alignment target, and
+    pulls the alignment loss's gradient back through the scores
+    (:func:`_scores_and_pull`). ``dk``, ``dv`` and the indexer keys'
+    cotangent are summed over the chunks in float32."""
     return _kernel_forward(q, k, v, qi, ki, w, thr, key_block, q_chunk,
                            dtype)[:3]
 
@@ -476,8 +564,8 @@ def _kernel_attention_bwd(key_block, q_chunk, dtype, kept, cotangents):
             def one(carry, args, end=end):
                 dk, dv, dki = carry
                 (qc, qic, wc, thr_c, o_c, do_c), t0 = args
-                scores, pull = jax.vjp(
-                    lambda *a: index_scores(*a, dtype), qic, ki[:end], wc)
+                scores, pull = _scores_and_pull(qic, ki[:end], wc, t0,
+                                                dtype)
                 with jax.named_scope("lm/attn/sparse"):
                     di = jnp.sum((o_c.astype(f32) * do_c.astype(f32)).reshape(
                         chunk, heads, -1), -1).T
@@ -488,8 +576,7 @@ def _kernel_attention_bwd(key_block, q_chunk, dtype, kept, cotangents):
                 with jax.named_scope("lm/index_loss"):
                     dscores = dkl * jax.grad(_alignment_loss, 1)(
                         target, scores, mask)
-                dqi_c, dki_c, dw_c = pull(dscores)
-                dki = dki.at[:end].add(dki_c.astype(f32))
+                dqi_c, dw_c, dki = pull(dscores, dki)
                 return (dk, dv, dki), (dq_c, dqi_c, dw_c)
 
             (dk, dv, dki), parts = lax.scan(
@@ -524,7 +611,7 @@ def selection_mask(qi, ki, w, thresholds, *, key_block: int, q_chunk: int,
 
         def one(args, end=end):
             (qic, wc, thr), t0 = args
-            scores = index_scores(qic, ki[:end], wc, dtype)
+            scores = chunk_scores(qic, ki[:end], wc, t0, dtype)
             return _causal(t0, chunk, end) & (scores >= thr[:, None])
 
         m = _map_chunks(one, (qi, w, thresholds), b0, block, chunk)

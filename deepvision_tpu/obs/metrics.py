@@ -446,15 +446,29 @@ def record_token_step(metrics: dict, registry: Registry | None = None
         reg.gauge(name).set(metrics[name])
 
 
-# Which lowering each sparse-attention call site of a traced token model
-# took (models/transformer.batched_sparse_attention decides while the
-# step is traced, so these count sites of traced programs, not steps).
+# Which lowering each call site of a traced token model took
+# (models/transformer.batched_sparse_attention and chunk_scores decide
+# while the step is traced, so these count sites of traced programs, not
+# steps): (through the Pallas kernels, through the XLA form).
 ATTENTION_SITE_COUNTERS = ("dsa_kernel_sites", "dsa_xla_sites")
+INDEXER_SITE_COUNTERS = ("indexer_kernel_sites", "indexer_xla_sites")
+
+
+def _record_site(counters: tuple, by_kernel: bool,
+                 registry: Registry | None) -> None:
+    reg = registry if registry is not None else default_registry()
+    reg.counter(counters[0 if by_kernel else 1]).inc()
 
 
 def record_attention_site(by_kernel: bool, registry: Registry | None = None
                           ) -> None:
-    """One call site lowered through the Pallas kernels
-    (``dsa_kernel_sites``) or through the XLA form (``dsa_xla_sites``)."""
-    reg = registry if registry is not None else default_registry()
-    reg.counter(ATTENTION_SITE_COUNTERS[0 if by_kernel else 1]).inc()
+    """One sparse-attention call site (``dsa_kernel_sites`` or
+    ``dsa_xla_sites``)."""
+    _record_site(ATTENTION_SITE_COUNTERS, by_kernel, registry)
+
+
+def record_indexer_site(by_kernel: bool, registry: Registry | None = None
+                        ) -> None:
+    """One call site of a chunk's indexer scores
+    (``indexer_kernel_sites`` or ``indexer_xla_sites``)."""
+    _record_site(INDEXER_SITE_COUNTERS, by_kernel, registry)

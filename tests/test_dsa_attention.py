@@ -414,3 +414,31 @@ def test_the_kernels_compile_for_v5e_at_the_cells_widths(
         args = (q, kv, kv, scores, thr, t0, rows, rows, q, sums, sums)
     compiled = jax.jit(fn).lower(*args).compile()
     assert f"dsa_attention_{which}" in compiled.as_text()
+
+
+@pytest.mark.parametrize("keys", [2048, 8192])
+@pytest.mark.parametrize("which", ["forward", "backward"])
+def test_the_indexer_kernels_compile_for_v5e_at_the_cells_widths(
+        one_chip, no_compile_cache, which, keys):
+    """``ops/dsa_indexer.py`` (its other tests: test_dsa_indexer.py; the
+    compile is here because one process of a test run describes the
+    chip): 512 queries of 16 indexer heads of 64, bf16, against a key
+    block's first and last extent. The heads are half a lane row wide:
+    every second one is a slice off the lane tiling."""
+    from deepvision_tpu.ops import dsa_indexer as dsi
+
+    tq, heads, dim = 512, 16, 64
+    shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    qi, ki = shape((tq, heads * dim), BF16), shape((keys, dim), BF16)
+    w, t0 = shape((tq, heads), F32), shape((), jnp.int32)
+    if which == "forward":
+        fn = lambda qi, ki, w, t0: dsi.forward(qi, ki, w, t0,
+                                               interpret=False)
+        args = (qi, ki, w, t0)
+    else:
+        fn = lambda qi, ki, w, t0, ds, dki: dsi.backward(
+            qi, ki, w, t0, ds, dki, interpret=False)
+        args = (qi, ki, w, t0, shape((tq, keys), F32),
+                shape((8192, dim), F32))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert f"dsa_indexer_{which}" in compiled.as_text()
