@@ -1,5 +1,6 @@
 """Hermetic synthetic sets shared by train.py and evaluate.py: the
-classification set, and the image-plus-tokens set of the token models.
+classification set, the image-plus-tokens set of the vision-language
+token model and the token set of the text one.
 
 One generator, used by BOTH CLIs, so the held-out split evaluate.py
 scores is bit-identical to the one train.py held out — the same
@@ -45,11 +46,30 @@ def synthetic_vlm(
     brightness gives away, so both the next-token loss and the image
     carry signal. ``[:split]`` is the held-out slice."""
     r = np.random.default_rng(0)
-    start = r.integers(0, vocab_size, n)
-    step = r.integers(1, 4, n)
-    tokens = ((start[:, None] + step[:, None] * np.arange(text_len))
-              % vocab_size).astype(np.int32)
+    start, tokens = _counting_tokens(r, n, text_len, vocab_size)
     imgs = r.normal(0, 1, (n, image_size, image_size, 3)).astype(np.float32)
     imgs += (start / vocab_size)[:, None, None, None].astype(np.float32)
     split = max(batch_size, int(n * 0.1))
     return imgs, tokens, split
+
+
+def synthetic_lm(
+    n: int, text_len: int, vocab_size: int, batch_size: int,
+) -> tuple[np.ndarray, int]:
+    """-> (tokens ``[n, text_len]``, split) for the text token model: a
+    document's ids count upwards (mod the vocabulary) from a seeded
+    start by a seeded step, so the next token follows from the last two.
+    ``[:split]`` is the held-out slice."""
+    _, tokens = _counting_tokens(np.random.default_rng(0), n, text_len,
+                                 vocab_size)
+    return tokens, max(batch_size, int(n * 0.1))
+
+
+def _counting_tokens(r, n: int, text_len: int, vocab_size: int):
+    """-> (each document's first id, ids ``[n, text_len]`` that count
+    upwards from it, mod the vocabulary, by a step of 1 to 3)."""
+    start = r.integers(0, vocab_size, n)
+    step = r.integers(1, 4, n)
+    tokens = ((start[:, None] + step[:, None] * np.arange(text_len))
+              % vocab_size).astype(np.int32)
+    return start, tokens

@@ -7,6 +7,7 @@ from deepvision_tpu.models import (  # noqa: F401
     gan,
     hourglass,
     inception,
+    latent_moe,
     lenet,
     mobilenet,
     resnet,

@@ -648,12 +648,35 @@ def batched_sparse_attention(q, k, v, qi, ki, w, thr, *, key_block: int,
 # ------------------------------------------------------ mixture of experts
 
 
-def route(h, router, *, experts_per_token: int, norm_topk: bool):
-    """-> (chosen experts ``[N, k]``, their gates). Float32 throughout."""
-    probs = jax.nn.softmax(float32_dot(h, router), -1)
-    gates, experts = lax.top_k(probs, experts_per_token)
+def route(h, router, *, experts_per_token: int, norm_topk: bool,
+          scoring: str = "softmax", bias=None, gate_scale: float = 1.0):
+    """-> (chosen experts ``[N, k]``, their gates). Float32 throughout.
+
+    ``scoring`` is the rule that turns the router's logits into scores:
+    ``"softmax"`` over the experts, or ``"sigmoid"`` of each
+    (DeepSeek-V3's, whose renormalisation adds 1e-20 to the sum). With a
+    ``bias [experts]`` the ``k`` largest of ``score + bias`` are chosen
+    and the gates are the chosen experts' scores: the bias moves the
+    choice and never the gate, so no gradient reaches it. ``gate_scale``
+    multiplies the gates."""
+    logits = float32_dot(h, router)
+    if scoring == "softmax":
+        scores, eps = jax.nn.softmax(logits, -1), None
+    elif scoring == "sigmoid":
+        scores, eps = jax.nn.sigmoid(logits), 1e-20
+    else:
+        raise ValueError(f"unknown scoring rule {scoring!r}")
+    if bias is None:
+        gates, experts = lax.top_k(scores, experts_per_token)
+    else:
+        _, experts = lax.top_k(scores + bias.astype(jnp.float32),
+                               experts_per_token)
+        gates = jnp.take_along_axis(scores, experts, -1)
     if norm_topk:
-        gates = gates / jnp.sum(gates, -1, keepdims=True)
+        total = jnp.sum(gates, -1, keepdims=True)
+        gates = gates / (total if eps is None else total + eps)
+    if gate_scale != 1.0:
+        gates = gates * gate_scale
     return experts, gates
 
 
@@ -664,13 +687,15 @@ WINDOW_FACTOR = 2.0
 
 def moe_layer(h, router, gate_w, up_w, down_w, *, experts_per_token: int,
               norm_topk: bool, expert_share: tuple, dtype,
-              capacity_factor: float = WINDOW_FACTOR):
+              capacity_factor: float = WINDOW_FACTOR,
+              scoring: str = "softmax", bias=None, gate_scale: float = 1.0):
     """The part of the layer's result that this chip's experts give.
 
     ``h [N, hidden]``; ``router [hidden, all experts]``; ``gate_w``,
     ``up_w [held, hidden, width]`` and ``down_w [held, width, hidden]``
     are experts ``index * held .. (index + 1) * held`` of
-    ``expert_share = (index, of)``. Routing is over all experts; a
+    ``expert_share = (index, of)``. Routing is over all experts, by
+    :func:`route`'s rule (``scoring``, ``bias``, ``gate_scale``); a
     token's choices that fall on absent experts add nothing here. The
     choices that fall on held experts are sorted by expert and go
     through three grouped products (``lax.ragged_dot``), in windows of
@@ -688,7 +713,8 @@ def moe_layer(h, router, gate_w, up_w, down_w, *, experts_per_token: int,
     lo = expert_share[0] * held
     with jax.named_scope("lm/moe/route"):
         experts, gates = route(h, router, experts_per_token=k,
-                               norm_topk=norm_topk)
+                               norm_topk=norm_topk, scoring=scoring,
+                               bias=bias, gate_scale=gate_scale)
         local = (experts >= lo) & (experts < lo + held)
         # absent experts sort behind the held ones
         key = jnp.where(local, experts - lo, held).reshape(-1)

@@ -424,26 +424,29 @@ def start_exposition_server(port: int, registry: Registry | None = None,
     return server, server.server_address[1]
 
 
-# Routing and selection counts of a token-model train step
-# (train/steps.vlm_train_step puts them in the step's metrics): totals as
-# counters, the last step's load as gauges.
+# Routing, selection and attention counts of a token-model train step
+# (train/steps.vlm_train_step and lm_train_step put them in the step's
+# metrics): totals as counters, the last step's load as gauges. A step
+# carries those of its model: an indexer's selected pairs or a causal
+# attention's pairs, a balancing bias or none.
 TOKEN_STEP_COUNTERS = ("moe_local_assignments", "moe_dropped",
-                       "dsa_selected_pairs")
-TOKEN_STEP_GAUGES = ("moe_expert_tokens_max", "moe_expert_tokens_mean")
+                       "dsa_selected_pairs", "attn_causal_pairs")
+TOKEN_STEP_GAUGES = ("moe_expert_tokens_max", "moe_expert_tokens_mean",
+                     "moe_bias_abs_mean")
 
 
 def record_token_step(metrics: dict, registry: Registry | None = None
                       ) -> None:
     """Fold one fetched step's ``metrics`` (host floats) into the
-    registry; a step without these keys (every conv model's) is left
-    alone."""
-    if TOKEN_STEP_COUNTERS[0] not in metrics:
-        return
+    registry, whichever of the names above it carries; a step without
+    any (every conv model's) is left alone."""
     reg = registry if registry is not None else default_registry()
     for name in TOKEN_STEP_COUNTERS:
-        reg.counter(name).inc(int(metrics[name]))
+        if name in metrics:
+            reg.counter(name).inc(int(metrics[name]))
     for name in TOKEN_STEP_GAUGES:
-        reg.gauge(name).set(metrics[name])
+        if name in metrics:
+            reg.gauge(name).set(metrics[name])
 
 
 # Which lowering each call site of a traced token model took

@@ -684,10 +684,19 @@ class ClusterSupervisor:
                     if q.poll() is None:
                         q.kill()
                 continue
-            last_step = self._consult_faults(
-                procs, last_step,
-                max([r.get("step", 0) for r in hb.values()], default=0),
-                preempt_pending)
+            # chaos waits until every live host has beaten once: a host
+            # still importing has no SIGTERM handler yet (the notice
+            # would kill it) and a host stopped before its first beat is
+            # "starting" to the ledger, never a straggler. The consults
+            # held back run in order at the next poll, so a schedule
+            # still replays by cluster-step value.
+            if all(index in hb for index, p in procs.items()
+                   if p.poll() is None):
+                last_step = self._consult_faults(
+                    procs, last_step,
+                    max([r.get("step", 0) for r in hb.values()],
+                        default=0),
+                    preempt_pending)
             for index, p in procs.items():
                 if p.poll() is not None or index in dead:
                     continue

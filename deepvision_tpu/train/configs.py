@@ -343,6 +343,51 @@ TRAINING_CONFIG: dict[str, dict] = {
         "optimizer_params": {"lr": 1e-3},
         "total_epochs": 2,
     },
+    # kanana-2-30b-a3b (models/latent_moe.py, DeepSeek-V3's layout):
+    # latent attention, 128 sigmoid-routed experts top-6 behind a
+    # balancing bias, two shared experts, a leading dense layer; text
+    # only. Published widths; the three entries differ in how much of
+    # the model one process holds. Adam and the warm-up as the sibling
+    # family's, for its reasons; ``text_len`` counts a document's ids
+    # (one more than its positions: position i predicts id i + 1).
+    "kanana2": {
+        "precision": "bf16",
+        "batch_size": 1,
+        "text_len": 8193,
+        "dataset": "lm",
+        "steps": "lm",
+        "optimizer": "adam",
+        "optimizer_params": {"lr": 1e-4},
+        "scheduler": "warmup",
+        "scheduler_params": {"warmup_steps": 2000},
+        "total_epochs": 1,
+    },
+    # one chip's share of an 8-chip expert-parallel layer: 16 of 128
+    # experts, 16,032 of 128,256 vocabulary rows, the dense layer and 5
+    # expert layers (the benchmark's kanana2_30b_a3b.train_text8k)
+    "kanana2_ep8": {
+        "precision": "bf16",
+        "batch_size": 2,
+        "text_len": 8193,
+        "dataset": "lm",
+        "steps": "lm",
+        "optimizer": "adam",
+        "optimizer_params": {"lr": 1e-4},
+        "scheduler": "warmup",
+        "scheduler_params": {"warmup_steps": 2000},
+        "total_epochs": 1,
+    },
+    # CPU-sized preset of the same layers (tests, smoke runs)
+    "kanana2_tiny": {
+        "precision": "bf16",
+        "batch_size": 8,
+        "text_len": 65,
+        "dataset": "lm",
+        "steps": "lm",
+        "optimizer": "adam",
+        "optimizer_params": {"lr": 1e-3},
+        "total_epochs": 2,
+    },
 }
 
 

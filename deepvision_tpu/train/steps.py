@@ -368,6 +368,69 @@ def vlm_eval_step(state: TrainState, batch: dict,
             "count": jnp.sum(mask)}
 
 
+def lm_train_step(state: TrainState, batch: dict, key: jax.Array,
+                  bias_rate: float = 1e-3):
+    """One step of the text token model on {'tokens'}
+    (``models/latent_moe.LatentMoeLM``): mean next-token cross-entropy
+    over the vocabulary the model holds, no auxiliary loss. The state
+    holds a leaf the optimiser never moves and a rule does: each expert
+    layer's selection bias has a gradient of 0 (it enters only the
+    discrete choice; Adam leaves such a leaf where it is), and after the
+    optimiser's update ``balance_router_bias`` adds ``bias_rate *
+    sign(mean load - load)`` from this step's routing counts (this
+    batch's tokens, every expert counted).
+
+    ``metrics`` carries the routing counts of the step
+    (``moe_local_assignments``, ``moe_expert_tokens_max`` / ``_mean``,
+    ``moe_dropped``, as :func:`vlm_train_step`'s), ``moe_bias_abs_mean``
+    (the mean of ``|b|`` over all bias entries after the rule: how far
+    it has carried the bias) and ``attn_causal_pairs`` (query-key pairs
+    attention ran over)."""
+    from deepvision_tpu.models.latent_moe import (
+        balance_router_bias,
+        router_bias_abs_mean,
+    )
+
+    del key                                     # no dropout in this family
+    inputs = {"tokens": batch["tokens"]}
+
+    def loss_fn(params):
+        out = state.apply_fn({"params": params}, inputs, train=True)
+        loss = jnp.mean(out["nll"])
+        return state.scale_loss(loss), (loss, out)
+
+    (_, (loss, out)), grads = jax.value_and_grad(
+        loss_fn, has_aux=True)(state.params)
+    new_state = state.apply_gradients(grads)
+    with jax.named_scope("lm/moe/bias"):
+        new_state = new_state.replace(params=balance_router_bias(
+            new_state.params, jnp.sum(out["expert_counts"], 0), bias_rate))
+        bias_abs_mean = router_bias_abs_mean(new_state.params)
+    per_expert = jnp.sum(out["expert_tokens"], 0)        # [layers, held]
+    metrics = {
+        "loss": loss,
+        "moe_local_assignments": jnp.sum(per_expert),
+        "moe_expert_tokens_max": jnp.max(per_expert),
+        "moe_expert_tokens_mean": jnp.mean(per_expert.astype(jnp.float32)),
+        "moe_dropped": jnp.max(out["moe_dropped"]),
+        "moe_bias_abs_mean": bias_abs_mean,
+        "attn_causal_pairs": jnp.sum(out["causal_pairs"]),
+        **precision_metrics(new_state),
+    }
+    return new_state, metrics
+
+
+def lm_eval_step(state: TrainState, batch: dict) -> dict:
+    """Count-weighted sums over one batch of {'tokens'}."""
+    mask = batch.get("mask")
+    if mask is None:
+        mask = jnp.ones(batch["tokens"].shape[0], jnp.float32)
+    out = state.apply_fn({"params": state.params},
+                         {"tokens": batch["tokens"]}, train=False)
+    return {"loss_sum": jnp.sum(jnp.mean(out["nll"], -1) * mask),
+            "count": jnp.sum(mask)}
+
+
 def aggregate_eval_parts(parts) -> tuple[dict, float]:
     """Sum an iterable of eval-step outputs (count-weighted sums + a
     'count' key) into ``(val_* means, total count)`` — the one masked

@@ -183,7 +183,7 @@ class Trainer:
             config, (steps_per_epoch or 1000) * self.data_echo
         )
         if hasattr(model, "sample_input"):
-            # token models take a dict of image and tokens
+            # token models take a dict (image and tokens, or tokens alone)
             sample = model.sample_input()
         else:
             size = config.get("input_size", 224)
@@ -796,7 +796,7 @@ class Trainer:
                         print(f"[fault] NaN-poisoned epoch {epoch} "
                               f"batch {j}", flush=True)
                     self.injector.maybe_stall()
-                counts.append(len(batch["image"]))
+                counts.append(len(batch[_lead(batch)]))
                 yield batch
 
         # async H2D feed (data/prefetch.py): a producer thread shards +
@@ -832,7 +832,7 @@ class Trainer:
                 for i, device_batch in enumerate(feed):
                     if not self._feed_described:
                         self._feed_described = True
-                        _describe_feed(device_batch["image"])
+                        _describe_feed(device_batch)
                     if self.cluster is not None and self._cluster_poll(
                             epoch, start_step + i):
                         # degraded abandon: NO final drain — peers are
@@ -1427,12 +1427,20 @@ def make_preempt_flag(signals=(signal.SIGTERM,)) -> Callable[[], bool]:
     return lambda: fired["stop"]
 
 
-def _describe_feed(image) -> None:
+def _lead(batch: dict) -> str:
+    """The entry a batch is described by: its image, or where it has
+    none (a text model's) its first."""
+    return "image" if "image" in batch else next(iter(batch))
+
+
+def _describe_feed(batch: dict) -> None:
     """One line on where the first fed batch landed: the only evidence
     a log carries that every device of the mesh holds its share (shard
     metadata is host-side; no sync)."""
-    shards = image.addressable_shards
-    print(f"[feed] image {tuple(image.shape)} {image.dtype}: "
+    name = _lead(batch)
+    lead = batch[name]
+    shards = lead.addressable_shards
+    print(f"[feed] {name} {tuple(lead.shape)} {lead.dtype}: "
           f"{len(shards)} shard(s) of {tuple(shards[0].data.shape)} on "
           f"devices {sorted(s.device.id for s in shards)}", flush=True)
 

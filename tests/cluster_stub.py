@@ -28,6 +28,11 @@ def main() -> int:
     step_s = float(sys.argv[2])
     member = ClusterMember.from_env()
     assert member is not None, "stub needs the DVTPU_CLUSTER_* env"
+    # late-start drill ("INDEX:SECONDS"): that host is still importing,
+    # with no handler installed and no beat written, for that long
+    late = os.environ.get("STUB_LATE_HOST", "")
+    if late and int(late.split(":")[0]) == member.host:
+        time.sleep(float(late.split(":")[1]))
     preempt = {"flag": False}
     signal.signal(signal.SIGTERM, lambda *_: preempt.update(flag=True))
 

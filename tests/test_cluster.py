@@ -360,6 +360,28 @@ def test_supervisor_straggler_detection_on_stall(tmp_path):
     assert reg.value_of("cluster_preemptions") == 0
 
 
+@pytest.mark.parametrize("faults", ["host_preempt@3", "host_stall@2:1.5"])
+def test_supervisor_holds_chaos_until_every_host_has_beaten(
+        tmp_path, faults):
+    """A fault scheduled for a step at which one host is still starting
+    (no SIGTERM handler yet, no beat yet) is delivered at that host's
+    first beat: the notice is answered with a coordinated save, not a
+    death, and the stopped host is a straggler, not one "starting"."""
+    rc, logs, reg = _run_stub_supervisor(
+        tmp_path, faults=faults, steps=100, step_s=0.05,
+        straggler_after_s=0.4, env={"STUB_LATE_HOST": "1:1.0"})
+    assert rc == 0
+    assert reg.value_of("cluster_host_deaths") == 0
+    if faults.startswith("host_preempt"):
+        assert reg.value_of("cluster_preemptions") == 1
+        assert reg.value_of("cluster_resumes") == 1
+        assert any("coordinated save committed by all 2 hosts" in line
+                   for line in logs)
+    else:
+        assert any("SIGSTOPping host index 1" in line for line in logs)
+        assert any("straggler host index 1" in line for line in logs)
+
+
 def test_supervisor_crash_relaunch_within_budget(tmp_path):
     rc, logs, reg = _run_stub_supervisor(
         tmp_path, faults=None, steps=12,

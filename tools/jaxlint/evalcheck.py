@@ -41,24 +41,24 @@ import jax.numpy as jnp
 class ModelSpec:
     """How to build + trace one registry entry."""
 
-    input_shape: tuple[int, ...]  # without the leading batch dim
+    input_shape: tuple[int, ...] | None  # without the leading batch dim
     input_dtype: object = jnp.float32
     kwargs: dict = field(default_factory=dict)
     init_rngs: tuple[str, ...] = ("params", "dropout")
     train_rngs: tuple[str, ...] = ("dropout",)
     # further inputs of a model that takes a dict (the token models):
     # {name: (shape without the batch dim, dtype)}; ``input_shape`` is
-    # then the dict's "image"
+    # then the dict's "image", or None where the dict has none (text)
     extra_inputs: dict = field(default_factory=dict)
 
     def inputs(self, batch: int):
+        extra = {k: jax.ShapeDtypeStruct((batch, *shape), dtype)
+                 for k, (shape, dtype) in self.extra_inputs.items()}
+        if self.input_shape is None:
+            return extra
         image = jax.ShapeDtypeStruct((batch, *self.input_shape),
                                      self.input_dtype)
-        if not self.extra_inputs:
-            return image
-        return {"image": image,
-                **{k: jax.ShapeDtypeStruct((batch, *shape), dtype)
-                   for k, (shape, dtype) in self.extra_inputs.items()}}
+        return {"image": image, **extra} if extra else image
 
 
 def _config_spec(config_name: str) -> ModelSpec:
@@ -67,9 +67,10 @@ def _config_spec(config_name: str) -> ModelSpec:
     cfg = get_config(config_name)
     size, ch = cfg["input_size"], cfg["channels"]
     kwargs = dict(cfg.get("model_kwargs", {}))
-    if cfg["dataset"] == "vlm":
+    if cfg["dataset"] in ("vlm", "lm"):
         return ModelSpec(
-            input_shape=(size, size, ch), kwargs=kwargs,
+            input_shape=(size, size, ch) if cfg["dataset"] == "vlm" else None,
+            kwargs=kwargs,
             extra_inputs={"tokens": ((cfg["text_len"],), jnp.int32)})
     if "num_heatmaps" in cfg:
         kwargs["num_heatmaps"] = cfg["num_heatmaps"]

@@ -539,10 +539,11 @@ def _cyclegan_build():
     return build
 
 
-def _vlm_build(cfg_name: str):
-    """Token-model family (models/transformer.py): the tiny preset's
-    geometry and step; the input is a dict of image and tokens, so the
-    state comes from the model's own sample input."""
+def _token_build(cfg_name: str):
+    """Token-model families (models/transformer.py, image and tokens;
+    models/latent_moe.py, tokens alone): the tiny preset's geometry and
+    step; the input is a dict, so the state comes from the model's own
+    sample input."""
 
     def build(batch: int, precision: str | None = None):
         import jax
@@ -553,7 +554,7 @@ def _vlm_build(cfg_name: str):
         from deepvision_tpu.train.configs import get_config
         from deepvision_tpu.train.optimizers import make_optimizer
         from deepvision_tpu.train.state import create_train_state
-        from deepvision_tpu.train.steps import vlm_train_step
+        from deepvision_tpu.train import steps
 
         cfg = get_config(cfg_name)
         policy = get_policy(precision or cfg["precision"])
@@ -566,10 +567,11 @@ def _vlm_build(cfg_name: str):
         state = jax.eval_shape(
             lambda s: create_train_state(model, tx, s, policy=policy),
             sample)
-        size = cfg["input_size"]
-        batch_sds = {"image": SDS((batch, size, size, 3), np.float32),
-                     "tokens": SDS((batch, cfg["text_len"]), np.int32)}
-        return state, batch_sds, vlm_train_step
+        batch_sds = {"tokens": SDS((batch, cfg["text_len"]), np.int32)}
+        if cfg["dataset"] == "vlm":
+            size = cfg["input_size"]
+            batch_sds["image"] = SDS((batch, size, size, 3), np.float32)
+        return state, batch_sds, getattr(steps, f"{cfg['steps']}_train_step")
 
     return build
 
@@ -639,9 +641,16 @@ def make_cases() -> dict[str, IRCase]:
     # (benchmark cell keye_vl2_30b_a3b.train_seq8k)
     cases["keye_vl2_tiny"] = IRCase(
         "keye_vl2_tiny", ("keye_vl2_tiny", "keye_vl2_ep8", "keye_vl2"), 2,
-        _vlm_build("keye_vl2_tiny"),
+        _token_build("keye_vl2_tiny"),
         "token model: sparse attention + expert share + ViT tower, "
         "f32 image wire, int32 tokens")
+    # likewise the three kanana2 entries (benchmark cell
+    # kanana2_30b_a3b.train_text8k)
+    cases["kanana2_tiny"] = IRCase(
+        "kanana2_tiny", ("kanana2_tiny", "kanana2_ep8", "kanana2"), 2,
+        _token_build("kanana2_tiny"),
+        "text token model: latent attention + biased sigmoid router + "
+        "shared expert + leading dense layer, int32 tokens")
     return cases
 
 

@@ -370,7 +370,7 @@ def main():
         model = get_model(args.model, dtype=dtype,
                           num_heatmaps=cfg["num_heatmaps"],
                           **cfg.get("model_kwargs", {}))
-    elif cfg["dataset"] == "vlm":
+    elif cfg["dataset"] in ("vlm", "lm"):
         # token models: the registry entry fixes every size (the
         # vocabulary among them), the config the image and text lengths
         model = get_model(args.model, dtype=dtype,
@@ -417,27 +417,36 @@ def main():
             )
             steps = (n - split) // cfg["batch_size"]
         cfg["input_size"] = size
-    elif cfg["dataset"] == "vlm":
+    elif cfg["dataset"] in ("vlm", "lm"):
         from deepvision_tpu.data.padding import iter_array_batches
-        from deepvision_tpu.data.synthetic import synthetic_vlm
-        from deepvision_tpu.train.steps import vlm_eval_step, vlm_train_step
+        from deepvision_tpu.data import synthetic
+        from deepvision_tpu.train import steps as token_steps
 
         if args.data_dir:
             raise SystemExit(
-                "the token models train on the seeded image-plus-tokens "
-                "set only: there is no record reader for them yet "
+                "the token models train on their seeded sets only "
+                "(image-plus-tokens, or tokens alone): there is no record "
+                "reader for them yet "
                 f"(this run: --data-dir {args.data_dir!r})")
-        step_fns = {"train_step": vlm_train_step,
-                    "eval_step": vlm_eval_step}
+        kind = cfg["dataset"]
+        step_fns = {"train_step": getattr(token_steps, f"{kind}_train_step"),
+                    "eval_step": getattr(token_steps, f"{kind}_eval_step")}
         n = args.synthetic_size
-        imgs, tokens, split = synthetic_vlm(
-            n, size, cfg["text_len"], model.vocab_size, cfg["batch_size"])
+        if kind == "vlm":
+            imgs, tokens, split = synthetic.synthetic_vlm(
+                n, size, cfg["text_len"], model.vocab_size,
+                cfg["batch_size"])
+            arrays = {"image": imgs, "tokens": tokens}
+        else:
+            tokens, split = synthetic.synthetic_lm(
+                n, cfg["text_len"], model.vocab_size, cfg["batch_size"])
+            arrays = {"tokens": tokens}
         train_data = lambda e: iter_array_batches(
-            {"image": imgs[split:], "tokens": tokens[split:]},
-            cfg["batch_size"], rng=np.random.default_rng(e))
+            {k: v[split:] for k, v in arrays.items()}, cfg["batch_size"],
+            rng=np.random.default_rng(e))
         val_data = lambda: iter_array_batches(
-            {"image": imgs[:split], "tokens": tokens[:split]},
-            cfg["batch_size"], drop_remainder=False)
+            {k: v[:split] for k, v in arrays.items()}, cfg["batch_size"],
+            drop_remainder=False)
         steps = (n - split) // cfg["batch_size"]
     elif cfg["dataset"] == "detection":
         if cfg.get("steps") == "centernet":
