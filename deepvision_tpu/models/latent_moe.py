@@ -38,7 +38,8 @@ Numerics as the sibling's: parameters float32, every multiply takes
 ``dtype`` operands and accumulates in float32, norms, router scores (a
 float32 product at HIGHEST), the exponent and the sum of the softmax and
 the loss are float32. Named scopes: ``lm/mla/proj`` (the four
-projections, the latent's norm, the rotary), ``lm/mla/attn``,
+projections, the latent's norm, the rotary), ``lm/mla/attn`` (either
+form, forward and backward),
 ``lm/dense_mlp``, ``lm/moe/route|experts`` (the shared function's),
 ``lm/moe/shared``, ``lm/head``.
 """
@@ -46,6 +47,7 @@ projections, the latent's norm, the rotary), ``lm/mla/attn``,
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
@@ -57,11 +59,13 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from deepvision_tpu.core.precision import compute_dot, compute_einsum
+from deepvision_tpu.models import transformer
 from deepvision_tpu.models.registry import register
 from deepvision_tpu.models.transformer import (
     RMSNorm,
     _blocks,
     _causal,
+    _chunks_of,
     _map_chunks,
     _stacked,
     moe_layer,
@@ -126,6 +130,121 @@ def causal_pairs(t: int) -> int:
     return t * (t + 1) // 2
 
 
+# The same attention through the latent kernels of ops/dsa_attention.py,
+# which keep the [heads, Tq, Tk] tiles on the chip and take the score as
+# q_nope . k_nope + q_rope . k_rope, the one rotary key never broadcast.
+# Which of the two a call site takes is read from the backend and the
+# shapes (:func:`mla_engages`), and two registry counters say which it
+# was.
+
+
+def mla_engages(t: int, nope_dim: int, rope_dim: int, v_dim: int,
+                key_block: int, q_chunk: int) -> bool:
+    """The kernels take score heads whose own part and value heads that
+    fill lane rows, a rotary part of half a lane row or whole ones and
+    chunks of queries that fill lane rows, on one TPU chip; everything
+    else is :func:`causal_attention`'s."""
+    chunk = _blocks(t, key_block, q_chunk)[1]
+    return (transformer._on_one_tpu() and nope_dim % 128 == 0
+            and v_dim % 128 == 0 and rope_dim % 64 == 0 and chunk % 128 == 0)
+
+
+def _kernel_forward(q, q_rope, k, k_rope, v, key_block, q_chunk):
+    """:func:`causal_attention` of every sequence, a chunk of queries a
+    kernel call. -> (``[B, T, heads x dv]``, log-sum-exp ``[B, chunks,
+    heads, chunk]``)"""
+    from deepvision_tpu.ops import dsa_attention as dsa
+
+    def sequence(args):
+        q, q_rope, k, k_rope, v = args
+        t, heads = q.shape[:2]
+        block, chunk = _blocks(t, key_block, q_chunk)
+        q, q_rope, k, v = (a.reshape(t, -1) for a in (q, q_rope, k, v))
+        norms = dsa.latent_key_norms(k, k_rope, heads)
+        outs, lses = [], []
+        for b0 in range(0, t, block):
+            end = b0 + block
+            kmax = jnp.max(norms[:end], 0)
+
+            def one(args, end=end, kmax=kmax):
+                (qc, qrc), t0 = args
+                return dsa.latent_forward(qc, qrc, k, k_rope, v, kmax, t0,
+                                          keys=end)
+
+            o, lse = _map_chunks(one, (q, q_rope), b0, block, chunk)
+            outs.append(o.reshape(block, -1))
+            lses.append(lse)
+        return jnp.concatenate(outs), jnp.concatenate(lses)
+
+    with jax.named_scope("lm/mla/attn"):
+        return lax.map(sequence, (q, q_rope, k, k_rope, v))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def kernel_attention(q, q_rope, k, k_rope, v, key_block, q_chunk):
+    """Causal latent attention of a batch through the kernels: ``q``,
+    ``k`` ``[B, T, heads, dn]``, ``q_rope [B, T, heads, dr]`` and the
+    one rotary key ``k_rope [B, T, dr]`` (both rotated), ``v [B, T,
+    heads, dv]`` -> ``[B, T, heads x dv]``.
+
+    The backward is written out (``custom_vjp``), as
+    ``transformer.kernel_attention``'s: what the forward keeps is the
+    output and each row's log-sum-exp, named ``attn_out`` and
+    ``mla_lse`` so that a recomputed layer keeps them too and never
+    runs the forward kernel twice; chunk by chunk the backward kernel
+    writes ``dq`` and adds to the float32 sums of ``dk``, ``dk_rope``
+    and ``dv`` that the chunks' scan carries, in place."""
+    return _kernel_forward(q, q_rope, k, k_rope, v, key_block, q_chunk)[0]
+
+
+def _kernel_attention_fwd(q, q_rope, k, k_rope, v, key_block, q_chunk):
+    o, lse = _kernel_forward(q, q_rope, k, k_rope, v, key_block, q_chunk)
+    o, lse = checkpoint_name(o, "attn_out"), checkpoint_name(lse, "mla_lse")
+    return o, (q, q_rope, k, k_rope, v, o, lse)
+
+
+def _kernel_attention_bwd(key_block, q_chunk, kept, do):
+    from deepvision_tpu.ops import dsa_attention as dsa
+
+    def sequence(args):
+        q, q_rope, k, k_rope, v, o, lse, do = args
+        t, heads = q.shape[:2]
+        block, chunk = _blocks(t, key_block, q_chunk)
+        shapes = q.shape, q_rope.shape, k.shape, v.shape
+        q, q_rope, k, v = (a.reshape(t, -1) for a in (q, q_rope, k, v))
+        f32 = jnp.float32
+        sums = tuple(jnp.zeros(a.shape, f32) for a in (k, k_rope, v))
+        dq, dq_rope = [], []
+        for b0 in range(0, t, block):
+            end = b0 + block
+
+            def one(sums, args, end=end):
+                (qc, qrc, o_c, do_c), t0 = args
+                di = jnp.sum((o_c.astype(f32) * do_c.astype(f32)).reshape(
+                    chunk, heads, -1), -1).T
+                dq_c, dqr_c, *sums = dsa.latent_backward(
+                    qc, qrc, k, k_rope, v, t0, lse[t0 // chunk], di, do_c,
+                    *sums, keys=end)
+                return tuple(sums), (dq_c, dqr_c)
+
+            sums, parts = lax.scan(
+                one, sums, _chunks_of((q, q_rope, o, do), b0, block, chunk))
+            for out, part in zip((dq, dq_rope), parts):
+                out.append(part.reshape(block, -1))
+        dk, dk_rope, dv = sums
+        return (jnp.concatenate(dq).reshape(shapes[0]),
+                jnp.concatenate(dq_rope).reshape(shapes[1]),
+                dk.astype(k.dtype).reshape(shapes[2]),
+                dk_rope.astype(k_rope.dtype),
+                dv.astype(v.dtype).reshape(shapes[3]))
+
+    with jax.named_scope("lm/mla/attn"):
+        return lax.map(sequence, (*kept, do))
+
+
+kernel_attention.defvjp(_kernel_attention_fwd, _kernel_attention_bwd)
+
+
 # ----------------------------------------------------------------- layers
 
 
@@ -166,6 +285,8 @@ class _LatentAttention(nn.Module):
 
     @nn.compact
     def __call__(self, h, angles):
+        from deepvision_tpu.obs.metrics import record_latent_site
+
         c, dt = self.cfg, self.cfg.dtype
         b, t, d = h.shape
         heads, dn, dr, dv = c.heads, c.nope_dim, c.rope_dim, c.v_dim
@@ -173,27 +294,35 @@ class _LatentAttention(nn.Module):
         wkva = self.param("kv_a", normal, (d, c.kv_rank + dr))
         wkvb = self.param("kv_b", normal, (c.kv_rank, heads * (dn + dv)))
         wo = self.param("o", normal, (heads * dv, d))
+        by_kernel = mla_engages(t, dn, dr, dv, c.key_block, c.q_chunk)
+        if not self.is_initializing():   # a shape trace runs nowhere
+            record_latent_site(by_kernel)
         with jax.named_scope("lm/mla/proj"):
             q = compute_dot(h, wq, dt).astype(dt).reshape(b, t, heads,
                                                           dn + dr)
-            q = jnp.concatenate(
-                [q[..., :dn], rotate(q[..., dn:], angles)], -1)
+            q, q_rope = q[..., :dn], rotate(q[..., dn:], angles)
             latent = compute_dot(h, wkva, dt).astype(dt)
             c_kv = RMSNorm(c.rms_eps, name="kv_norm")(
                 latent[..., :c.kv_rank])
             k_rope = rotate(latent[..., None, c.kv_rank:], angles)
             kv = compute_dot(c_kv, wkvb, dt).astype(dt).reshape(
                 b, t, heads, dn + dv)
-            # the one rotary key stands in every head's
-            k = jnp.concatenate([kv[..., :dn], jnp.broadcast_to(
-                k_rope, (b, t, heads, dr))], -1)
-            v = kv[..., dn:]
-        o = lax.map(lambda a: causal_attention(
-            *a, key_block=c.key_block, q_chunk=c.q_chunk, dtype=dt),
-            (q, k, v))
-        # kept across the layer's recomputation: the way back then
-        # recomputes each chunk once, not twice
-        o = checkpoint_name(o, "attn_out")
+            k, v = kv[..., :dn], kv[..., dn:]
+            if not by_kernel:
+                # the one rotary key stands in every head's
+                q = jnp.concatenate([q, q_rope], -1)
+                k = jnp.concatenate([k, jnp.broadcast_to(
+                    k_rope, (b, t, heads, dr))], -1)
+        if by_kernel:
+            o = kernel_attention(q, q_rope, k, k_rope[:, :, 0], v,
+                                 c.key_block, c.q_chunk)
+        else:
+            o = lax.map(lambda a: causal_attention(
+                *a, key_block=c.key_block, q_chunk=c.q_chunk, dtype=dt),
+                (q, k, v))
+            # kept across the layer's recomputation: the way back then
+            # recomputes each chunk once, not twice
+            o = checkpoint_name(o, "attn_out")
         with jax.named_scope("lm/mla/proj"):
             return compute_dot(o, wo, dt).astype(dt)
 
@@ -342,7 +471,8 @@ class LatentMoeLM(nn.Module):
             self.capture, dt)
         dense, layer = DenseLayer, ExpertLayer
         if self.remat is not None:
-            keep = jax.checkpoint_policies.save_only_these_names("attn_out")
+            keep = jax.checkpoint_policies.save_only_these_names(
+                "attn_out", "mla_lse")
             dense = nn.remat(DenseLayer, policy=keep)
             layer = nn.remat(ExpertLayer, prevent_cse=False, policy=keep)
         x = dense(cfg, name="dense")(x, angles)

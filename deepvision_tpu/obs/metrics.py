@@ -450,11 +450,13 @@ def record_token_step(metrics: dict, registry: Registry | None = None
 
 
 # Which lowering each call site of a traced token model took
-# (models/transformer.batched_sparse_attention and chunk_scores decide
-# while the step is traced, so these count sites of traced programs, not
-# steps): (through the Pallas kernels, through the XLA form).
+# (models/transformer.batched_sparse_attention and chunk_scores, and
+# models/latent_moe._LatentAttention, decide while the step is traced,
+# so these count sites of traced programs, not steps): (through the
+# Pallas kernels, through the XLA form).
 ATTENTION_SITE_COUNTERS = ("dsa_kernel_sites", "dsa_xla_sites")
 INDEXER_SITE_COUNTERS = ("indexer_kernel_sites", "indexer_xla_sites")
+LATENT_SITE_COUNTERS = ("mla_kernel_sites", "mla_xla_sites")
 
 
 def _record_site(counters: tuple, by_kernel: bool,
@@ -475,3 +477,11 @@ def record_indexer_site(by_kernel: bool, registry: Registry | None = None
     """One call site of a chunk's indexer scores
     (``indexer_kernel_sites`` or ``indexer_xla_sites``)."""
     _record_site(INDEXER_SITE_COUNTERS, by_kernel, registry)
+
+
+def record_latent_site(by_kernel: bool, registry: Registry | None = None
+                       ) -> None:
+    """One latent-attention call site of a program that will run, the
+    shape trace of ``init`` left out (``mla_kernel_sites`` or
+    ``mla_xla_sites``)."""
+    _record_site(LATENT_SITE_COUNTERS, by_kernel, registry)

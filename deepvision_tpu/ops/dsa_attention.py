@@ -1,5 +1,13 @@
-"""Pallas TPU kernels: masked grouped-query attention of one chunk of
-queries whose ``[heads, queries, keys]`` tiles never leave the chip.
+"""Pallas TPU kernels: blocked attention of one chunk of queries whose
+``[heads, queries, keys]`` tiles never leave the chip, in two forms that
+share tiling, the bound-shifted softmax and the transposed-tile
+backward: masked grouped-query attention behind an indexer's selection
+(this page: ``forward``, ``backward``; ``models/transformer.py``'s) and
+plain causal latent attention (``latent_forward``, ``latent_backward``,
+at the end of the module, where what differs is said;
+``models/latent_moe.py``'s). Which form a model's call site takes is
+read from the operands it has (a threshold and scores, or a rotary key
+of its own), all of it while the program is traced.
 
 The token model's sparse attention (``models/transformer.py``) keeps,
 for query ``t``, the causal keys whose indexer score reaches the
@@ -31,10 +39,12 @@ Three kernels, one chunk a call:
   no transpose. The probabilities are there anyway, so it also returns
   the alignment target again, for the alignment loss's own backward.
 
-Numerics: operands of both products in the inputs' dtype, float32
-accumulation; logits, exponent, row sums, division, target float32.
-Key tiles strictly above the diagonal are skipped. Layouts are the
-model's (``[T, heads x dim]``): no transpose outside the kernels.
+Numerics, both forms: operands of both products in the inputs' dtype,
+float32 accumulation; logits, exponent, row sums, division, target
+float32; the exponentials go to the second product unnormalised, cast
+to the inputs' dtype; no running maximum, nothing rescaled. Key tiles
+strictly above the diagonal are skipped. Layouts are the model's (``[T,
+heads x dim]``): no transpose outside the kernels.
 
 ``interpret=True`` runs the Pallas interpreter (CPU tests); left to the
 default it is chosen by the backend.
@@ -105,6 +115,26 @@ def _lane_tiles(x, n: int):
     return x if n == 1 else jnp.tile(x, (1, n))
 
 
+def _accumulate(r: int, p, v_t, l_ref, acc_ref):
+    """Head ``r`` of the step: the tile's unnormalised exponentials
+    ``p [tq, tk]`` into the row sums and the output's accumulator."""
+    # row sums stay lane-wise partial sums until the last tile
+    l_ref[r] += sum(p[:, j * LANES:(j + 1) * LANES]
+                    for j in range(p.shape[1] // LANES))
+    acc_ref[r] += jnp.dot(p.astype(v_t.dtype), v_t,
+                          preferred_element_type=jnp.float32)
+
+
+def _finish(r: int, o_ref, lse_ref, acc_ref, l_ref, bound_ref):
+    """Head ``r``'s output, divided by its rows' sums, and their
+    log-sum-exp as a lane row."""
+    tq, hd = acc_ref.shape[1:]
+    total = jnp.sum(l_ref[r], -1, keepdims=True)                # [tq, 1]
+    o_ref[:, r * hd:(r + 1) * hd] = (acc_ref[r] / total).astype(o_ref.dtype)
+    lse = bound_ref[r] + jnp.log(jnp.broadcast_to(total, (tq, LANES)))
+    lse_ref[r:r + 1, :] = lse.T[:1, :]
+
+
 # ---------------------------------------------------------------- forward
 
 
@@ -135,21 +165,12 @@ def _forward_kernel(t0_ref, q_ref, k_ref, v_ref, scores_ref, thr_ref,
                                 preferred_element_type=jnp.float32)
             z = s * _SCALE - _lane_tiles(bound_ref[r], tk // LANES)
             p = jnp.where(keep, jnp.exp(jnp.maximum(z, -80.0)), 0.0)
-            # row sums stay lane-wise partial sums until the last tile
-            l_ref[r] += sum(p[:, j * LANES:(j + 1) * LANES]
-                            for j in range(tk // LANES))
-            acc_ref[r] += jnp.dot(p.astype(v_t.dtype), v_t,
-                                  preferred_element_type=jnp.float32)
+            _accumulate(r, p, v_t, l_ref, acc_ref)
 
     @pl.when(kk == last)
     def _():
         for r in range(reps):
-            total = jnp.sum(l_ref[r], -1, keepdims=True)        # [tq, 1]
-            o_ref[:, r * hd:(r + 1) * hd] = (acc_ref[r] / total).astype(
-                o_ref.dtype)
-            lse = bound_ref[r] + jnp.log(jnp.broadcast_to(total,
-                                                          (tq, LANES)))
-            lse_ref[r:r + 1, :] = lse.T[:1, :]
+            _finish(r, o_ref, lse_ref, acc_ref, l_ref, bound_ref)
 
 
 def _forward_call(q, k, v, scores, thr, t0, interpret):
@@ -374,3 +395,267 @@ def backward(q, k, v, scores, thr, t0, lse, di, do, dk, dv, *,
         name="dsa_attention_backward", interpret=interpret,
     )(jnp.asarray(t0, jnp.int32).reshape(1), q, do, k, v, scores,
       thr[None, :], lse, di, dk, dv)
+
+
+# ------------------------------------------------- latent attention (MLA)
+#
+# The same blocked attention for the second token model's heads
+# (``models/latent_moe.py``): the mask is the causal one alone (no
+# scores, no threshold, no alignment target), every query head has its
+# own keys and values (the ``groups == heads`` case, ``heads`` of them a
+# grid step so that a step is worth its overhead), value heads may be
+# wider or narrower than score heads, and a head's score is the sum of
+# two products: ``q . k`` over the head's own ``dn`` columns and
+# ``q_rope . k_rope`` over ``dr`` rotary columns whose key is ONE
+# ``[keys, dr]`` array for all heads (never broadcast). Key tiles, the
+# live-tile test, the bound-shifted softmax, the lane-wise row sums and
+# the transposed-tile backward are the ones above. Keys, values and the
+# sums may hold more rows than ``keys``: the grid covers the first
+# ``keys``, and a tile above the chunk's last query names the last live
+# one again in every index map, so it costs a grid step and no fetch.
+
+
+# Heads a grid step. Forward: with 8 the step's blocks and scratch pass
+# the 16 MB of scoped VMEM that XLA holds the call to inside the chunks'
+# loop (found compiling the cell's step for a described v5e).
+FORWARD_HEADS, BACKWARD_HEADS = 4, 8
+
+
+def _latent_sizes(q, q_rope, k_rope, v, keys, most: int):
+    """-> (queries, keys, heads, heads a grid step: ``most`` or all of
+    them, dn, dr, dv, key tile)."""
+    dr = k_rope.shape[1]
+    heads = q_rope.shape[1] // dr
+    keys = k_rope.shape[0] if keys is None else keys
+    return (q.shape[0], keys, heads, most if heads % most == 0 else heads,
+            q.shape[1] // heads, dr, v.shape[1] // heads, key_tile(keys))
+
+
+def _cols(r: int, width: int) -> slice:
+    """Head ``r``'s columns among heads ``width`` wide side by side."""
+    return slice(r * width, (r + 1) * width)
+
+
+def _causal_tile(t0, kk, tq: int, tk: int, transposed: bool = False):
+    """``[tq, tk]`` (``[tk, tq]`` transposed): key at or below query."""
+    shape, q_axis = ((tk, tq), 1) if transposed else ((tq, tk), 0)
+    query = t0 + lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    key = kk * tk + lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    return key <= query
+
+
+def latent_key_norms(k, k_rope, heads: int):
+    """``[T, heads]`` float32: the norm of each head's whole key ``[k_h |
+    k_rope]``. Its maximum over a call's keys is the key part of the
+    softmax's bound (the ``kmax`` operand below)."""
+    t = k.shape[0]
+    sq = lambda a: jnp.sum(jnp.square(a.astype(jnp.float32)), -1)
+    return jnp.sqrt(sq(k.reshape(t, heads, -1)) + sq(k_rope)[:, None])
+
+
+def _latent_forward_kernel(t0_ref, q_ref, qr_ref, k_ref, kr_ref, v_ref,
+                           kmax_ref, o_ref, lse_ref, acc_ref, l_ref,
+                           bound_ref, *, heads: int, scale: float):
+    tq, tk = q_ref.shape[0], k_ref.shape[0]
+    dn, dr, dv = q_ref.shape[1] // heads, kr_ref.shape[1], acc_ref.shape[2]
+    kk, last = pl.program_id(1), pl.num_programs(1) - 1
+    t0 = t0_ref[0]
+
+    @pl.when(kk == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        for r in range(heads):
+            qf = q_ref[:, _cols(r, dn)].astype(jnp.float32)
+            qrf = qr_ref[:, _cols(r, dr)].astype(jnp.float32)
+            norm = jnp.sqrt(jnp.sum(qf * qf, -1, keepdims=True)
+                            + jnp.sum(qrf * qrf, -1, keepdims=True))
+            bound_ref[r] = (jnp.broadcast_to(norm, (tq, LANES))
+                            * kmax_ref[:, _cols(r, LANES)] * scale)
+
+    @pl.when(_tile_is_live(t0, kk, tq, tk))
+    def _():
+        keep = _causal_tile(t0, kk, tq, tk)
+        kr_t = kr_ref[...]
+        for r in range(heads):
+            s = lax.dot_general(q_ref[:, _cols(r, dn)],
+                                k_ref[:, _cols(r, dn)], _NT,
+                                preferred_element_type=jnp.float32)
+            s += lax.dot_general(qr_ref[:, _cols(r, dr)], kr_t, _NT,
+                                 preferred_element_type=jnp.float32)
+            z = s * scale - _lane_tiles(bound_ref[r], tk // LANES)
+            p = jnp.where(keep, jnp.exp(jnp.maximum(z, -80.0)), 0.0)
+            _accumulate(r, p, v_ref[:, _cols(r, dv)], l_ref, acc_ref)
+
+    @pl.when(kk == last)
+    def _():
+        for r in range(heads):
+            _finish(r, o_ref, lse_ref, acc_ref, l_ref, bound_ref)
+
+
+def latent_forward(q, q_rope, k, k_rope, v, kmax, t0, *,
+                   keys: int | None = None, interpret: bool | None = None):
+    """One chunk of causal attention with scores ``(q_h . k_h + q_rope_h
+    . k_rope) / sqrt(dn + dr)``. ``q [Tq, heads x dn]``, ``q_rope [Tq,
+    heads x dr]``, ``k [>= keys, heads x dn]``, ``k_rope [>= keys, dr]``,
+    ``v [>= keys, heads x dv]``, ``kmax [heads]`` float32 (the maximum
+    of :func:`latent_key_norms` over the keys), ``t0`` the first query's
+    position among the keys, ``keys`` (all rows by default) how many
+    the chunk attends over. -> (output ``[Tq, heads x dv]`` in ``q``'s
+    dtype, log-sum-exp ``[heads, Tq]`` float32)."""
+    interpret = _interpret() if interpret is None else interpret
+    tq, keys, heads, hb, dn, dr, dv, tk = _latent_sizes(
+        q, q_rope, k_rope, v, keys, FORWARD_HEADS)
+    live = lambda kk, t0: jnp.minimum(kk, (t0[0] + tq - 1) // tk)
+    grid = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(heads // hb, keys // tk),
+        in_specs=[
+            pl.BlockSpec((tq, hb * dn), lambda g, kk, t0: (0, g)),
+            pl.BlockSpec((tq, hb * dr), lambda g, kk, t0: (0, g)),
+            pl.BlockSpec((tk, hb * dn), lambda g, kk, t0: (live(kk, t0), g)),
+            pl.BlockSpec((tk, dr), lambda g, kk, t0: (live(kk, t0), 0)),
+            pl.BlockSpec((tk, hb * dv), lambda g, kk, t0: (live(kk, t0), g)),
+            pl.BlockSpec((1, hb * LANES), lambda g, kk, t0: (0, g)),
+        ],
+        out_specs=[
+            pl.BlockSpec((tq, hb * dv), lambda g, kk, t0: (0, g)),
+            pl.BlockSpec((None, hb, tq), lambda g, kk, t0: (g, 0, 0)),
+        ],
+        scratch_shapes=[pltpu.VMEM((hb, tq, dv), jnp.float32),
+                        pltpu.VMEM((hb, tq, LANES), jnp.float32),
+                        pltpu.VMEM((hb, tq, LANES), jnp.float32)])
+    o, lse = pl.pallas_call(
+        functools.partial(_latent_forward_kernel, heads=hb,
+                          scale=1.0 / math.sqrt(dn + dr)),
+        grid_spec=grid,
+        out_shape=[jax.ShapeDtypeStruct((tq, heads * dv), q.dtype),
+                   jax.ShapeDtypeStruct((heads // hb, hb, tq), jnp.float32)],
+        compiler_params=_params("parallel", "arbitrary"),
+        name="mla_attention_forward", interpret=interpret,
+    )(jnp.asarray(t0, jnp.int32).reshape(1), q, q_rope, k, k_rope, v,
+      jnp.repeat(kmax.astype(jnp.float32), LANES)[None])
+    return o, lse.reshape(heads, tq)
+
+
+def _latent_backward_kernel(t0_ref, q_ref, qr_ref, do_ref, k_ref, kr_ref,
+                            v_ref, lse_ref, di_ref, dk_in_ref, dkr_in_ref,
+                            dv_in_ref, dq_ref, dqr_ref, dk_ref, dkr_ref,
+                            dv_ref, dq_acc_ref, dqr_acc_ref, *, heads: int,
+                            scale: float):
+    tq, tk = q_ref.shape[0], k_ref.shape[0]
+    dn, dr, dv = q_ref.shape[1] // heads, kr_ref.shape[1], \
+        v_ref.shape[1] // heads
+    kk, g = pl.program_id(0), pl.program_id(1)
+    t0 = t0_ref[0]
+
+    @pl.when(kk == 0)
+    def _():
+        dq_acc_ref[g] = jnp.zeros(dq_acc_ref.shape[1:], jnp.float32)
+        dqr_acc_ref[g] = jnp.zeros(dqr_acc_ref.shape[1:], jnp.float32)
+
+    # a tile that is not live named the last live one's blocks again:
+    # they stay as that step left them
+    @pl.when(_tile_is_live(t0, kk, tq, tk))
+    def _():
+        keep = _causal_tile(t0, kk, tq, tk, transposed=True)
+        kr_t = kr_ref[...]
+        dkr = jnp.zeros((tk, dr), jnp.float32)
+        for r in range(heads):
+            q_r = q_ref[:, _cols(r, dn)]
+            qr_r = qr_ref[:, _cols(r, dr)]
+            do_r = do_ref[:, _cols(r, dv)]
+            k_t = k_ref[:, _cols(r, dn)]
+            s = lax.dot_general(k_t, q_r, _NT,
+                                preferred_element_type=jnp.float32)
+            s += lax.dot_general(kr_t, qr_r, _NT,
+                                 preferred_element_type=jnp.float32)
+            p = jnp.exp(jnp.where(keep, s * scale - lse_ref[r:r + 1, :],
+                                  _NEG))                        # [tk, tq]
+            dv_ref[:, _cols(r, dv)] = (
+                dv_in_ref[:, _cols(r, dv)]
+                + jnp.dot(p.astype(do_r.dtype), do_r,
+                          preferred_element_type=jnp.float32))
+            dp = lax.dot_general(v_ref[:, _cols(r, dv)], do_r, _NT,
+                                 preferred_element_type=jnp.float32)
+            ds = p * (dp - di_ref[r:r + 1, :])
+            ds_t = ds.astype(q_r.dtype)
+            dk_ref[:, _cols(r, dn)] = (
+                dk_in_ref[:, _cols(r, dn)]
+                + jnp.dot(ds_t, q_r,
+                          preferred_element_type=jnp.float32) * scale)
+            dkr += jnp.dot(ds_t, qr_r, preferred_element_type=jnp.float32)
+            ds_q = ds.T.astype(k_t.dtype)
+            dq_acc_ref[g, :, _cols(r, dn)] += jnp.dot(
+                ds_q, k_t, preferred_element_type=jnp.float32)
+            dqr_acc_ref[g, :, _cols(r, dr)] += jnp.dot(
+                ds_q, kr_t, preferred_element_type=jnp.float32)
+
+        # the one rotary key's sum runs over the heads of every step
+        @pl.when(g == 0)
+        def _():
+            dkr_ref[...] = dkr_in_ref[...] + dkr * scale
+
+        @pl.when(g > 0)
+        def _():
+            dkr_ref[...] += dkr * scale
+
+    @pl.when((kk == pl.num_programs(0) - 1) & (g == pl.num_programs(1) - 1))
+    def _():
+        for g2 in range(dq_acc_ref.shape[0]):
+            dq_ref[:, _cols(g2, heads * dn)] = (
+                dq_acc_ref[g2] * scale).astype(dq_ref.dtype)
+            dqr_ref[:, _cols(g2, heads * dr)] = (
+                dqr_acc_ref[g2] * scale).astype(dqr_ref.dtype)
+
+
+def latent_backward(q, q_rope, k, k_rope, v, t0, lse, di, do, dk, dk_rope,
+                    dv, *, keys: int | None = None,
+                    interpret: bool | None = None):
+    """The chunk's cotangents from its output's (``do [Tq, heads x
+    dv]``), the forward's ``lse`` and ``di [heads, Tq] = sum(o * do)``
+    per head. ``dk``, ``dk_rope``, ``dv`` (float32, ``[>= keys, .]``)
+    are the sums over the chunks so far; this chunk's part is added to
+    their first ``keys`` rows in place. -> (``dq``, ``dq_rope`` in
+    ``q``'s dtype, ``dk``, ``dk_rope``, ``dv``)."""
+    interpret = _interpret() if interpret is None else interpret
+    tq, keys, heads, hb, dn, dr, dv_dim, tk = _latent_sizes(
+        q, q_rope, k_rope, v, keys, BACKWARD_HEADS)
+    steps = heads // hb
+
+    def tile(kk, g, t0):
+        """(key tile, head step) of a grid step, the last live tile's
+        last step for one above the chunk's last query."""
+        last = (t0[0] + tq - 1) // tk
+        live = kk <= last
+        return jnp.where(live, kk, last), jnp.where(live, g, steps - 1)
+
+    rows = lambda w: pl.BlockSpec(
+        (tq, hb * w), lambda kk, g, t0: (0, tile(kk, g, t0)[1]))
+    keyed = lambda w: pl.BlockSpec(
+        (tk, hb * w), lambda kk, g, t0: tile(kk, g, t0))
+    rope = pl.BlockSpec((tk, dr), lambda kk, g, t0: (tile(kk, g, t0)[0], 0))
+    stats = pl.BlockSpec((hb, tq), lambda kk, g, t0: (tile(kk, g, t0)[1], 0))
+    whole = lambda a: pl.BlockSpec(a.shape, lambda kk, g, t0: (0, 0))
+    grid = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(keys // tk, steps),
+        in_specs=[rows(dn), rows(dr), rows(dv_dim), keyed(dn), rope,
+                  keyed(dv_dim), stats, stats, keyed(dn), rope,
+                  keyed(dv_dim)],
+        out_specs=[whole(q), whole(q_rope), keyed(dn), rope, keyed(dv_dim)],
+        scratch_shapes=[pltpu.VMEM((steps, tq, hb * dn), jnp.float32),
+                        pltpu.VMEM((steps, tq, hb * dr), jnp.float32)])
+    return pl.pallas_call(
+        functools.partial(_latent_backward_kernel, heads=hb,
+                          scale=1.0 / math.sqrt(dn + dr)),
+        grid_spec=grid,
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(q_rope.shape, q.dtype),
+                   jax.ShapeDtypeStruct(dk.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(dk_rope.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(dv.shape, jnp.float32)],
+        # operands count the prefetched scalar: the sums are 9, 10, 11
+        input_output_aliases={9: 2, 10: 3, 11: 4},
+        compiler_params=_params("arbitrary", "arbitrary"),
+        name="mla_attention_backward", interpret=interpret,
+    )(jnp.asarray(t0, jnp.int32).reshape(1), q, q_rope, do, k, k_rope, v,
+      lse, di, dk, dk_rope, dv)
