@@ -6,6 +6,7 @@ from deepvision_tpu.models import (  # noqa: F401
     centernet,
     gan,
     hourglass,
+    hyper_latent,
     inception,
     latent_moe,
     lenet,

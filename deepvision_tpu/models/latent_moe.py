@@ -20,6 +20,9 @@ own:
   (:func:`causal_attention`), blocked like the sparse one: queries in
   blocks of ``key_block`` against the keys up to the block's end, in
   chunks of ``q_chunk``, each chunk recomputed on the way back.
+  ``models/hyper_latent.py`` builds its attention from the same module
+  with a compressed query (``q_rank``), the heads of a ``head_share``
+  and a softmax scale of its own (``LatentConfig``).
 - **The expert layer** is :func:`transformer.moe_layer`, told the
   scoring rule (``sigmoid``), the selection bias and the gate scale;
   beside it one SiLU-gated MLP that every token passes (the shared
@@ -81,15 +84,16 @@ Dtype = Any
 # ------------------------------------------------------- causal attention
 
 
-def _attend(q, k, v, mask, dtype):
+def _attend(q, k, v, mask, dtype, scale=None):
     """Softmax attention of ``q [Tq, heads, dq]`` over the keys ``mask
-    [Tq, Tk]`` keeps; ``k [Tk, heads, dq]``, ``v [Tk, heads, dv]``.
+    [Tq, Tk]`` keeps; ``k [Tk, heads, dq]``, ``v [Tk, heads, dv]``;
+    logits times ``scale`` (by default ``1 / sqrt(dq)``).
     -> ``[Tq, heads x dv]``. The softmax is shifted by a bound known
     before the logits, as ``transformer._attend``'s is, and for its
     reasons: the exponentials leave the first product's fusion in the
     compute dtype, the second product takes them unnormalised."""
     tq, heads, dq = q.shape
-    scale = 1.0 / math.sqrt(dq)
+    scale = 1.0 / math.sqrt(dq) if scale is None else scale
     with jax.named_scope("lm/mla/attn"):
         norm = lambda a: jnp.sqrt(jnp.sum(jnp.square(
             a.astype(jnp.float32)), -1))
@@ -105,9 +109,10 @@ def _attend(q, k, v, mask, dtype):
     return out.reshape(tq, -1).astype(dtype)
 
 
-def causal_attention(q, k, v, *, key_block: int, q_chunk: int, dtype):
+def causal_attention(q, k, v, *, key_block: int, q_chunk: int, dtype,
+                     scale=None):
     """One sequence: ``q``, ``k`` ``[T, heads, dq]`` (rotated), ``v [T,
-    heads, dv]`` -> ``[T, heads x dv]``."""
+    heads, dv]`` -> ``[T, heads x dv]``; ``scale`` as :func:`_attend`'s."""
     t = q.shape[0]
     block, chunk = _blocks(t, key_block, q_chunk)
     outs = []
@@ -118,7 +123,7 @@ def causal_attention(q, k, v, *, key_block: int, q_chunk: int, dtype):
         def one(args, end=end):
             (qc,), t0 = args
             return _attend(qc, k[:end], v[:end], _causal(t0, chunk, end),
-                           dtype)
+                           dtype, scale)
 
         outs.append(_map_chunks(one, (q,), b0, block, chunk).reshape(
             block, -1))
@@ -149,7 +154,7 @@ def mla_engages(t: int, nope_dim: int, rope_dim: int, v_dim: int,
             and v_dim % 128 == 0 and rope_dim % 64 == 0 and chunk % 128 == 0)
 
 
-def _kernel_forward(q, q_rope, k, k_rope, v, key_block, q_chunk):
+def _kernel_forward(q, q_rope, k, k_rope, v, key_block, q_chunk, scale):
     """:func:`causal_attention` of every sequence, a chunk of queries a
     kernel call. -> (``[B, T, heads x dv]``, log-sum-exp ``[B, chunks,
     heads, chunk]``)"""
@@ -169,7 +174,7 @@ def _kernel_forward(q, q_rope, k, k_rope, v, key_block, q_chunk):
             def one(args, end=end, kmax=kmax):
                 (qc, qrc), t0 = args
                 return dsa.latent_forward(qc, qrc, k, k_rope, v, kmax, t0,
-                                          keys=end)
+                                          keys=end, scale=scale)
 
             o, lse = _map_chunks(one, (q, q_rope), b0, block, chunk)
             outs.append(o.reshape(block, -1))
@@ -180,12 +185,14 @@ def _kernel_forward(q, q_rope, k, k_rope, v, key_block, q_chunk):
         return lax.map(sequence, (q, q_rope, k, k_rope, v))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def kernel_attention(q, q_rope, k, k_rope, v, key_block, q_chunk):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def kernel_attention(q, q_rope, k, k_rope, v, key_block, q_chunk,
+                     scale=None):
     """Causal latent attention of a batch through the kernels: ``q``,
     ``k`` ``[B, T, heads, dn]``, ``q_rope [B, T, heads, dr]`` and the
     one rotary key ``k_rope [B, T, dr]`` (both rotated), ``v [B, T,
-    heads, dv]`` -> ``[B, T, heads x dv]``.
+    heads, dv]`` -> ``[B, T, heads x dv]``; the logits' ``scale`` is by
+    default ``1 / sqrt(dn + dr)``.
 
     The backward is written out (``custom_vjp``), as
     ``transformer.kernel_attention``'s: what the forward keeps is the
@@ -194,16 +201,19 @@ def kernel_attention(q, q_rope, k, k_rope, v, key_block, q_chunk):
     runs the forward kernel twice; chunk by chunk the backward kernel
     writes ``dq`` and adds to the float32 sums of ``dk``, ``dk_rope``
     and ``dv`` that the chunks' scan carries, in place."""
-    return _kernel_forward(q, q_rope, k, k_rope, v, key_block, q_chunk)[0]
+    return _kernel_forward(q, q_rope, k, k_rope, v, key_block, q_chunk,
+                           scale)[0]
 
 
-def _kernel_attention_fwd(q, q_rope, k, k_rope, v, key_block, q_chunk):
-    o, lse = _kernel_forward(q, q_rope, k, k_rope, v, key_block, q_chunk)
+def _kernel_attention_fwd(q, q_rope, k, k_rope, v, key_block, q_chunk,
+                          scale):
+    o, lse = _kernel_forward(q, q_rope, k, k_rope, v, key_block, q_chunk,
+                             scale)
     o, lse = checkpoint_name(o, "attn_out"), checkpoint_name(lse, "mla_lse")
     return o, (q, q_rope, k, k_rope, v, o, lse)
 
 
-def _kernel_attention_bwd(key_block, q_chunk, kept, do):
+def _kernel_attention_bwd(key_block, q_chunk, scale, kept, do):
     from deepvision_tpu.ops import dsa_attention as dsa
 
     def sequence(args):
@@ -224,7 +234,7 @@ def _kernel_attention_bwd(key_block, q_chunk, kept, do):
                     chunk, heads, -1), -1).T
                 dq_c, dqr_c, *sums = dsa.latent_backward(
                     qc, qrc, k, k_rope, v, t0, lse[t0 // chunk], di, do_c,
-                    *sums, keys=end)
+                    *sums, keys=end, scale=scale)
                 return tuple(sums), (dq_c, dqr_c)
 
             sums, parts = lax.scan(
@@ -278,6 +288,14 @@ class LatentConfig:
     q_chunk: int
     capture: bool = False
     dtype: Dtype = jnp.bfloat16
+    # the query through a latent of this rank (``q_a``, its norm,
+    # ``q_b``), or one product (``q``) where None
+    q_rank: int | None = None
+    # (index, of): the heads this chip holds of ``heads``, and so the
+    # part of the output they give
+    head_share: tuple = (0, 1)
+    # the logits' scale, by default 1 / sqrt(nope_dim + rope_dim)
+    softmax_scale: float | None = None
 
 
 class _LatentAttention(nn.Module):
@@ -289,8 +307,14 @@ class _LatentAttention(nn.Module):
 
         c, dt = self.cfg, self.cfg.dtype
         b, t, d = h.shape
-        heads, dn, dr, dv = c.heads, c.nope_dim, c.rope_dim, c.v_dim
-        wq = self.param("q", normal, (d, heads * (dn + dr)))
+        heads = c.heads // c.head_share[1]                  # held here
+        dn, dr, dv = c.nope_dim, c.rope_dim, c.v_dim
+        if c.q_rank is None:
+            wq = self.param("q", normal, (d, heads * (dn + dr)))
+        else:
+            wqa = self.param("q_a", normal, (d, c.q_rank))
+            q_norm = RMSNorm(c.rms_eps, name="q_norm")
+            wqb = self.param("q_b", normal, (c.q_rank, heads * (dn + dr)))
         wkva = self.param("kv_a", normal, (d, c.kv_rank + dr))
         wkvb = self.param("kv_b", normal, (c.kv_rank, heads * (dn + dv)))
         wo = self.param("o", normal, (heads * dv, d))
@@ -298,8 +322,12 @@ class _LatentAttention(nn.Module):
         if not self.is_initializing():   # a shape trace runs nowhere
             record_latent_site(by_kernel)
         with jax.named_scope("lm/mla/proj"):
-            q = compute_dot(h, wq, dt).astype(dt).reshape(b, t, heads,
-                                                          dn + dr)
+            if c.q_rank is None:
+                q = compute_dot(h, wq, dt).astype(dt)
+            else:
+                q = compute_dot(q_norm(compute_dot(h, wqa, dt).astype(dt)),
+                                wqb, dt).astype(dt)
+            q = q.reshape(b, t, heads, dn + dr)
             q, q_rope = q[..., :dn], rotate(q[..., dn:], angles)
             latent = compute_dot(h, wkva, dt).astype(dt)
             c_kv = RMSNorm(c.rms_eps, name="kv_norm")(
@@ -315,11 +343,11 @@ class _LatentAttention(nn.Module):
                     k_rope, (b, t, heads, dr))], -1)
         if by_kernel:
             o = kernel_attention(q, q_rope, k, k_rope[:, :, 0], v,
-                                 c.key_block, c.q_chunk)
+                                 c.key_block, c.q_chunk, c.softmax_scale)
         else:
             o = lax.map(lambda a: causal_attention(
-                *a, key_block=c.key_block, q_chunk=c.q_chunk, dtype=dt),
-                (q, k, v))
+                *a, key_block=c.key_block, q_chunk=c.q_chunk, dtype=dt,
+                scale=c.softmax_scale), (q, k, v))
             # kept across the layer's recomputation: the way back then
             # recomputes each chunk once, not twice
             o = checkpoint_name(o, "attn_out")
@@ -517,12 +545,22 @@ def balance_router_bias(params, expert_counts, rate: float):
     layer's selection bias: ``expert_counts [expert layers, all
     experts]`` are the tokens of the step's batch that chose each
     expert. An overloaded expert's entry falls by ``rate``, an
-    underloaded one's rises; every other leaf is returned as it is."""
+    underloaded one's rises; every other leaf is returned as it is.
+    Where several leaves hold biases (a stack of layers ``[layers,
+    experts]``, a lone layer's ``[experts]``), they take the rows of
+    ``expert_counts`` one after another in the tree's order."""
     c = expert_counts.astype(jnp.float32)
     step = rate * jnp.sign(jnp.mean(c, -1, keepdims=True) - c)
-    return jax.tree_util.tree_map_with_path(
-        lambda path, leaf: leaf + step.astype(leaf.dtype)
-        if is_router_bias(path) else leaf, params)
+    flat, tree = jax.tree_util.tree_flatten_with_path(params)
+    leaves, row = [], 0
+    for path, leaf in flat:
+        if is_router_bias(path):
+            n = leaf.shape[0] if leaf.ndim == 2 else 1
+            leaf = leaf + step[row:row + n].reshape(leaf.shape).astype(
+                leaf.dtype)
+            row += n
+        leaves.append(leaf)
+    return jax.tree_util.tree_unflatten(tree, leaves)
 
 
 def router_bias_abs_mean(params):

@@ -161,6 +161,37 @@ def rope_angles(length: int, pairs: int, theta: float):
             * jnp.asarray(inv, jnp.float32))
 
 
+def yarn_angles(length: int, pairs: int, theta: float, factor: float,
+                original: int, beta_fast: float, beta_slow: float):
+    """``[length, pairs]`` angles of DeepSeek-V3's yarn rotary: pairs
+    that turn more than ``beta_fast`` times over the ``original``
+    length keep their frequency, pairs that turn fewer than
+    ``beta_slow`` times have theirs divided by ``factor``, and a linear
+    ramp over the pair's index joins the two (its
+    ``yarn_find_correction_range`` and ``yarn_linear_ramp_mask``)."""
+    dim = 2 * pairs
+
+    def pair_of(turns):
+        return dim * math.log(original / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = theta ** (-np.arange(pairs) / pairs)
+    keep = 1.0 - np.clip((np.arange(pairs) - low) / (high - low), 0.0, 1.0)
+    inv = extra / factor * (1.0 - keep) + extra * keep
+    return (jnp.arange(length, dtype=jnp.float32)[:, None]
+            * jnp.asarray(inv, jnp.float32))
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """yarn's attention factor ``0.1 mscale ln(factor) + 1`` (1 for a
+    factor of 1 or less)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
 def rotate(x, angles):
     """Rotary embedding of the leading ``2 x angles.shape[-1]`` dims of
     ``x [..., T, heads, dim]``; pair ``i`` is dims ``(i, i + pairs)``."""

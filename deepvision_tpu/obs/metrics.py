@@ -428,11 +428,12 @@ def start_exposition_server(port: int, registry: Registry | None = None,
 # (train/steps.vlm_train_step and lm_train_step put them in the step's
 # metrics): totals as counters, the last step's load as gauges. A step
 # carries those of its model: an indexer's selected pairs or a causal
-# attention's pairs, a balancing bias or none.
+# attention's pairs, a balancing bias or none, a multi-token prediction's
+# loss and the hyper-connections' Sinkhorn error or neither.
 TOKEN_STEP_COUNTERS = ("moe_local_assignments", "moe_dropped",
                        "dsa_selected_pairs", "attn_causal_pairs")
 TOKEN_STEP_GAUGES = ("moe_expert_tokens_max", "moe_expert_tokens_mean",
-                     "moe_bias_abs_mean")
+                     "moe_bias_abs_mean", "mtp_loss", "mhc_sinkhorn_err")
 
 
 def record_token_step(metrics: dict, registry: Registry | None = None

@@ -415,20 +415,26 @@ def backward(q, k, v, scores, thr, t0, lse, di, do, dk, dv, *,
 # one again in every index map, so it costs a grid step and no fetch.
 
 
-# Heads a grid step. Forward: with 8 the step's blocks and scratch pass
-# the 16 MB of scoped VMEM that XLA holds the call to inside the chunks'
-# loop (found compiling the cell's step for a described v5e).
+# Most heads a grid step. Forward: with 8 the step's blocks and scratch
+# pass the 16 MB of scoped VMEM that XLA holds the call to inside the
+# chunks' loop (found compiling the cell's step for a described v5e).
 FORWARD_HEADS, BACKWARD_HEADS = 4, 8
 
 
 def _latent_sizes(q, q_rope, k_rope, v, keys, most: int):
-    """-> (queries, keys, heads, heads a grid step: ``most`` or all of
-    them, dn, dr, dv, key tile)."""
+    """-> (queries, keys, heads, heads a grid step: the largest divisor
+    of the heads that is at most ``most``, dn, dr, dv, key tile)."""
     dr = k_rope.shape[1]
     heads = q_rope.shape[1] // dr
     keys = k_rope.shape[0] if keys is None else keys
-    return (q.shape[0], keys, heads, most if heads % most == 0 else heads,
-            q.shape[1] // heads, dr, v.shape[1] // heads, key_tile(keys))
+    step = max(n for n in range(1, min(most, heads) + 1) if heads % n == 0)
+    return (q.shape[0], keys, heads, step, q.shape[1] // heads, dr,
+            v.shape[1] // heads, key_tile(keys))
+
+
+def _scale(scale, dn: int, dr: int) -> float:
+    """The softmax's scale: the caller's, or ``1 / sqrt(dn + dr)``."""
+    return 1.0 / math.sqrt(dn + dr) if scale is None else float(scale)
 
 
 def _cols(r: int, width: int) -> slice:
@@ -494,9 +500,10 @@ def _latent_forward_kernel(t0_ref, q_ref, qr_ref, k_ref, kr_ref, v_ref,
 
 
 def latent_forward(q, q_rope, k, k_rope, v, kmax, t0, *,
-                   keys: int | None = None, interpret: bool | None = None):
+                   keys: int | None = None, scale: float | None = None,
+                   interpret: bool | None = None):
     """One chunk of causal attention with scores ``(q_h . k_h + q_rope_h
-    . k_rope) / sqrt(dn + dr)``. ``q [Tq, heads x dn]``, ``q_rope [Tq,
+    . k_rope) x scale``, ``scale`` by default ``1 / sqrt(dn + dr)``. ``q [Tq, heads x dn]``, ``q_rope [Tq,
     heads x dr]``, ``k [>= keys, heads x dn]``, ``k_rope [>= keys, dr]``,
     ``v [>= keys, heads x dv]``, ``kmax [heads]`` float32 (the maximum
     of :func:`latent_key_norms` over the keys), ``t0`` the first query's
@@ -526,7 +533,7 @@ def latent_forward(q, q_rope, k, k_rope, v, kmax, t0, *,
                         pltpu.VMEM((hb, tq, LANES), jnp.float32)])
     o, lse = pl.pallas_call(
         functools.partial(_latent_forward_kernel, heads=hb,
-                          scale=1.0 / math.sqrt(dn + dr)),
+                          scale=_scale(scale, dn, dr)),
         grid_spec=grid,
         out_shape=[jax.ShapeDtypeStruct((tq, heads * dv), q.dtype),
                    jax.ShapeDtypeStruct((heads // hb, hb, tq), jnp.float32)],
@@ -610,13 +617,15 @@ def _latent_backward_kernel(t0_ref, q_ref, qr_ref, do_ref, k_ref, kr_ref,
 
 def latent_backward(q, q_rope, k, k_rope, v, t0, lse, di, do, dk, dk_rope,
                     dv, *, keys: int | None = None,
+                    scale: float | None = None,
                     interpret: bool | None = None):
     """The chunk's cotangents from its output's (``do [Tq, heads x
     dv]``), the forward's ``lse`` and ``di [heads, Tq] = sum(o * do)``
     per head. ``dk``, ``dk_rope``, ``dv`` (float32, ``[>= keys, .]``)
     are the sums over the chunks so far; this chunk's part is added to
-    their first ``keys`` rows in place. -> (``dq``, ``dq_rope`` in
-    ``q``'s dtype, ``dk``, ``dk_rope``, ``dv``)."""
+    their first ``keys`` rows in place; ``scale`` is the forward's. ->
+    (``dq``, ``dq_rope`` in ``q``'s dtype, ``dk``, ``dk_rope``,
+    ``dv``)."""
     interpret = _interpret() if interpret is None else interpret
     tq, keys, heads, hb, dn, dr, dv_dim, tk = _latent_sizes(
         q, q_rope, k_rope, v, keys, BACKWARD_HEADS)
@@ -646,7 +655,7 @@ def latent_backward(q, q_rope, k, k_rope, v, t0, lse, di, do, dk, dk_rope,
                         pltpu.VMEM((steps, tq, hb * dr), jnp.float32)])
     return pl.pallas_call(
         functools.partial(_latent_backward_kernel, heads=hb,
-                          scale=1.0 / math.sqrt(dn + dr)),
+                          scale=_scale(scale, dn, dr)),
         grid_spec=grid,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(q_rope.shape, q.dtype),

@@ -388,6 +388,51 @@ TRAINING_CONFIG: dict[str, dict] = {
         "optimizer_params": {"lr": 1e-3},
         "total_epochs": 2,
     },
+    # Xing4.0-29B-A4B (models/hyper_latent.py): kanana's layers on a
+    # residual of 4 streams mixed by manifold-constrained
+    # hyper-connections, a compressed query, yarn rotary, 64 experts
+    # top-4 with one shared expert, two leading dense layers and one
+    # multi-token-prediction module; the optimiser is kanana's. A
+    # document is 4,097 ids: 4,096 positions (the config's original
+    # pre-training length), next-token and second-next-token labels.
+    "xing4": {
+        "precision": "bf16",
+        "batch_size": 1,
+        "text_len": 4097,
+        "dataset": "lm",
+        "steps": "lm",
+        "optimizer": "adam",
+        "optimizer_params": {"lr": 1e-4},
+        "scheduler": "warmup",
+        "scheduler_params": {"warmup_steps": 2000},
+        "total_epochs": 1,
+    },
+    # one chip's share of an 8-chip tensor- and expert-parallel layer: 4
+    # of 32 heads, 8 of 64 experts, 16,384 of 131,072 vocabulary rows,
+    # one dense and 4 expert blocks and the MTP module (the benchmark's
+    # xing4_29b_a4b.train_mtp)
+    "xing4_ep8tp8": {
+        "precision": "bf16",
+        "batch_size": 2,
+        "text_len": 4097,
+        "dataset": "lm",
+        "steps": "lm",
+        "optimizer": "adam",
+        "optimizer_params": {"lr": 1e-4},
+        "scheduler": "warmup",
+        "scheduler_params": {"warmup_steps": 2000},
+        "total_epochs": 1,
+    },
+    "xing4_tiny": {
+        "precision": "bf16",
+        "batch_size": 8,
+        "text_len": 65,
+        "dataset": "lm",
+        "steps": "lm",
+        "optimizer": "adam",
+        "optimizer_params": {"lr": 1e-3},
+        "total_epochs": 2,
+    },
 }
 
 

@@ -369,10 +369,14 @@ def vlm_eval_step(state: TrainState, batch: dict,
 
 
 def lm_train_step(state: TrainState, batch: dict, key: jax.Array,
-                  bias_rate: float = 1e-3):
-    """One step of the text token model on {'tokens'}
-    (``models/latent_moe.LatentMoeLM``): mean next-token cross-entropy
-    over the vocabulary the model holds, no auxiliary loss. The state
+                  bias_rate: float = 1e-3, mtp_weight: float = 0.3):
+    """One step of a text token model on {'tokens'}
+    (``models/latent_moe.LatentMoeLM``, ``models/hyper_latent``): mean
+    next-token cross-entropy over the vocabulary the model holds, plus
+    ``mtp_weight`` times the mean cross-entropy of the multi-token
+    prediction where the model has such a module (``mtp_nll``; 0.3 is
+    DeepSeek-V3's weight of its first training phase); no auxiliary
+    loss. The state
     holds a leaf the optimiser never moves and a rule does: each expert
     layer's selection bias has a gradient of 0 (it enters only the
     discrete choice; Adam leaves such a leaf where it is), and after the
@@ -385,7 +389,10 @@ def lm_train_step(state: TrainState, batch: dict, key: jax.Array,
     ``moe_dropped``, as :func:`vlm_train_step`'s), ``moe_bias_abs_mean``
     (the mean of ``|b|`` over all bias entries after the rule: how far
     it has carried the bias) and ``attn_causal_pairs`` (query-key pairs
-    attention ran over)."""
+    attention ran over); a model with the module adds ``lm_loss`` and
+    ``mtp_loss`` (the two means) and ``mhc_sinkhorn_err`` (the largest
+    ``|row or column sum - 1|`` of any hyper-connection's mixing
+    matrix)."""
     from deepvision_tpu.models.latent_moe import (
         balance_router_bias,
         router_bias_abs_mean,
@@ -397,6 +404,8 @@ def lm_train_step(state: TrainState, batch: dict, key: jax.Array,
     def loss_fn(params):
         out = state.apply_fn({"params": params}, inputs, train=True)
         loss = jnp.mean(out["nll"])
+        if "mtp_nll" in out:
+            loss = loss + mtp_weight * jnp.mean(out["mtp_nll"])
         return state.scale_loss(loss), (loss, out)
 
     (_, (loss, out)), grads = jax.value_and_grad(
@@ -417,6 +426,10 @@ def lm_train_step(state: TrainState, batch: dict, key: jax.Array,
         "attn_causal_pairs": jnp.sum(out["causal_pairs"]),
         **precision_metrics(new_state),
     }
+    if "mtp_nll" in out:
+        metrics.update(lm_loss=jnp.mean(out["nll"]),
+                       mtp_loss=jnp.mean(out["mtp_nll"]),
+                       mhc_sinkhorn_err=jnp.max(out["mhc_sinkhorn_err"]))
     return new_state, metrics
 
 
@@ -427,8 +440,11 @@ def lm_eval_step(state: TrainState, batch: dict) -> dict:
         mask = jnp.ones(batch["tokens"].shape[0], jnp.float32)
     out = state.apply_fn({"params": state.params},
                          {"tokens": batch["tokens"]}, train=False)
-    return {"loss_sum": jnp.sum(jnp.mean(out["nll"], -1) * mask),
+    sums = {"loss_sum": jnp.sum(jnp.mean(out["nll"], -1) * mask),
             "count": jnp.sum(mask)}
+    if "mtp_nll" in out:
+        sums["mtp_loss_sum"] = jnp.sum(jnp.mean(out["mtp_nll"], -1) * mask)
+    return sums
 
 
 def aggregate_eval_parts(parts) -> tuple[dict, float]:

@@ -32,6 +32,7 @@ from test_dsa_attention import (  # noqa: E402, F401
 
 F32, BF16 = jnp.float32, jnp.bfloat16
 FIXTURE = ROOT / "tests/fixtures/keye_dsa_kernels.jaxpr.txt"
+KANANA_FIXTURE = ROOT / "tests/fixtures/kanana_mla_kernels.jaxpr.txt"
 
 
 def _dsa():
@@ -170,6 +171,52 @@ def test_kernel_attention_matches_causal_attention(name, dtype, tol):
     for g, w in zip(got, want):
         assert g.dtype == w.dtype and g.shape == w.shape
         assert _gap(g, w) < tol
+
+
+def test_four_held_heads_and_the_callers_scale():
+    """The hyper-connected model's share: 4 heads (the backward takes all
+    four a grid step, as 8 do not divide them) and yarn's softmax scale
+    (2.00475 / sqrt(192)), output and gradients against the XLA form
+    with the same scale."""
+    import math
+
+    t, heads, key_block, q_chunk = 256, 4, 128, 128
+    scale = (0.1 * math.log(64) + 1) ** 2 / math.sqrt(192)
+    args = _batch_of(t, heads, F32)
+    weights = jax.random.normal(jax.random.key(9), (2, t, heads * 128), F32)
+
+    def scalar(fn):
+        def loss(*a):
+            o = fn(*a)
+            return jnp.sum(o * weights), o
+        return jax.jit(jax.value_and_grad(loss, range(5), has_aux=True))
+
+    xla = scalar(lambda *a: jax.lax.map(lambda b: L.causal_attention(
+        *b, key_block=key_block, q_chunk=q_chunk, dtype=F32, scale=scale),
+        _whole(*a)))
+    kernel = scalar(lambda *a: L.kernel_attention(*a, key_block, q_chunk,
+                                                  scale))
+    (_, want_o), want = xla(*args)
+    (_, o), got = kernel(*args)
+    assert _gap(o, want_o) < 2e-5
+    for g, w in zip(got, want):
+        assert _gap(g, w) < 2e-5
+    # without the scale the result is another one
+    (_, plain_o), _ = scalar(lambda *a: L.kernel_attention(
+        *a, key_block, q_chunk))(*args)
+    assert _gap(plain_o, want_o) > 1e-2
+
+
+@pytest.mark.parametrize("heads,steps", [
+    (32, (4, 8)), (16, (4, 8)), (4, (4, 4)), (6, (3, 6)), (2, (2, 2))])
+def test_heads_a_grid_step_divide_the_heads_held(heads, steps):
+    dsa = _dsa()
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+    q, q_rope = shape(128, heads * 128), shape(128, heads * 64)
+    k_rope, v = shape(512, 64), shape(512, heads * 128)
+    got = tuple(dsa._latent_sizes(q, q_rope, k_rope, v, None, most)[3]
+                for most in (dsa.FORWARD_HEADS, dsa.BACKWARD_HEADS))
+    assert got == steps
 
 
 # ------------------------------------------------- which path a site takes
@@ -317,6 +364,49 @@ def test_keyes_kernels_trace_to_the_operations_they_were():
     assert keye_kernels_jaxpr() == FIXTURE.read_text()
 
 
+def kanana_kernels_jaxpr() -> str:
+    """``latent_forward`` and ``latent_backward`` traced at the kanana
+    cell's shapes (512 queries of 32 heads, 128 + 64 wide for scores and
+    128 for values, over the first 2,048 of 8,192 keys, bf16, the
+    default scale): every operation of the two kernels, their grids,
+    blocks and each block's index map."""
+    dsa = _dsa()
+    tq, keys, heads, dn, dr, dv = 512, 2048, 32, 128, 64, 128
+    shape = jax.ShapeDtypeStruct
+    q, qr = shape((tq, heads * dn), BF16), shape((tq, heads * dr), BF16)
+    k, kr = shape((8192, heads * dn), BF16), shape((8192, dr), BF16)
+    v, kmax = shape((8192, heads * dv), BF16), shape((heads,), F32)
+    t0, rows = shape((), jnp.int32), shape((heads, tq), F32)
+    do = shape((tq, heads * dv), BF16)
+    sums = [shape((8192, heads * dn), F32), shape((8192, dr), F32),
+            shape((8192, heads * dv), F32)]
+    traced = [
+        jax.make_jaxpr(lambda *a: dsa.latent_forward(
+            *a, keys=keys, interpret=False))(q, qr, k, kr, v, kmax, t0),
+        jax.make_jaxpr(lambda *a: dsa.latent_backward(
+            *a, keys=keys, interpret=False))(
+            q, qr, k, kr, v, t0, rows, rows, do, *sums)]
+    lines = []
+    for jaxpr in traced:
+        lines.append(str(jaxpr))
+        for eqn in jaxpr.jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                lines += [f"{eqn.params['name']} index map: "
+                          f"{bm.index_map_jaxpr}"
+                          for bm in eqn.params["grid_mapping"].block_mappings]
+    return "\n".join(lines) + "\n"
+
+
+def test_kananas_kernels_trace_to_the_operations_they_were():
+    """The fixture was written by this function on the parent of the PR
+    that let the latent kernels take a caller's scale and any number of
+    heads (PR 38): kanana's cell runs the same kernels, its default scale
+    the same constant. To change them on purpose, write the fixture again
+    (``python tests/test_mla_attention.py``) and measure the cell."""
+    assert kanana_kernels_jaxpr() == KANANA_FIXTURE.read_text()
+
+
 if __name__ == "__main__":
     FIXTURE.parent.mkdir(exist_ok=True)
     FIXTURE.write_text(keye_kernels_jaxpr())
+    KANANA_FIXTURE.write_text(kanana_kernels_jaxpr())

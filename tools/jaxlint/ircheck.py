@@ -651,6 +651,13 @@ def make_cases() -> dict[str, IRCase]:
         _token_build("kanana2_tiny"),
         "text token model: latent attention + biased sigmoid router + "
         "shared expert + leading dense layer, int32 tokens")
+    # likewise the three xing4 entries (benchmark cell
+    # xing4_29b_a4b.train_mtp)
+    cases["xing4_tiny"] = IRCase(
+        "xing4_tiny", ("xing4_tiny", "xing4_ep8tp8", "xing4"), 2,
+        _token_build("xing4_tiny"),
+        "text token model: latent attention on 4 hyper-connected streams "
+        "+ MTP module, int32 tokens; no hbm baseline")
     return cases
 
 
