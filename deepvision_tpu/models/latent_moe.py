@@ -68,7 +68,6 @@ from deepvision_tpu.models.transformer import (
     RMSNorm,
     _blocks,
     _causal,
-    _chunks_of,
     _map_chunks,
     _stacked,
     moe_layer,
@@ -154,102 +153,60 @@ def mla_engages(t: int, nope_dim: int, rope_dim: int, v_dim: int,
             and v_dim % 128 == 0 and rope_dim % 64 == 0 and chunk % 128 == 0)
 
 
-def _kernel_forward(q, q_rope, k, k_rope, v, key_block, q_chunk, scale):
-    """:func:`causal_attention` of every sequence, a chunk of queries a
-    kernel call. -> (``[B, T, heads x dv]``, log-sum-exp ``[B, chunks,
-    heads, chunk]``)"""
+def _kernel_forward(q, q_rope, kv, k_rope, key_block, q_chunk, scale):
+    """:func:`causal_attention` of every sequence in one kernel call. ->
+    (``[B, T, heads x dv]``, log-sum-exp ``[B, heads, T]``)"""
     from deepvision_tpu.ops import dsa_attention as dsa
 
-    def sequence(args):
-        q, q_rope, k, k_rope, v = args
-        t, heads = q.shape[:2]
-        block, chunk = _blocks(t, key_block, q_chunk)
-        q, q_rope, k, v = (a.reshape(t, -1) for a in (q, q_rope, k, v))
-        norms = dsa.latent_key_norms(k, k_rope, heads)
-        outs, lses = [], []
-        for b0 in range(0, t, block):
-            end = b0 + block
-            kmax = jnp.max(norms[:end], 0)
-
-            def one(args, end=end, kmax=kmax):
-                (qc, qrc), t0 = args
-                return dsa.latent_forward(qc, qrc, k, k_rope, v, kmax, t0,
-                                          keys=end, scale=scale)
-
-            o, lse = _map_chunks(one, (q, q_rope), b0, block, chunk)
-            outs.append(o.reshape(block, -1))
-            lses.append(lse)
-        return jnp.concatenate(outs), jnp.concatenate(lses)
-
+    t = q.shape[1]
+    heads = q_rope.shape[-1] // k_rope.shape[-1]
+    block, chunk = _blocks(t, key_block, q_chunk)
     with jax.named_scope("lm/mla/attn"):
-        return lax.map(sequence, (q, q_rope, k, k_rope, v))
+        kmax = dsa.latent_key_norms(kv, k_rope, heads, q.shape[-1] // heads,
+                                    block)
+        return dsa.latent_forward(q, q_rope, kv, k_rope, kmax, q_chunk=chunk,
+                                  scale=scale)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def kernel_attention(q, q_rope, k, k_rope, v, key_block, q_chunk,
-                     scale=None):
-    """Causal latent attention of a batch through the kernels: ``q``,
-    ``k`` ``[B, T, heads, dn]``, ``q_rope [B, T, heads, dr]`` and the
-    one rotary key ``k_rope [B, T, dr]`` (both rotated), ``v [B, T,
-    heads, dv]`` -> ``[B, T, heads x dv]``; the logits' ``scale`` is by
-    default ``1 / sqrt(dn + dr)``.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def kernel_attention(q, q_rope, kv, k_rope, key_block, q_chunk, scale=None):
+    """Causal latent attention of a batch through the kernels, heads side
+    by side as the projections write them: ``q [B, T, heads x dn]``,
+    ``q_rope [B, T, heads x dr]`` and the one rotary key ``k_rope [B, T,
+    dr]`` (both rotated), ``kv [B, T, heads x (dn + dv)]`` (each head's
+    key, then its values) -> ``[B, T, heads x dv]``; the logits' ``scale``
+    is by default ``1 / sqrt(dn + dr)``.
 
     The backward is written out (``custom_vjp``), as
     ``transformer.kernel_attention``'s: what the forward keeps is the
     output and each row's log-sum-exp, named ``attn_out`` and
     ``mla_lse`` so that a recomputed layer keeps them too and never
-    runs the forward kernel twice; chunk by chunk the backward kernel
-    writes ``dq`` and adds to the float32 sums of ``dk``, ``dk_rope``
-    and ``dv`` that the chunks' scan carries, in place."""
-    return _kernel_forward(q, q_rope, k, k_rope, v, key_block, q_chunk,
+    runs the forward kernel twice; one backward kernel call writes
+    ``dq``, ``dq_rope``, ``dk_rope`` and the cotangent of ``kv`` in its
+    layout."""
+    return _kernel_forward(q, q_rope, kv, k_rope, key_block, q_chunk,
                            scale)[0]
 
 
-def _kernel_attention_fwd(q, q_rope, k, k_rope, v, key_block, q_chunk,
-                          scale):
-    o, lse = _kernel_forward(q, q_rope, k, k_rope, v, key_block, q_chunk,
+def _kernel_attention_fwd(q, q_rope, kv, k_rope, key_block, q_chunk, scale):
+    o, lse = _kernel_forward(q, q_rope, kv, k_rope, key_block, q_chunk,
                              scale)
     o, lse = checkpoint_name(o, "attn_out"), checkpoint_name(lse, "mla_lse")
-    return o, (q, q_rope, k, k_rope, v, o, lse)
+    return o, (q, q_rope, kv, k_rope, o, lse)
 
 
 def _kernel_attention_bwd(key_block, q_chunk, scale, kept, do):
     from deepvision_tpu.ops import dsa_attention as dsa
 
-    def sequence(args):
-        q, q_rope, k, k_rope, v, o, lse, do = args
-        t, heads = q.shape[:2]
-        block, chunk = _blocks(t, key_block, q_chunk)
-        shapes = q.shape, q_rope.shape, k.shape, v.shape
-        q, q_rope, k, v = (a.reshape(t, -1) for a in (q, q_rope, k, v))
-        f32 = jnp.float32
-        sums = tuple(jnp.zeros(a.shape, f32) for a in (k, k_rope, v))
-        dq, dq_rope = [], []
-        for b0 in range(0, t, block):
-            end = b0 + block
-
-            def one(sums, args, end=end):
-                (qc, qrc, o_c, do_c), t0 = args
-                di = jnp.sum((o_c.astype(f32) * do_c.astype(f32)).reshape(
-                    chunk, heads, -1), -1).T
-                dq_c, dqr_c, *sums = dsa.latent_backward(
-                    qc, qrc, k, k_rope, v, t0, lse[t0 // chunk], di, do_c,
-                    *sums, keys=end, scale=scale)
-                return tuple(sums), (dq_c, dqr_c)
-
-            sums, parts = lax.scan(
-                one, sums, _chunks_of((q, q_rope, o, do), b0, block, chunk))
-            for out, part in zip((dq, dq_rope), parts):
-                out.append(part.reshape(block, -1))
-        dk, dk_rope, dv = sums
-        return (jnp.concatenate(dq).reshape(shapes[0]),
-                jnp.concatenate(dq_rope).reshape(shapes[1]),
-                dk.astype(k.dtype).reshape(shapes[2]),
-                dk_rope.astype(k_rope.dtype),
-                dv.astype(v.dtype).reshape(shapes[3]))
-
+    q, q_rope, kv, k_rope, o, lse = kept
+    b, t, heads = q.shape[0], q.shape[1], lse.shape[1]
+    f32 = jnp.float32
     with jax.named_scope("lm/mla/attn"):
-        return lax.map(sequence, (*kept, do))
+        di = jnp.sum((o.astype(f32) * do.astype(f32)).reshape(
+            b, t, heads, -1), -1)
+        return dsa.latent_backward(
+            q, q_rope, kv, k_rope, lse, jnp.swapaxes(di, 1, 2), do,
+            q_chunk=_blocks(t, key_block, q_chunk)[1], scale=scale)
 
 
 kernel_attention.defvjp(_kernel_attention_fwd, _kernel_attention_bwd)
@@ -333,17 +290,20 @@ class _LatentAttention(nn.Module):
             c_kv = RMSNorm(c.rms_eps, name="kv_norm")(
                 latent[..., :c.kv_rank])
             k_rope = rotate(latent[..., None, c.kv_rank:], angles)
-            kv = compute_dot(c_kv, wkvb, dt).astype(dt).reshape(
-                b, t, heads, dn + dv)
-            k, v = kv[..., :dn], kv[..., dn:]
-            if not by_kernel:
+            kv = compute_dot(c_kv, wkvb, dt).astype(dt)
+            if by_kernel:
+                # the kernels read heads side by side in lane rows
+                q, q_rope = q.reshape(b, t, -1), q_rope.reshape(b, t, -1)
+            else:
+                kv = kv.reshape(b, t, heads, dn + dv)
+                k, v = kv[..., :dn], kv[..., dn:]
                 # the one rotary key stands in every head's
                 q = jnp.concatenate([q, q_rope], -1)
                 k = jnp.concatenate([k, jnp.broadcast_to(
                     k_rope, (b, t, heads, dr))], -1)
         if by_kernel:
-            o = kernel_attention(q, q_rope, k, k_rope[:, :, 0], v,
-                                 c.key_block, c.q_chunk, c.softmax_scale)
+            o = kernel_attention(q, q_rope, kv, k_rope[:, :, 0], c.key_block,
+                                 c.q_chunk, c.softmax_scale)
         else:
             o = lax.map(lambda a: causal_attention(
                 *a, key_block=c.key_block, q_chunk=c.q_chunk, dtype=dt,

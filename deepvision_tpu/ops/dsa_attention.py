@@ -53,6 +53,7 @@ default it is chosen by the backend.
 from __future__ import annotations
 
 import functools
+import itertools
 import math
 
 import jax
@@ -407,29 +408,68 @@ def backward(q, k, v, scores, thr, t0, lse, di, do, dk, dv, *,
 # wider or narrower than score heads, and a head's score is the sum of
 # two products: ``q . k`` over the head's own ``dn`` columns and
 # ``q_rope . k_rope`` over ``dr`` rotary columns whose key is ONE
-# ``[keys, dr]`` array for all heads (never broadcast). Key tiles, the
-# live-tile test, the bound-shifted softmax, the lane-wise row sums and
-# the transposed-tile backward are the ones above. Keys, values and the
-# sums may hold more rows than ``keys``: the grid covers the first
-# ``keys``, and a tile above the chunk's last query names the last live
-# one again in every index map, so it costs a grid step and no fetch.
+# ``[T, dr]`` array for all heads (never broadcast). The bound-shifted
+# softmax, the lane-wise row sums and the transposed-tile backward are
+# the ones above.
+#
+# One call takes a whole batch in each direction: the grid is (sequence,
+# head step, live pair), and two scalar-prefetched tables name each
+# live (query chunk, key tile) pair, so that no grid step lies above the
+# diagonal and nothing loops around the call. The operands are read in
+# the projections' layouts, heads side by side in lane rows: ``kv [B, T,
+# heads x (dn + dv)]`` holds head ``r``'s key at columns ``r (dn + dv)``
+# and its values right after it, and the backward writes their cotangent
+# in that layout. The forward walks the pairs chunk by chunk (a chunk's
+# output gathers over its tiles), the backward tile by tile (a tile's
+# ``dk`` and ``dv`` gather over its chunks in VMEM and leave it once, cast;
+# every chunk's ``dq`` gathers in a float32 scratch of the sequence for
+# the step's heads and leaves it after the chunk's last tile).
 
 
-# Most heads a grid step. Forward: with 8 the step's blocks and scratch
-# pass the 16 MB of scoped VMEM that XLA holds the call to inside the
-# chunks' loop (found compiling the cell's step for a described v5e).
+# Most heads a grid step. The forward's 4 are what fitted the 16 MB of
+# scoped VMEM that XLA held a call to inside a loop over chunks (found
+# compiling the cell's step for a described v5e); no such loop is left,
+# and 8 read 2.5% faster alone on a v5e, but are not measured in a step.
 FORWARD_HEADS, BACKWARD_HEADS = 4, 8
 
 
-def _latent_sizes(q, q_rope, k_rope, v, keys, most: int):
-    """-> (queries, keys, heads, heads a grid step: the largest divisor
-    of the heads that is at most ``most``, dn, dr, dv, key tile)."""
-    dr = k_rope.shape[1]
-    heads = q_rope.shape[1] // dr
-    keys = k_rope.shape[0] if keys is None else keys
-    step = max(n for n in range(1, min(most, heads) + 1) if heads % n == 0)
-    return (q.shape[0], keys, heads, step, q.shape[1] // heads, dr,
-            v.shape[1] // heads, key_tile(keys))
+def head_step(heads: int, most: int) -> int:
+    """Heads a grid step: the largest divisor of ``heads`` that is at
+    most ``most``."""
+    return max(n for n in range(1, min(most, heads) + 1) if heads % n == 0)
+
+
+def _latent_widths(q, q_rope, kv, k_rope) -> tuple:
+    """-> (heads, dn, dr, dv) of the operands' last axes."""
+    dr = k_rope.shape[-1]
+    heads = q_rope.shape[-1] // dr
+    dn = q.shape[-1] // heads
+    return heads, dn, dr, kv.shape[-1] // heads - dn
+
+
+def _last_tile(c, tq: int, tk: int):
+    """The key tile that holds query chunk ``c``'s last query."""
+    return (c * tq + tq - 1) // tk
+
+
+def _table(values) -> jax.Array:
+    """A scalar-prefetched table of the grid's live pairs."""
+    return jnp.asarray(values, jnp.int32)
+
+
+def live_pairs(t: int, tq: int, tk: int, by_tile: bool = False):
+    """The (query chunk, key tile) pairs whose tile holds a key at or
+    below the chunk's last query, chunk by chunk with each chunk's tiles
+    in order (``by_tile``: tile by tile with each tile's chunks in
+    order) -> (chunks, tiles), a tuple of ints each."""
+    chunks = range(t // tq)
+    if by_tile:
+        pairs = [(c, kk) for kk in range(t // tk) for c in chunks
+                 if _last_tile(c, tq, tk) >= kk]
+    else:
+        pairs = [(c, kk) for c in chunks
+                 for kk in range(_last_tile(c, tq, tk) + 1)]
+    return tuple(zip(*pairs))
 
 
 def _scale(scale, dn: int, dr: int) -> float:
@@ -450,22 +490,27 @@ def _causal_tile(t0, kk, tq: int, tk: int, transposed: bool = False):
     return key <= query
 
 
-def latent_key_norms(k, k_rope, heads: int):
-    """``[T, heads]`` float32: the norm of each head's whole key ``[k_h |
-    k_rope]``. Its maximum over a call's keys is the key part of the
-    softmax's bound (the ``kmax`` operand below)."""
-    t = k.shape[0]
+def latent_key_norms(kv, k_rope, heads: int, dn: int, block: int):
+    """``[..., T / block, heads]`` float32: the largest norm of each
+    head's whole key ``[k_h | k_rope]`` (``k_h`` the first ``dn`` of head
+    ``h``'s columns of ``kv [..., T, heads x (dn + dv)]``) over the keys
+    up to each block's end: the key part of the softmax's bound for the
+    block's queries (``kmax``)."""
     sq = lambda a: jnp.sum(jnp.square(a.astype(jnp.float32)), -1)
-    return jnp.sqrt(sq(k.reshape(t, heads, -1)) + sq(k_rope)[:, None])
+    k = kv.reshape(*kv.shape[:-1], heads, -1)[..., :dn]
+    norms = jnp.sqrt(sq(k) + sq(k_rope)[..., None])
+    *lead, t = norms.shape[:-1]
+    blocks = jnp.max(norms.reshape(*lead, t // block, block, heads), -2)
+    return lax.cummax(blocks, len(lead))
 
 
-def _latent_forward_kernel(t0_ref, q_ref, qr_ref, k_ref, kr_ref, v_ref,
-                           kmax_ref, o_ref, lse_ref, acc_ref, l_ref,
+def _latent_forward_kernel(chunk_ref, tile_ref, q_ref, qr_ref, kv_ref,
+                           kr_ref, kmax_ref, o_ref, lse_ref, acc_ref, l_ref,
                            bound_ref, *, heads: int, scale: float):
-    tq, tk = q_ref.shape[0], k_ref.shape[0]
+    tq, tk = q_ref.shape[0], kv_ref.shape[0]
     dn, dr, dv = q_ref.shape[1] // heads, kr_ref.shape[1], acc_ref.shape[2]
-    kk, last = pl.program_id(1), pl.num_programs(1) - 1
-    t0 = t0_ref[0]
+    pair = pl.program_id(2)
+    t0, kk = chunk_ref[pair] * tq, tile_ref[pair]
 
     @pl.when(kk == 0)
     def _():
@@ -479,54 +524,59 @@ def _latent_forward_kernel(t0_ref, q_ref, qr_ref, k_ref, kr_ref, v_ref,
             bound_ref[r] = (jnp.broadcast_to(norm, (tq, LANES))
                             * kmax_ref[:, _cols(r, LANES)] * scale)
 
-    @pl.when(_tile_is_live(t0, kk, tq, tk))
-    def _():
-        keep = _causal_tile(t0, kk, tq, tk)
-        kr_t = kr_ref[...]
-        for r in range(heads):
-            s = lax.dot_general(q_ref[:, _cols(r, dn)],
-                                k_ref[:, _cols(r, dn)], _NT,
-                                preferred_element_type=jnp.float32)
-            s += lax.dot_general(qr_ref[:, _cols(r, dr)], kr_t, _NT,
-                                 preferred_element_type=jnp.float32)
-            z = s * scale - _lane_tiles(bound_ref[r], tk // LANES)
-            p = jnp.where(keep, jnp.exp(jnp.maximum(z, -80.0)), 0.0)
-            _accumulate(r, p, v_ref[:, _cols(r, dv)], l_ref, acc_ref)
+    keep = _causal_tile(t0, kk, tq, tk)
+    kr_t = kr_ref[...]
+    for r in range(heads):
+        k0 = r * (dn + dv)
+        s = lax.dot_general(q_ref[:, _cols(r, dn)], kv_ref[:, k0:k0 + dn],
+                            _NT, preferred_element_type=jnp.float32)
+        s += lax.dot_general(qr_ref[:, _cols(r, dr)], kr_t, _NT,
+                             preferred_element_type=jnp.float32)
+        z = s * scale - _lane_tiles(bound_ref[r], tk // LANES)
+        p = jnp.where(keep, jnp.exp(jnp.maximum(z, -80.0)), 0.0)
+        _accumulate(r, p, kv_ref[:, k0 + dn:k0 + dn + dv], l_ref, acc_ref)
 
-    @pl.when(kk == last)
+    @pl.when(kk == (t0 + tq - 1) // tk)           # the chunk's last tile
     def _():
         for r in range(heads):
             _finish(r, o_ref, lse_ref, acc_ref, l_ref, bound_ref)
 
 
-def latent_forward(q, q_rope, k, k_rope, v, kmax, t0, *,
-                   keys: int | None = None, scale: float | None = None,
+def latent_forward(q, q_rope, kv, k_rope, kmax, *, q_chunk: int,
+                   scale: float | None = None,
                    interpret: bool | None = None):
-    """One chunk of causal attention with scores ``(q_h . k_h + q_rope_h
-    . k_rope) x scale``, ``scale`` by default ``1 / sqrt(dn + dr)``. ``q [Tq, heads x dn]``, ``q_rope [Tq,
-    heads x dr]``, ``k [>= keys, heads x dn]``, ``k_rope [>= keys, dr]``,
-    ``v [>= keys, heads x dv]``, ``kmax [heads]`` float32 (the maximum
-    of :func:`latent_key_norms` over the keys), ``t0`` the first query's
-    position among the keys, ``keys`` (all rows by default) how many
-    the chunk attends over. -> (output ``[Tq, heads x dv]`` in ``q``'s
-    dtype, log-sum-exp ``[heads, Tq]`` float32)."""
+    """Causal attention of a batch with scores ``(q_h . k_h + q_rope_h .
+    k_rope) x scale``, ``scale`` by default ``1 / sqrt(dn + dr)``: ``q [B,
+    T, heads x dn]``, ``q_rope [B, T, heads x dr]``, ``kv [B, T, heads x
+    (dn + dv)]`` (head ``h``'s key at columns ``h (dn + dv)``, its values
+    after it), ``k_rope [B, T, dr]``; queries in chunks of ``q_chunk``.
+    ``kmax [B, blocks, heads]`` float32: for each of ``blocks`` equal
+    blocks of positions, the maximum of :func:`latent_key_norms` over the
+    keys up to the block's end, which bounds the block's logits. ->
+    (output ``[B, T, heads x dv]`` in ``q``'s dtype, log-sum-exp ``[B,
+    heads, T]`` float32)."""
     interpret = _interpret() if interpret is None else interpret
-    tq, keys, heads, hb, dn, dr, dv, tk = _latent_sizes(
-        q, q_rope, k_rope, v, keys, FORWARD_HEADS)
-    live = lambda kk, t0: jnp.minimum(kk, (t0[0] + tq - 1) // tk)
+    b, t = q.shape[:2]
+    heads, dn, dr, dv = _latent_widths(q, q_rope, kv, k_rope)
+    hb, tq, tk = head_step(heads, FORWARD_HEADS), q_chunk, key_tile(t)
+    block = t // kmax.shape[1]
+    chunks, tiles = live_pairs(t, tq, tk)
+    rows = lambda w: pl.BlockSpec(
+        (None, tq, hb * w), lambda i, g, p, c, kk: (i, c[p], g))
     grid = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(heads // hb, keys // tk),
+        num_scalar_prefetch=2, grid=(b, heads // hb, len(chunks)),
         in_specs=[
-            pl.BlockSpec((tq, hb * dn), lambda g, kk, t0: (0, g)),
-            pl.BlockSpec((tq, hb * dr), lambda g, kk, t0: (0, g)),
-            pl.BlockSpec((tk, hb * dn), lambda g, kk, t0: (live(kk, t0), g)),
-            pl.BlockSpec((tk, dr), lambda g, kk, t0: (live(kk, t0), 0)),
-            pl.BlockSpec((tk, hb * dv), lambda g, kk, t0: (live(kk, t0), g)),
-            pl.BlockSpec((1, hb * LANES), lambda g, kk, t0: (0, g)),
+            rows(dn), rows(dr),
+            pl.BlockSpec((None, tk, hb * (dn + dv)),
+                         lambda i, g, p, c, kk: (i, kk[p], g)),
+            pl.BlockSpec((None, tk, dr), lambda i, g, p, c, kk: (i, kk[p], 0)),
+            pl.BlockSpec((None, None, 1, hb * LANES),
+                         lambda i, g, p, c, kk: (i, c[p] * tq // block, 0, g)),
         ],
         out_specs=[
-            pl.BlockSpec((tq, hb * dv), lambda g, kk, t0: (0, g)),
-            pl.BlockSpec((None, hb, tq), lambda g, kk, t0: (g, 0, 0)),
+            rows(dv),
+            pl.BlockSpec((None, None, hb, tq),
+                         lambda i, g, p, c, kk: (i, g, 0, c[p])),
         ],
         scratch_shapes=[pltpu.VMEM((hb, tq, dv), jnp.float32),
                         pltpu.VMEM((hb, tq, LANES), jnp.float32),
@@ -535,136 +585,139 @@ def latent_forward(q, q_rope, k, k_rope, v, kmax, t0, *,
         functools.partial(_latent_forward_kernel, heads=hb,
                           scale=_scale(scale, dn, dr)),
         grid_spec=grid,
-        out_shape=[jax.ShapeDtypeStruct((tq, heads * dv), q.dtype),
-                   jax.ShapeDtypeStruct((heads // hb, hb, tq), jnp.float32)],
-        compiler_params=_params("parallel", "arbitrary"),
+        out_shape=[jax.ShapeDtypeStruct((b, t, heads * dv), q.dtype),
+                   jax.ShapeDtypeStruct((b, heads // hb, hb, t),
+                                        jnp.float32)],
+        compiler_params=_params("parallel", "parallel", "arbitrary"),
         name="mla_attention_forward", interpret=interpret,
-    )(jnp.asarray(t0, jnp.int32).reshape(1), q, q_rope, k, k_rope, v,
-      jnp.repeat(kmax.astype(jnp.float32), LANES)[None])
-    return o, lse.reshape(heads, tq)
+    )(_table(chunks), _table(tiles), q, q_rope, kv, k_rope,
+      jnp.repeat(kmax.astype(jnp.float32), LANES, -1)[:, :, None])
+    return o, lse.reshape(b, heads, t)
 
 
-def _latent_backward_kernel(t0_ref, q_ref, qr_ref, do_ref, k_ref, kr_ref,
-                            v_ref, lse_ref, di_ref, dk_in_ref, dkr_in_ref,
-                            dv_in_ref, dq_ref, dqr_ref, dk_ref, dkr_ref,
-                            dv_ref, dq_acc_ref, dqr_acc_ref, *, heads: int,
-                            scale: float):
-    tq, tk = q_ref.shape[0], k_ref.shape[0]
-    dn, dr, dv = q_ref.shape[1] // heads, kr_ref.shape[1], \
-        v_ref.shape[1] // heads
-    kk, g = pl.program_id(0), pl.program_id(1)
-    t0 = t0_ref[0]
+def _latent_backward_kernel(chunk_ref, tile_ref, done_ref, q_ref, qr_ref,
+                            do_ref, kv_ref, kr_ref, lse_ref, di_ref, dq_ref,
+                            dqr_ref, dkv_ref, dkr_ref, dq_acc_ref,
+                            dqr_acc_ref, dkv_acc_ref, dkr_acc_ref, *,
+                            heads: int, scale: float):
+    del done_ref                                  # the index maps' alone
+    tq, tk = q_ref.shape[0], kv_ref.shape[0]
+    dn, dr = q_ref.shape[1] // heads, kr_ref.shape[1]
+    dv = do_ref.shape[1] // heads
+    pair = pl.program_id(2)
+    c, kk = chunk_ref[pair], tile_ref[pair]
+    t0 = c * tq
 
-    @pl.when(kk == 0)
+    @pl.when(c == kk * tk // tq)                  # the tile's first chunk
     def _():
-        dq_acc_ref[g] = jnp.zeros(dq_acc_ref.shape[1:], jnp.float32)
-        dqr_acc_ref[g] = jnp.zeros(dqr_acc_ref.shape[1:], jnp.float32)
+        dkv_acc_ref[...] = jnp.zeros_like(dkv_acc_ref)
+        dkr_acc_ref[...] = jnp.zeros_like(dkr_acc_ref)
 
-    # a tile that is not live named the last live one's blocks again:
-    # they stay as that step left them
-    @pl.when(_tile_is_live(t0, kk, tq, tk))
+    @pl.when(kk == 0)                             # the chunk's first tile
     def _():
-        keep = _causal_tile(t0, kk, tq, tk, transposed=True)
-        kr_t = kr_ref[...]
-        dkr = jnp.zeros((tk, dr), jnp.float32)
+        dq_acc_ref[c] = jnp.zeros(dq_acc_ref.shape[1:], jnp.float32)
+        dqr_acc_ref[c] = jnp.zeros(dqr_acc_ref.shape[1:], jnp.float32)
+
+    keep = _causal_tile(t0, kk, tq, tk, transposed=True)
+    kr_t = kr_ref[...]
+    dkr = jnp.zeros((tk, dr), jnp.float32)
+    for r in range(heads):
+        k0 = r * (dn + dv)
+        q_r = q_ref[:, _cols(r, dn)]
+        qr_r = qr_ref[:, _cols(r, dr)]
+        do_r = do_ref[:, _cols(r, dv)]
+        k_t = kv_ref[:, k0:k0 + dn]
+        s = lax.dot_general(k_t, q_r, _NT,
+                            preferred_element_type=jnp.float32)
+        s += lax.dot_general(kr_t, qr_r, _NT,
+                             preferred_element_type=jnp.float32)
+        p = jnp.exp(jnp.where(keep, s * scale - lse_ref[r:r + 1, :],
+                              _NEG))                        # [tk, tq]
+        dkv_acc_ref[:, k0 + dn:k0 + dn + dv] += jnp.dot(
+            p.astype(do_r.dtype), do_r, preferred_element_type=jnp.float32)
+        dp = lax.dot_general(kv_ref[:, k0 + dn:k0 + dn + dv], do_r, _NT,
+                             preferred_element_type=jnp.float32)
+        ds = p * (dp - di_ref[r:r + 1, :])
+        ds_t = ds.astype(q_r.dtype)
+        dkv_acc_ref[:, k0:k0 + dn] += jnp.dot(
+            ds_t, q_r, preferred_element_type=jnp.float32)
+        dkr += jnp.dot(ds_t, qr_r, preferred_element_type=jnp.float32)
+        ds_q = ds.T.astype(k_t.dtype)
+        dq_acc_ref[c, :, _cols(r, dn)] += jnp.dot(
+            ds_q, k_t, preferred_element_type=jnp.float32)
+        dqr_acc_ref[c, :, _cols(r, dr)] += jnp.dot(
+            ds_q, kr_t, preferred_element_type=jnp.float32)
+    dkr_acc_ref[...] += dkr
+
+    @pl.when(kk == (t0 + tq - 1) // tk)           # the chunk's last tile
+    def _():
+        dq_ref[...] = (dq_acc_ref[c] * scale).astype(dq_ref.dtype)
+        dqr_ref[...] = (dqr_acc_ref[c] * scale).astype(dqr_ref.dtype)
+
+    @pl.when(c == dq_acc_ref.shape[0] - 1)        # the tile's last chunk
+    def _():
         for r in range(heads):
-            q_r = q_ref[:, _cols(r, dn)]
-            qr_r = qr_ref[:, _cols(r, dr)]
-            do_r = do_ref[:, _cols(r, dv)]
-            k_t = k_ref[:, _cols(r, dn)]
-            s = lax.dot_general(k_t, q_r, _NT,
-                                preferred_element_type=jnp.float32)
-            s += lax.dot_general(kr_t, qr_r, _NT,
-                                 preferred_element_type=jnp.float32)
-            p = jnp.exp(jnp.where(keep, s * scale - lse_ref[r:r + 1, :],
-                                  _NEG))                        # [tk, tq]
-            dv_ref[:, _cols(r, dv)] = (
-                dv_in_ref[:, _cols(r, dv)]
-                + jnp.dot(p.astype(do_r.dtype), do_r,
-                          preferred_element_type=jnp.float32))
-            dp = lax.dot_general(v_ref[:, _cols(r, dv)], do_r, _NT,
-                                 preferred_element_type=jnp.float32)
-            ds = p * (dp - di_ref[r:r + 1, :])
-            ds_t = ds.astype(q_r.dtype)
-            dk_ref[:, _cols(r, dn)] = (
-                dk_in_ref[:, _cols(r, dn)]
-                + jnp.dot(ds_t, q_r,
-                          preferred_element_type=jnp.float32) * scale)
-            dkr += jnp.dot(ds_t, qr_r, preferred_element_type=jnp.float32)
-            ds_q = ds.T.astype(k_t.dtype)
-            dq_acc_ref[g, :, _cols(r, dn)] += jnp.dot(
-                ds_q, k_t, preferred_element_type=jnp.float32)
-            dqr_acc_ref[g, :, _cols(r, dr)] += jnp.dot(
-                ds_q, kr_t, preferred_element_type=jnp.float32)
-
-        # the one rotary key's sum runs over the heads of every step
-        @pl.when(g == 0)
-        def _():
-            dkr_ref[...] = dkr_in_ref[...] + dkr * scale
-
-        @pl.when(g > 0)
-        def _():
-            dkr_ref[...] += dkr * scale
-
-    @pl.when((kk == pl.num_programs(0) - 1) & (g == pl.num_programs(1) - 1))
-    def _():
-        for g2 in range(dq_acc_ref.shape[0]):
-            dq_ref[:, _cols(g2, heads * dn)] = (
-                dq_acc_ref[g2] * scale).astype(dq_ref.dtype)
-            dqr_ref[:, _cols(g2, heads * dr)] = (
-                dqr_acc_ref[g2] * scale).astype(dqr_ref.dtype)
+            k0 = r * (dn + dv)
+            dkv_ref[:, k0:k0 + dn] = (dkv_acc_ref[:, k0:k0 + dn]
+                                      * scale).astype(dkv_ref.dtype)
+            dkv_ref[:, k0 + dn:k0 + dn + dv] = dkv_acc_ref[
+                :, k0 + dn:k0 + dn + dv].astype(dkv_ref.dtype)
+        dkr_ref[...] = dkr_acc_ref[...] * scale
 
 
-def latent_backward(q, q_rope, k, k_rope, v, t0, lse, di, do, dk, dk_rope,
-                    dv, *, keys: int | None = None,
+def latent_backward(q, q_rope, kv, k_rope, lse, di, do, *, q_chunk: int,
                     scale: float | None = None,
                     interpret: bool | None = None):
-    """The chunk's cotangents from its output's (``do [Tq, heads x
-    dv]``), the forward's ``lse`` and ``di [heads, Tq] = sum(o * do)``
-    per head. ``dk``, ``dk_rope``, ``dv`` (float32, ``[>= keys, .]``)
-    are the sums over the chunks so far; this chunk's part is added to
-    their first ``keys`` rows in place; ``scale`` is the forward's. ->
-    (``dq``, ``dq_rope`` in ``q``'s dtype, ``dk``, ``dk_rope``,
-    ``dv``)."""
+    """The batch's cotangents from its output's (``do [B, T, heads x
+    dv]``), the forward's ``lse`` and ``di [B, heads, T] = sum(o * do)``
+    per head; operands, ``q_chunk`` and ``scale`` as the forward's. ->
+    (``dq``, ``dq_rope``, ``dkv`` in ``kv``'s layout, ``dk_rope``, each
+    in its operand's dtype)."""
     interpret = _interpret() if interpret is None else interpret
-    tq, keys, heads, hb, dn, dr, dv_dim, tk = _latent_sizes(
-        q, q_rope, k_rope, v, keys, BACKWARD_HEADS)
+    b, t = q.shape[:2]
+    heads, dn, dr, dv = _latent_widths(q, q_rope, kv, k_rope)
+    hb, tq, tk = head_step(heads, BACKWARD_HEADS), q_chunk, key_tile(t)
     steps = heads // hb
-
-    def tile(kk, g, t0):
-        """(key tile, head step) of a grid step, the last live tile's
-        last step for one above the chunk's last query."""
-        last = (t0[0] + tq - 1) // tk
-        live = kk <= last
-        return jnp.where(live, kk, last), jnp.where(live, g, steps - 1)
-
-    rows = lambda w: pl.BlockSpec(
-        (tq, hb * w), lambda kk, g, t0: (0, tile(kk, g, t0)[1]))
-    keyed = lambda w: pl.BlockSpec(
-        (tk, hb * w), lambda kk, g, t0: tile(kk, g, t0))
-    rope = pl.BlockSpec((tk, dr), lambda kk, g, t0: (tile(kk, g, t0)[0], 0))
-    stats = pl.BlockSpec((hb, tq), lambda kk, g, t0: (tile(kk, g, t0)[1], 0))
-    whole = lambda a: pl.BlockSpec(a.shape, lambda kk, g, t0: (0, 0))
+    chunks, tiles = live_pairs(t, tq, tk, by_tile=True)
+    # a chunk's dq leaves after its last tile: each step names the last
+    # chunk done, so that a block is written back once it is whole
+    done = list(itertools.accumulate(
+        (c if _last_tile(c, tq, tk) == kk else 0
+         for c, kk in zip(chunks, tiles)), max))
+    chunk_rows = lambda w: pl.BlockSpec(
+        (None, tq, hb * w), lambda i, g, p, c, kk, d: (i, c[p], g))
+    done_rows = lambda w: pl.BlockSpec(
+        (None, tq, hb * w), lambda i, g, p, c, kk, d: (i, d[p], g))
+    keyed = pl.BlockSpec((None, tk, hb * (dn + dv)),
+                         lambda i, g, p, c, kk, d: (i, kk[p], g))
+    stats = pl.BlockSpec((None, None, hb, tq),
+                         lambda i, g, p, c, kk, d: (i, g, 0, c[p]))
     grid = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(keys // tk, steps),
-        in_specs=[rows(dn), rows(dr), rows(dv_dim), keyed(dn), rope,
-                  keyed(dv_dim), stats, stats, keyed(dn), rope,
-                  keyed(dv_dim)],
-        out_specs=[whole(q), whole(q_rope), keyed(dn), rope, keyed(dv_dim)],
-        scratch_shapes=[pltpu.VMEM((steps, tq, hb * dn), jnp.float32),
-                        pltpu.VMEM((steps, tq, hb * dr), jnp.float32)])
-    return pl.pallas_call(
+        num_scalar_prefetch=3, grid=(b, steps, len(chunks)),
+        in_specs=[chunk_rows(dn), chunk_rows(dr), chunk_rows(dv), keyed,
+                  pl.BlockSpec((None, tk, dr),
+                               lambda i, g, p, c, kk, d: (i, kk[p], 0)),
+                  stats, stats],
+        out_specs=[done_rows(dn), done_rows(dr), keyed,
+                   pl.BlockSpec((None, None, tk, dr),
+                                lambda i, g, p, c, kk, d: (i, g, kk[p], 0))],
+        scratch_shapes=[
+            pltpu.VMEM((t // tq, tq, hb * dn), jnp.float32),
+            pltpu.VMEM((t // tq, tq, hb * dr), jnp.float32),
+            pltpu.VMEM((tk, hb * (dn + dv)), jnp.float32),
+            pltpu.VMEM((tk, dr), jnp.float32)])
+    per_step = lambda a: a.reshape(b, steps, hb, t)
+    dq, dq_rope, dkv, dk_rope = pl.pallas_call(
         functools.partial(_latent_backward_kernel, heads=hb,
                           scale=_scale(scale, dn, dr)),
         grid_spec=grid,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct(q_rope.shape, q.dtype),
-                   jax.ShapeDtypeStruct(dk.shape, jnp.float32),
-                   jax.ShapeDtypeStruct(dk_rope.shape, jnp.float32),
-                   jax.ShapeDtypeStruct(dv.shape, jnp.float32)],
-        # operands count the prefetched scalar: the sums are 9, 10, 11
-        input_output_aliases={9: 2, 10: 3, 11: 4},
-        compiler_params=_params("arbitrary", "arbitrary"),
+                   jax.ShapeDtypeStruct(q_rope.shape, q_rope.dtype),
+                   jax.ShapeDtypeStruct(kv.shape, kv.dtype),
+                   # the one rotary key's: a sum a head step, added below
+                   jax.ShapeDtypeStruct((b, steps, t, dr), jnp.float32)],
+        compiler_params=_params("parallel", "parallel", "arbitrary"),
         name="mla_attention_backward", interpret=interpret,
-    )(jnp.asarray(t0, jnp.int32).reshape(1), q, q_rope, do, k, k_rope, v,
-      lse, di, dk, dk_rope, dv)
+    )(_table(chunks), _table(tiles), _table(done), q, q_rope,
+      do, kv, k_rope, per_step(lse), per_step(di))
+    return dq, dq_rope, dkv, jnp.sum(dk_rope, 1).astype(k_rope.dtype)

@@ -422,25 +422,26 @@ def test_the_latent_kernels_compile_for_v5e_at_the_cells_widths(
         one_chip, no_compile_cache, which, keys):
     """The module's latent kernels (their other tests:
     test_mla_attention.py; the compile is here because one process of a
-    test run describes the chip): 512 queries of 32 heads, 128 + 64 wide
-    for scores (the 64 rotary columns of every second head a slice off
-    the lane tiling) and 128 for values, bf16, against a key block's
-    first and last extent of the 8,192 rows the operands hold."""
+    test run describes the chip): 2 sequences of ``keys`` positions, 32
+    heads, 128 + 64 wide for scores (the 64 rotary columns of every
+    second head a slice off the lane tiling) and 128 for values, bf16,
+    chunks of 512, one call a direction; the backward's float32 ``dq``
+    sums of a sequence and head step stay in VMEM."""
     dsa = _dsa()
-    tq, rows, heads, dn, dr, dv = 512, 8192, 32, 128, 64, 128
+    b, heads, dn, dr, dv = 2, 32, 128, 64, 128
     shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-    q, q_rope = shape((tq, heads * dn), BF16), shape((tq, heads * dr), BF16)
-    k, k_rope = shape((rows, heads * dn), BF16), shape((rows, dr), BF16)
-    v, t0 = shape((rows, heads * dv), BF16), shape((), jnp.int32)
+    q, q_rope = shape((b, keys, heads * dn), BF16), \
+        shape((b, keys, heads * dr), BF16)
+    kv = shape((b, keys, heads * (dn + dv)), BF16)
+    k_rope = shape((b, keys, dr), BF16)
     if which == "forward":
-        fn = lambda *a: dsa.latent_forward(*a, keys=keys, interpret=False)
-        args = (q, q_rope, k, k_rope, v, shape((heads,), F32), t0)
+        fn = lambda *a: dsa.latent_forward(*a, q_chunk=512, interpret=False)
+        args = (q, q_rope, kv, k_rope, shape((b, keys // 2048, heads), F32))
     else:
-        fn = lambda *a: dsa.latent_backward(*a, keys=keys, interpret=False)
-        stats = shape((heads, tq), F32)
-        args = (q, q_rope, k, k_rope, v, t0, stats, stats,
-                shape((tq, heads * dv), BF16), shape(k.shape, F32),
-                shape(k_rope.shape, F32), shape(v.shape, F32))
+        fn = lambda *a: dsa.latent_backward(*a, q_chunk=512, interpret=False)
+        stats = shape((b, heads, keys), F32)
+        args = (q, q_rope, kv, k_rope, stats, stats,
+                shape((b, keys, heads * dv), BF16))
     compiled = jax.jit(fn).lower(*args).compile()
     assert f"mla_attention_{which}" in compiled.as_text()
 

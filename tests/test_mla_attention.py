@@ -7,6 +7,7 @@ before the module was shared. The kernels' compile for a described v5e
 sits in tests/test_dsa_attention.py with the sibling's: one process of a
 test run describes the chip."""
 
+import collections
 import sys
 from pathlib import Path
 
@@ -56,87 +57,151 @@ def _whole(q, q_rope, k, k_rope, v):
             jnp.concatenate([k, k_rope], -1), v)
 
 
-# ------------------------------------------------- one chunk, the kernels
+# ----------------------------------------------- a batch, the kernels alone
 
-# name: (queries, keys, first query's position, heads, dn, dr, dv)
-CHUNKS = {
-    "one_tile": (128, 128, 0, 4, 128, 64, 128),
-    "first_tiles_live_then_the_diagonal": (128, 384, 256, 4, 128, 64, 128),
-    "tiles_above_the_diagonal": (128, 640, 130, 4, 128, 64, 128),
-    "key_tiles_of_512": (256, 1024, 512, 2, 128, 64, 128),
-    "two_steps_of_eight_heads": (128, 256, 128, 16, 128, 64, 128),
-    "wider_heads_narrower_values": (128, 256, 128, 2, 256, 128, 128),
-}
-
-
-def _chunk(name, dtype):
-    tq, keys, t0, heads, dn, dr, dv = CHUNKS[name]
-    ks = jax.random.split(jax.random.key(len(name)), 6)
-    normal = lambda k, *s: jax.random.normal(k, s, F32).astype(dtype)
-    # keys and values hold rows past the chunk's keys: never read
-    rows = keys + 128
-    return (normal(ks[0], tq, heads, dn), normal(ks[1], tq, heads, dr),
-            normal(ks[2], rows, heads, dn), normal(ks[3], rows, dr),
-            normal(ks[4], rows, heads, dv), normal(ks[5], tq, heads * dv))
-
-
-@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2e-2)])
-@pytest.mark.parametrize("name", list(CHUNKS))
-def test_the_latent_kernels_match_the_xla_form_on_a_chunk(name, dtype, tol):
-    """Output, log-sum-exp and the gradients to q, q_rope, k, k_rope, v."""
-    dsa = _dsa()
-    dtype = jnp.dtype(dtype)
-    tq, keys, t0, heads, dn, dr, dv = CHUNKS[name]
-    q, q_rope, k, k_rope, v, do = _chunk(name, dtype)
-    mask = T._causal(t0, tq, keys)
-
-    def xla(q, q_rope, k, k_rope, v):
-        return L._attend(*_whole(q, q_rope, k[:keys], k_rope[:keys],
-                                 v[:keys]), mask, dtype)
-
-    want_o, pull = jax.vjp(xla, q, q_rope, k, k_rope, v)
-    want = pull(do)
-    qw, kw, _ = _whole(*(a.astype(F32) for a in (q, q_rope, k[:keys],
-                                                 k_rope[:keys], v[:keys])))
-    logits = jnp.einsum("thd,shd->hts", qw, kw, precision="highest")
-    want_lse = jax.nn.logsumexp(
-        jnp.where(mask, logits / np.sqrt(dn + dr), -jnp.inf), -1)
-
-    flat = lambda a: a.reshape(a.shape[0], -1)
-    kmax = jnp.max(dsa.latent_key_norms(flat(k), k_rope, heads)[:keys], 0)
-    o, lse = dsa.latent_forward(flat(q), flat(q_rope), flat(k), k_rope,
-                                flat(v), kmax, t0, keys=keys, interpret=True)
-    assert o.dtype == dtype and o.shape == (tq, heads * dv)
-    assert lse.dtype == F32 and lse.shape == (heads, tq)
-    assert _gap(o, want_o) < tol
-    assert _gap(lse, want_lse) < 2e-5 + tol / 10
-
-    di = jnp.sum((o.astype(F32) * do.astype(F32)).reshape(tq, heads, dv),
-                 -1).T
-    # sums over the chunks so far: this chunk's part is added in place,
-    # and rows past the chunk's keys are left alone
-    before = lambda a: jnp.full(flat(a).shape, 0.5, F32)
-    dq, dq_rope, dk, dk_rope, dv_ = dsa.latent_backward(
-        flat(q), flat(q_rope), flat(k), k_rope, flat(v), t0, lse, di, do,
-        before(k), before(k_rope), before(v), keys=keys, interpret=True)
-    assert dq.dtype == dtype and dq_rope.dtype == dtype
-    assert _gap(dq, flat(want[0])) < tol
-    assert _gap(dq_rope, flat(want[1])) < tol
-    for got, w in ((dk, want[2]), (dk_rope, want[3]), (dv_, want[4])):
-        assert got.dtype == F32
-        assert _gap(got[:keys] - 0.5, flat(w)[:keys]) < tol
-        assert np.all(np.asarray(got[keys:]) == 0.5)
-
-
-# ---------------------------------------- a batch, through the model's path
+SCALE = float((0.1 * np.log(64) + 1) ** 2 / np.sqrt(192))  # yarn's
 
 
 def _batch_of(t, heads, dtype, rows=2, dn=128, dr=64, dv=128):
-    ks = jax.random.split(jax.random.key(t), 5)
+    """The kernels' operands as the projections write them: ``q [B, T,
+    heads x dn]``, ``q_rope [B, T, heads x dr]``, ``kv [B, T, heads x (dn
+    + dv)]`` (each head's key, then its values), ``k_rope [B, T, dr]``."""
+    ks = jax.random.split(jax.random.key(t + heads), 4)
     normal = lambda k, *s: jax.random.normal(k, s, F32).astype(dtype)
-    return (normal(ks[0], rows, t, heads, dn), normal(ks[1], rows, t, heads, dr),
-            normal(ks[2], rows, t, heads, dn), normal(ks[3], rows, t, dr),
-            normal(ks[4], rows, t, heads, dv))
+    return (normal(ks[0], rows, t, heads * dn),
+            normal(ks[1], rows, t, heads * dr),
+            normal(ks[2], rows, t, heads * (dn + dv)),
+            normal(ks[3], rows, t, dr))
+
+
+def _xla_form(heads, key_block, q_chunk, dtype, scale=None):
+    """:func:`causal_attention` of every sequence, on the kernels'
+    operands: ``(q, q_rope, kv, k_rope) -> [B, T, heads x dv]``."""
+    def attention(q, q_rope, kv, k_rope):
+        split = lambda a: a.reshape(*a.shape[:2], heads, -1)
+        q, q_rope, kv = split(q), split(q_rope), split(kv)
+        k, v = kv[..., :q.shape[-1]], kv[..., q.shape[-1]:]
+        return jax.lax.map(lambda a: L.causal_attention(
+            *a, key_block=key_block, q_chunk=q_chunk, dtype=dtype,
+            scale=scale), _whole(q, q_rope, k, k_rope, v))
+    return attention
+
+
+def _exact_lse(q, q_rope, kv, k_rope, heads, scale):
+    """``[B, heads, T]``: each causal row's log-sum-exp in float32."""
+    split = lambda a: a.astype(F32).reshape(*a.shape[:2], heads, -1)
+    q, q_rope, kv = split(q), split(q_rope), split(kv)
+    dn = q.shape[-1]
+    qw, kw, _ = _whole(q, q_rope, kv[..., :dn], k_rope.astype(F32), kv)
+    logits = jnp.einsum("bthd,bshd->bhts", qw, kw, precision="highest")
+    t = q.shape[1]
+    return jax.nn.logsumexp(jnp.where(
+        T._causal(0, t, t), logits * scale, -jnp.inf), -1)
+
+
+# name: (length, heads, key_block, q_chunk, dn, dr, dv, scale), 2 sequences
+CASES = {
+    "one_tile": (128, 4, 128, 128, 128, 64, 128, None),
+    # key tiles of 128: chunk c sees c whole tiles, then the diagonal
+    "first_tiles_live_then_the_diagonal": (768, 4, 384, 128, 128, 64, 128,
+                                           None),
+    # a chunk over two tiles of 128, the diagonal through both
+    "tiles_above_the_diagonal": (768, 4, 256, 256, 128, 64, 128, None),
+    # two chunks a tile of 512
+    "key_tiles_of_512": (1024, 2, 512, 256, 128, 64, 128, None),
+    "two_steps_of_eight_heads": (512, 16, 256, 128, 128, 64, 128, None),
+    "wider_heads_narrower_values": (256, 2, 128, 128, 256, 128, 128, None),
+    # the kanana cell's head steps, 4 forward and 8 backward
+    "thirty_two_heads_and_the_callers_scale": (512, 32, 256, 128, 128, 64,
+                                               128, SCALE),
+    # chunks of 384 against tiles of 512: a tile's first chunk begins
+    # before it, a chunk's last tile ends after it
+    "chunks_that_straddle_tiles": (1536, 2, 768, 384, 128, 64, 128, None),
+}
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2e-2)])
+@pytest.mark.parametrize("name", list(CASES))
+def test_the_latent_kernels_match_the_xla_form_on_a_chunk(name, dtype, tol):
+    """Every chunk of two sequences in one call each way: the output,
+    log-sum-exp and the cotangents of ``q``, ``q_rope``, ``kv`` (in its
+    layout) and ``k_rope`` against :func:`causal_attention` and its
+    ``jax.vjp``."""
+    dsa = _dsa()
+    dtype = jnp.dtype(dtype)
+    t, heads, key_block, q_chunk, dn, dr, dv, scale = CASES[name]
+    args = _batch_of(t, heads, dtype, dn=dn, dr=dr, dv=dv)
+    do = jax.random.normal(jax.random.key(5), (2, t, heads * dv),
+                           F32).astype(dtype)
+    want_o, pull = jax.vjp(_xla_form(heads, key_block, q_chunk, dtype,
+                                     scale), *args)
+    want = pull(do)
+    want_lse = _exact_lse(*args, heads,
+                          1 / np.sqrt(dn + dr) if scale is None else scale)
+
+    block, chunk = T._blocks(t, key_block, q_chunk)
+    kmax = dsa.latent_key_norms(args[2], args[3], heads, dn, block)
+    assert kmax.shape == (2, t // block, heads)
+    o, lse = dsa.latent_forward(*args, kmax, q_chunk=chunk, scale=scale,
+                                interpret=True)
+    assert o.dtype == dtype and o.shape == (2, t, heads * dv)
+    assert lse.dtype == F32 and lse.shape == (2, heads, t)
+    assert _gap(o, want_o) < tol
+    assert _gap(lse, want_lse) < 2e-5 + tol / 10
+
+    di = jnp.sum((o.astype(F32) * do.astype(F32)).reshape(2, t, heads, dv),
+                 -1)
+    got = dsa.latent_backward(*args, lse, jnp.swapaxes(di, 1, 2), do,
+                              q_chunk=chunk, scale=scale, interpret=True)
+    for g, w, a in zip(got, want, args):
+        assert g.dtype == dtype and g.shape == a.shape
+        assert _gap(g, w) < tol
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_forward_is_its_chunks_bit_for_bit(dtype):
+    """Each chunk alone, over its sequence's keys up to its last key
+    tile, at the bound of its key block: the batched call's output and
+    log-sum-exp are those bits (two sequences, two key blocks of two
+    chunks, two chunks a tile of 512)."""
+    dsa = _dsa()
+    t, heads, block, chunk = 1024, 4, 512, 256
+    q, q_rope, kv, k_rope = _batch_of(t, heads, jnp.dtype(dtype))
+    kmax = dsa.latent_key_norms(kv, k_rope, heads, 128, block)
+    o, lse = dsa.latent_forward(q, q_rope, kv, k_rope, kmax, q_chunk=chunk,
+                                interpret=True)
+    tile = dsa.key_tile(t)
+    for i in range(2):
+        for c in range(t // chunk):
+            end = -(-(c + 1) * chunk // tile) * tile
+            part = lambda a: a[i:i + 1, :end]
+            bound = kmax[i:i + 1, c * chunk // block][:, None]
+            o_c, lse_c = dsa.latent_forward(
+                part(q), part(q_rope), part(kv), part(k_rope), bound,
+                q_chunk=chunk, interpret=True)
+            rows = slice(c * chunk, (c + 1) * chunk)
+            np.testing.assert_array_equal(np.asarray(o_c[0, rows], F32),
+                                          np.asarray(o[i, rows], F32))
+            np.testing.assert_array_equal(lse_c[0, :, rows], lse[i, :, rows])
+
+
+@pytest.mark.parametrize("t,chunk,tile,pairs", [
+    (8192, 512, 512, 136), (2048, 512, 512, 10), (1536, 384, 512, 9),
+    (768, 256, 128, 12)])
+def test_the_grid_visits_the_live_pairs_alone(t, chunk, tile, pairs):
+    """Each (chunk, tile) pair whose tile holds a key at or below the
+    chunk's last query, once, in both orders."""
+    dsa = _dsa()
+    by_chunk, by_tile = (list(zip(*dsa.live_pairs(
+        t, chunk, tile, by_tile=order))) for order in (False, True))
+    want = {(c, kk) for c in range(t // chunk) for kk in range(t // tile)
+            if kk * tile <= c * chunk + chunk - 1}
+    assert set(by_chunk) == set(by_tile) == want and len(want) == pairs
+    assert by_chunk == sorted(want)
+    assert by_tile == sorted(want, key=lambda pair: pair[::-1])
+
+
+# ---------------------------------------- a batch, through the model's path
 
 
 SEQUENCES = {
@@ -147,24 +212,25 @@ SEQUENCES = {
 }
 
 
+def _value_and_grads(fn, weights):
+    def loss(*a):
+        o = fn(*a)
+        return jnp.sum(o.astype(F32) * weights), o
+    return jax.jit(jax.value_and_grad(loss, range(4), has_aux=True))
+
+
 @pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 2e-2)])
 @pytest.mark.parametrize("name", list(SEQUENCES))
 def test_kernel_attention_matches_causal_attention(name, dtype, tol):
-    """Output and the gradients to all five inputs, whole sequences."""
+    """Output and the gradients to all four inputs, whole sequences."""
     t, heads, key_block, q_chunk = SEQUENCES[name]
     dtype = jnp.dtype(dtype)
     args = _batch_of(t, heads, dtype)
     weights = jax.random.normal(jax.random.key(9), (2, t, heads * 128), F32)
-
-    def scalar(fn):
-        def loss(*a):
-            o = fn(*a)
-            return jnp.sum(o.astype(F32) * weights), o
-        return jax.jit(jax.value_and_grad(loss, range(5), has_aux=True))
-
-    xla = scalar(lambda *a: jax.lax.map(lambda b: L.causal_attention(
-        *b, key_block=key_block, q_chunk=q_chunk, dtype=dtype), _whole(*a)))
-    kernel = scalar(lambda *a: L.kernel_attention(*a, key_block, q_chunk))
+    xla = _value_and_grads(_xla_form(heads, key_block, q_chunk, dtype),
+                           weights)
+    kernel = _value_and_grads(
+        lambda *a: L.kernel_attention(*a, key_block, q_chunk), weights)
     (_, want_o), want = xla(*args)
     (_, o), got = kernel(*args)
     assert o.dtype == dtype and _gap(o, want_o) < tol
@@ -178,32 +244,21 @@ def test_four_held_heads_and_the_callers_scale():
     four a grid step, as 8 do not divide them) and yarn's softmax scale
     (2.00475 / sqrt(192)), output and gradients against the XLA form
     with the same scale."""
-    import math
-
     t, heads, key_block, q_chunk = 256, 4, 128, 128
-    scale = (0.1 * math.log(64) + 1) ** 2 / math.sqrt(192)
     args = _batch_of(t, heads, F32)
     weights = jax.random.normal(jax.random.key(9), (2, t, heads * 128), F32)
-
-    def scalar(fn):
-        def loss(*a):
-            o = fn(*a)
-            return jnp.sum(o * weights), o
-        return jax.jit(jax.value_and_grad(loss, range(5), has_aux=True))
-
-    xla = scalar(lambda *a: jax.lax.map(lambda b: L.causal_attention(
-        *b, key_block=key_block, q_chunk=q_chunk, dtype=F32, scale=scale),
-        _whole(*a)))
-    kernel = scalar(lambda *a: L.kernel_attention(*a, key_block, q_chunk,
-                                                  scale))
+    xla = _value_and_grads(_xla_form(heads, key_block, q_chunk, F32, SCALE),
+                           weights)
+    kernel = _value_and_grads(
+        lambda *a: L.kernel_attention(*a, key_block, q_chunk, SCALE), weights)
     (_, want_o), want = xla(*args)
     (_, o), got = kernel(*args)
     assert _gap(o, want_o) < 2e-5
     for g, w in zip(got, want):
         assert _gap(g, w) < 2e-5
     # without the scale the result is another one
-    (_, plain_o), _ = scalar(lambda *a: L.kernel_attention(
-        *a, key_block, q_chunk))(*args)
+    (_, plain_o), _ = _value_and_grads(lambda *a: L.kernel_attention(
+        *a, key_block, q_chunk), weights)(*args)
     assert _gap(plain_o, want_o) > 1e-2
 
 
@@ -211,10 +266,7 @@ def test_four_held_heads_and_the_callers_scale():
     (32, (4, 8)), (16, (4, 8)), (4, (4, 4)), (6, (3, 6)), (2, (2, 2))])
 def test_heads_a_grid_step_divide_the_heads_held(heads, steps):
     dsa = _dsa()
-    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
-    q, q_rope = shape(128, heads * 128), shape(128, heads * 64)
-    k_rope, v = shape(512, 64), shape(512, heads * 128)
-    got = tuple(dsa._latent_sizes(q, q_rope, k_rope, v, None, most)[3]
+    got = tuple(dsa.head_step(heads, most)
                 for most in (dsa.FORWARD_HEADS, dsa.BACKWARD_HEADS))
     assert got == steps
 
@@ -311,17 +363,57 @@ def test_the_model_takes_the_kernels_and_agrees_with_the_xla_form(
 
 def test_a_recomputed_layer_keeps_the_log_sum_exp(as_on_one_tpu):
     """Forward and backward of the recomputed layers (the dense one and
-    the scanned body): the forward kernel once a key block (two here) a
-    layer, in the forward pass only; the backward kernel once a key
-    block. Without ``mla_lse`` among the names a layer keeps, the way
-    back would run the forward kernel again just to have it."""
+    the scanned body): one forward kernel call a layer, in the forward
+    pass only, and one backward kernel call a layer. Without ``mla_lse``
+    among the names a layer keeps, the way back would run the forward
+    kernel again just to have it."""
     model = get_model("kanana2_tiny", dtype=F32, **LANE_WIDE)
     params, batch = _params(model), _tokens()
     forward = _kernel_calls(jax.make_jaxpr(_loss(model, batch))(params))
-    assert forward == {"mla_attention_forward": 4}
+    assert forward == {"mla_attention_forward": 2}
     both = _kernel_calls(jax.make_jaxpr(
         jax.grad(_loss(model, batch), has_aux=True))(params))
-    assert both == {"mla_attention_forward": 4, "mla_attention_backward": 4}
+    assert both == {"mla_attention_forward": 2, "mla_attention_backward": 2}
+
+
+def _scope_operations(jaxpr, scope: str) -> collections.Counter:
+    """Operations of a traced function under the named scope ``scope``,
+    by primitive: loops' bodies and recomputed regions included, a
+    kernel's body not."""
+    counts = collections.Counter()
+
+    def walk(jp, inside):
+        for eqn in jp.eqns:
+            here = inside or scope in str(eqn.source_info.name_stack)
+            counts[eqn.primitive.name] += here
+            if eqn.primitive.name == "pallas_call":
+                continue
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (list, tuple)) \
+                        else (value,):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        walk(inner, here)
+
+    walk(jaxpr.jaxpr, False)
+    return +counts
+
+
+def test_nothing_loops_slices_or_concatenates_around_the_kernels(
+        as_on_one_tpu):
+    """The traced training step (two key blocks a sequence here): under
+    ``lm/mla/attn`` the two kernel calls of each layer and the small
+    operations of their operands, and no loop over sequences or chunks,
+    no slice or update of a sequence or a chunk and no concatenation of
+    the attention's outputs."""
+    model = get_model("kanana2_tiny", dtype=F32, **LANE_WIDE)
+    params, batch = _params(model), _tokens()
+    ops = _scope_operations(jax.make_jaxpr(
+        jax.grad(_loss(model, batch), has_aux=True))(params), "lm/mla/attn")
+    loops = ("scan", "while", "concatenate", "dynamic_slice",
+             "dynamic_update_slice")
+    assert not {op: ops[op] for op in loops if ops[op]}
+    assert ops["pallas_call"] == 4
 
 
 # --------------------------- the sibling's kernels, which share the module
@@ -366,26 +458,22 @@ def test_keyes_kernels_trace_to_the_operations_they_were():
 
 def kanana_kernels_jaxpr() -> str:
     """``latent_forward`` and ``latent_backward`` traced at the kanana
-    cell's shapes (512 queries of 32 heads, 128 + 64 wide for scores and
-    128 for values, over the first 2,048 of 8,192 keys, bf16, the
-    default scale): every operation of the two kernels, their grids,
-    blocks and each block's index map."""
+    cell's shapes (2 sequences of 8,192 positions, 32 heads, 128 + 64
+    wide for scores and 128 for values, chunks of 512 against key blocks
+    of 2,048, bf16, the default scale): every operation of the two
+    kernels, their grids, blocks and each block's index map."""
     dsa = _dsa()
-    tq, keys, heads, dn, dr, dv = 512, 2048, 32, 128, 64, 128
+    b, t, heads, dn, dr, dv = 2, 8192, 32, 128, 64, 128
     shape = jax.ShapeDtypeStruct
-    q, qr = shape((tq, heads * dn), BF16), shape((tq, heads * dr), BF16)
-    k, kr = shape((8192, heads * dn), BF16), shape((8192, dr), BF16)
-    v, kmax = shape((8192, heads * dv), BF16), shape((heads,), F32)
-    t0, rows = shape((), jnp.int32), shape((heads, tq), F32)
-    do = shape((tq, heads * dv), BF16)
-    sums = [shape((8192, heads * dn), F32), shape((8192, dr), F32),
-            shape((8192, heads * dv), F32)]
+    q, qr = shape((b, t, heads * dn), BF16), shape((b, t, heads * dr), BF16)
+    kv, kr = shape((b, t, heads * (dn + dv)), BF16), shape((b, t, dr), BF16)
+    kmax, rows = shape((b, t // 2048, heads), F32), shape((b, heads, t), F32)
+    do = shape((b, t, heads * dv), BF16)
     traced = [
         jax.make_jaxpr(lambda *a: dsa.latent_forward(
-            *a, keys=keys, interpret=False))(q, qr, k, kr, v, kmax, t0),
+            *a, q_chunk=512, interpret=False))(q, qr, kv, kr, kmax),
         jax.make_jaxpr(lambda *a: dsa.latent_backward(
-            *a, keys=keys, interpret=False))(
-            q, qr, k, kr, v, t0, rows, rows, do, *sums)]
+            *a, q_chunk=512, interpret=False))(q, qr, kv, kr, rows, rows, do)]
     lines = []
     for jaxpr in traced:
         lines.append(str(jaxpr))
@@ -398,10 +486,9 @@ def kanana_kernels_jaxpr() -> str:
 
 
 def test_kananas_kernels_trace_to_the_operations_they_were():
-    """The fixture was written by this function on the parent of the PR
-    that let the latent kernels take a caller's scale and any number of
-    heads (PR 38): kanana's cell runs the same kernels, its default scale
-    the same constant. To change them on purpose, write the fixture again
+    """The fixture was written by this function when the latent kernels
+    took a whole batch a call, the chunks in their grid: the cell runs
+    these operations. To change them on purpose, write the fixture again
     (``python tests/test_mla_attention.py``) and measure the cell."""
     assert kanana_kernels_jaxpr() == KANANA_FIXTURE.read_text()
 
