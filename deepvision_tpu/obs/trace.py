@@ -65,7 +65,14 @@ Design points:
   processes onto one timeline;
 - **process labels**: ``set_labels(role=..., host=..., generation=...)``
   stamps exports and spool headers so a merged fleet/cluster trace
-  names its pid rows (``replica r1``, ``host 0 gen 2``).
+  names its pid rows (``replica r1``, ``host 0 gen 2``);
+- **start-up spans**: :func:`startup_span` (``startup/runtime``,
+  ``startup/load_model``, ``startup/engine``, ``startup/state``,
+  ``startup/compile``) always measures and keeps its interval
+  (:meth:`Tracer.startup_spans`, a handful a process, capped), enabled
+  or not, and is a profiler annotation like any span while a profile
+  runs; ``deepvision_tpu.startup`` reads them into the ``[startup]``
+  ready line and the benchmark's ``setup_runtime_s``.
 
 :func:`summarize_chrome` turns an exported trace back into per-span
 totals + a wall-time-attribution figure; ``tools/trace_summary.py`` is
@@ -74,6 +81,7 @@ its CLI.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import sys
@@ -83,7 +91,9 @@ from collections import deque
 from pathlib import Path
 
 __all__ = ["Span", "Tracer", "format_labels", "get_tracer", "span",
-           "summarize_chrome"]
+           "startup_phase", "startup_span", "summarize_chrome"]
+
+STARTUP_CAPACITY = 256  # start-up spans kept per process
 
 
 class _NoopSpan:
@@ -179,6 +189,18 @@ class Span:
         return False
 
 
+class _StartupSpan(Span):
+    """A span of the program's own start-up: measured whether or not
+    the tracer is on, and its interval kept by the tracer."""
+
+    __slots__ = ()
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        self._tracer._keep_startup(self.name, self.t0, self.dur)
+        return False
+
+
 class Tracer:
     """Ring buffer of completed spans + Chrome-trace export."""
 
@@ -194,6 +216,10 @@ class Tracer:
         self._dropped = 0          # ring evictions since clear()
         self._drop_counter = None  # lazily bound obs counter
         self._labels: dict = {}
+        # (name, perf_counter start, seconds) of each start-up span;
+        # clear() leaves them: start-up happens once
+        self._startup: list[tuple[str, float, float]] = []
+        self.startup_dropped = 0
 
     # -- lifecycle -------------------------------------------------------
     @property
@@ -206,6 +232,12 @@ class Tracer:
         (a spool/flight-recorder sink keeps spans flowing without the
         in-memory export machinery)."""
         return self._enabled or bool(self._sinks)
+
+    @property
+    def wall_offset(self) -> float:
+        """Add to a ``time.time()`` stamp to put it on the spans'
+        ``perf_counter`` clock (the trace zero's two readings)."""
+        return self._epoch - self.epoch_wall
 
     @property
     def dropped_spans(self) -> int:
@@ -277,6 +309,27 @@ class Tracer:
         and the profile."""
         return Span(self, name, cat, args, None, observe=observe,
                     profiled=_profile_running())
+
+    def startup(self, name: str) -> Span:
+        """``startup/<name>``: a span of the program's own start-up. It
+        always measures and is kept (:meth:`startup_spans`) whether or
+        not the tracer is enabled; it is on the ring when the tracer is,
+        and a profiler annotation while a profile runs."""
+        return _StartupSpan(self, name, "startup", None, None,
+                            profiled=_profile_running())
+
+    def _keep_startup(self, name: str, t0: float, dur: float) -> None:
+        with self._lock:
+            if len(self._startup) < STARTUP_CAPACITY:
+                self._startup.append((f"startup/{name}", t0, dur))
+            else:
+                self.startup_dropped += 1
+
+    def startup_spans(self) -> list[tuple[str, float, float]]:
+        """Every start-up span closed so far, in closing order:
+        ``(name, perf_counter start, seconds)``."""
+        with self._lock:
+            return list(self._startup)
 
     def _push(self) -> None:
         self._local.depth = getattr(self._local, "depth", 0) + 1
@@ -426,6 +479,24 @@ def span(name: str, cat: str = "app", args: dict | None = None,
     """``with span("step"): ...`` against the default tracer."""
     return _TRACER.span(name, cat=cat, args=args, device_sync=device_sync,
                         encloses=encloses)
+
+
+def startup_span(name: str) -> Span:
+    """``with startup_span("runtime"): ...`` against the default tracer
+    (:meth:`Tracer.startup`)."""
+    return _TRACER.startup(name)
+
+
+def startup_phase(name: str):
+    """Decorator: each call of the function is one ``startup/<name>``
+    span."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def timed(*args, **kwargs):
+            with _TRACER.startup(name):
+                return fn(*args, **kwargs)
+        return timed
+    return wrap
 
 
 # ------------------------------------------------------- trace analysis

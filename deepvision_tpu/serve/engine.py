@@ -55,7 +55,7 @@ from typing import Iterable
 import numpy as np
 
 from deepvision_tpu.obs.distributed import flight_dump
-from deepvision_tpu.obs.trace import get_tracer
+from deepvision_tpu.obs.trace import get_tracer, startup_phase
 from deepvision_tpu.serve.admission import AdmissionController, ShedError
 from deepvision_tpu.serve.compile_cache import CompileCache
 from deepvision_tpu.serve.models import ServedModel
@@ -121,6 +121,7 @@ class InferenceEngine:
     replaces one tenant's weights under live load with zero drops.
     """
 
+    @startup_phase("engine")
     def __init__(
         self,
         models: Iterable[ServedModel] | dict[str, ServedModel],

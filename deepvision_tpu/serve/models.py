@@ -31,6 +31,8 @@ from typing import Any, Callable
 
 import numpy as np
 
+from deepvision_tpu.obs.trace import startup_phase
+
 __all__ = [
     "ServedModel", "load_served", "from_stablehlo", "restore_state",
     "model_geometry", "task_for",
@@ -361,6 +363,7 @@ def _gan_post(host: dict, i: int) -> dict:
 # --------------------------------------------------------------- loaders
 
 
+@startup_phase("load_model")
 def load_served(
     name: str,
     workdir: str | None = None,

@@ -39,7 +39,7 @@ from deepvision_tpu.core.step import (
 from deepvision_tpu.data.prefetch import DevicePrefetcher, FeedTelemetry
 from deepvision_tpu.obs.metrics import record_token_step
 from deepvision_tpu.obs.profiler import ProfileWindow, sample_memory_gauges
-from deepvision_tpu.obs.trace import span
+from deepvision_tpu.obs.trace import span, startup_phase, startup_span
 from deepvision_tpu.resilience.recovery import (
     NumericDivergence,
     RecoveryCounters,
@@ -196,8 +196,9 @@ class Trainer:
         from deepvision_tpu.core.precision import get_policy
 
         self.policy = get_policy(config.get("precision", "bf16"))
-        self.state = create_train_state(model, self.tx, sample, rng=seed,
-                                        policy=self.policy)
+        with startup_span("state"):
+            self.state = create_train_state(model, self.tx, sample,
+                                            rng=seed, policy=self.policy)
         state_spec = None
         if shard_weight_update:
             # ZeRO-1 (arXiv:2004.13336): optimizer state + the weight
@@ -265,6 +266,10 @@ class Trainer:
             self._train_step = compile_train_step(
                 train_step, mesh, state_spec=state_spec
             )
+        # jit compiles on the first call: that call is the
+        # startup/compile span, after which the process is ready
+        self._compiled_step = self._train_step
+        self._train_step = self._first_step
         # eval must see the SAME state sharding: pinning a sharded
         # opt_state to replicated would all-gather it every val batch
         self._eval_step = compile_eval_step(
@@ -342,6 +347,19 @@ class Trainer:
         self.sdc_detected = False
         # per-epoch KeySeq derived in train_epoch from this root key
         self._base_key = jax.random.key(seed + 1)
+
+    def _first_step(self, *args):
+        """The first train step: it traces, lowers and compiles (or
+        fetches) the program, as the ``startup/compile`` span; then the
+        process is ready (``startup.mark_ready``) and later steps call
+        the program directly."""
+        from deepvision_tpu.startup import mark_ready
+
+        self._train_step = self._compiled_step
+        with startup_span("compile"):
+            out = self._compiled_step(*args)
+        mark_ready()
+        return out
 
     # -- multi-host cluster (resilience/cluster.py) ----------------------
     def attach_cluster(self, member) -> None:
@@ -563,6 +581,7 @@ class Trainer:
             shutil.rmtree(self._preempt_dir, ignore_errors=True)
 
     # -- resume ----------------------------------------------------------
+    @startup_phase("state")
     def resume(self, epoch: int | None = None) -> None:
         """Restore latest (or given) checkpoint incl. host-side scheduler +
         metric history — the reference restores model/opt/scheduler/loggers

@@ -905,7 +905,8 @@ def main(argv=None):
     p.add_argument("--profile-dir", default=None,
                    help="capture a jax.profiler trace of the whole "
                         "serving session into this directory (started "
-                        "after warmup, stopped at shutdown)")
+                        "before the model loads, so start-up and warmup "
+                        "are in it; stopped at shutdown)")
     p.add_argument("--trace-spool", default=None, metavar="DIR",
                    help="distributed tracing: append every completed "
                         "span to a crash-safe per-process spool file "
@@ -939,44 +940,46 @@ def main(argv=None):
         return
 
     from deepvision_tpu.obs.profiler import profile_session
-    from deepvision_tpu.startup import init_runtime
+    from deepvision_tpu.startup import init_runtime, mark_ready
 
     init_runtime()
     spool = _setup_obs(args, args.obs_role or "replica")
-    engine = build_engine(args)
-    try:
-        # the profiler bracket starts AFTER build_engine so warmup
-        # compiles don't drown the serving steady state in the trace
-        with profile_session(args.profile_dir):
+    # the profile starts before the model loads, so the start-up spans
+    # (startup/load_model, startup/engine) land beside the warm-up's
+    # device operations
+    with profile_session(args.profile_dir):
+        engine = build_engine(args)
+        mark_ready()
+        try:
             if args.http is not None:
                 run_http(engine, args)
             else:
                 run_stdin(engine, args)
-    finally:
-        engine.close()
-        if spool is not None:
-            spool.close()
-        stats = engine.stats()
-        # grep-stable exit line (chip_smoke.py reads it): executed rows
-        # (rows + padded_rows) per batch say which buckets ran
-        tel = stats["telemetry"]
-        print(f"[serve] completed={tel['completed']} "
-              f"failed={tel['failed']} batches={tel['batches']} "
-              f"rows={tel['rows']} padded_rows={tel['padded_rows']}",
-              file=sys.stderr, flush=True)
-        if stats.get("pipelines"):
-            # grep-stable exit line: the pipeline smoke gate asserts
-            # served counts and that the frozen cache saw zero
-            # post-warm misses (no request paid a hidden compile)
-            served = ",".join(f"{k}={v}" for k, v in
-                              sorted(stats["pipelines"].items()))
-            cache = stats["cache"]
-            print(f"[pipeline] served {served} "
-                  f"frozen={cache['frozen']} misses={cache['misses']} "
-                  f"hits={cache['hits']}", file=sys.stderr, flush=True)
-        # grep-stable tenancy exit line: the swap smoke gate asserts
-        # swaps=N on it (and zero dropped data responses upstream)
-        print(engine.tenancy.summary_line(), file=sys.stderr, flush=True)
+        finally:
+            engine.close()
+            if spool is not None:
+                spool.close()
+            stats = engine.stats()
+            # grep-stable exit line (chip_smoke.py reads it): executed rows
+            # (rows + padded_rows) per batch say which buckets ran
+            tel = stats["telemetry"]
+            print(f"[serve] completed={tel['completed']} "
+                  f"failed={tel['failed']} batches={tel['batches']} "
+                  f"rows={tel['rows']} padded_rows={tel['padded_rows']}",
+                  file=sys.stderr, flush=True)
+            if stats.get("pipelines"):
+                # grep-stable exit line: the pipeline smoke gate asserts
+                # served counts and that the frozen cache saw zero
+                # post-warm misses (no request paid a hidden compile)
+                served = ",".join(f"{k}={v}" for k, v in
+                                  sorted(stats["pipelines"].items()))
+                cache = stats["cache"]
+                print(f"[pipeline] served {served} "
+                      f"frozen={cache['frozen']} misses={cache['misses']} "
+                      f"hits={cache['hits']}", file=sys.stderr, flush=True)
+            # grep-stable tenancy exit line: the swap smoke gate asserts
+            # swaps=N on it (and zero dropped data responses upstream)
+            print(engine.tenancy.summary_line(), file=sys.stderr, flush=True)
 
 
 if __name__ == "__main__":
