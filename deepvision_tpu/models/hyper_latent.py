@@ -9,9 +9,10 @@ compressed query and a head share, ``_BiasedMoe``, ``_GatedMlp``,
 ``yarn_angles``, ``_stacked``, ``token_nll``). What is its own:
 
 - **The residual is ``n`` streams** ``X [B, T, n, C]`` in the compute
-  dtype. The input is the embedding copied into every stream; the
-  readout is their sum. Around each sublayer ``F`` (attention; MLP or
-  expert layer) the hyper-connection (DeepSeek's mHC, on the
+  dtype, carried side by side as ``[B, T, n C]``. The input is the
+  embedding copied into every stream; the readout is their sum. Around
+  each sublayer ``F`` (attention; MLP or expert layer) the
+  hyper-connection (DeepSeek's mHC, on the
   Hyper-Connections of Zhu et al.) computes, per token, from ``x~ =
   RMSNorm(vec X_t)`` (width ``n C``, no scale of its own):
   ``H_pre = sigmoid(a_pre (x~ phi_pre) + b_pre)``, ``H_post = 2
@@ -22,7 +23,10 @@ compressed query and a head share, ``_BiasedMoe``, ``_GatedMlp``,
   H_res X + H_post^T F(H_pre X)``. The maps ``phi`` are one ``[n C, n (n
   + 2)]`` product in float32 at HIGHEST (as the router's), and the
   sigmoids, the clamp, the exponent and the iterations are float32; the
-  mixes read and write the streams in the compute dtype.
+  mixes read and write the streams in the compute dtype. On one TPU
+  chip the maps, the mixes and their backward run as the Pallas kernels
+  of ``ops/mhc.py`` (:func:`mhc_engages`), whose stream cotangents stay
+  in the compute dtype; elsewhere in XLA, the kernels' yardstick.
 - **Multi-token prediction** (DeepSeek-V3 section 2.2, depth 1): ``h'_t
   = M [RMSNorm(readout_t); RMSNorm(Emb(tok_{t+1}))]``, one expert block
   with hyper-connections of its own, a norm and the shared head, which
@@ -39,6 +43,7 @@ and loss) and ``lm/head``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
@@ -49,6 +54,7 @@ import numpy as np
 from jax import lax
 
 from deepvision_tpu.core.precision import compute_dot, float32_dot
+from deepvision_tpu.models import transformer
 from deepvision_tpu.models.latent_moe import (
     LatentConfig,
     _BiasedMoe,
@@ -101,16 +107,19 @@ def sinkhorn_error(m):
 class HyperMaps(nn.Module):
     """``X [B, T, n, C]`` -> (``H_pre [B, T, n]``, ``H_post [B, T, n]``,
     ``H_res [B, T, n, n]``, float32; the Sinkhorn error's largest value
-    of each sample ``[B]``)."""
+    of each sample ``[B]``); ``weights_only`` -> (``phi``, ``alpha``,
+    ``b``), for the kernels of ``ops/mhc.py``."""
 
     hc: HyperConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, weights_only: bool = False):
         b, t, n, d = x.shape
         phi = self.param("phi", normal, (n * d, n * (n + 2)))
         alpha = self.param("alpha", nn.initializers.constant(0.01), (3,))
         bias = self.param("b", nn.initializers.zeros, (n * (n + 2),))
+        if weights_only:
+            return phi, alpha, bias
         hc = self.hc
         with jax.named_scope("lm/mhc"):
             flat = x.reshape(b, t, n * d)
@@ -128,30 +137,94 @@ class HyperMaps(nn.Module):
         return pre, post, res, err
 
 
+def mhc_engages(tokens: int, streams: int, width: int,
+                dtype=jnp.bfloat16) -> bool:
+    """The kernels of ``ops/mhc.py`` take 4 streams in bf16 whose width
+    fills lane rows, on one TPU chip, whatever the token count (a count
+    the tile does not divide is padded); everything else is the XLA form
+    of :class:`HyperMaps` and :func:`hyper_sublayer`."""
+    return (tokens > 0 and streams == 4 and width % 128 == 0
+            and jnp.dtype(dtype) == jnp.bfloat16
+            and transformer._on_one_tpu())
+
+
 def hyper_sublayer(maps, x, sublayer, dtype):
     """``X' = H_res X + H_post^T F(H_pre X)``; ``sublayer`` returns
-    (``F``, what else it has to say). -> (``X'``, the Sinkhorn error,
-    that)."""
-    pre, post, res, err = maps(x)
+    (``F``, what else it has to say). ``x`` is ``[B, T, n, C]`` or, as
+    the model carries it, the streams side by side ``[B, T, n C]``. ->
+    (``X'`` in ``x``'s layout, the Sinkhorn error, that). Through the
+    kernels where :func:`mhc_engages`, else in XLA; a registry counter
+    says which."""
+    from deepvision_tpu.obs.metrics import record_mhc_site
+
+    n = maps.hc.streams
+    b, t = x.shape[:2]
+    d = x.shape[-1] // n if x.ndim == 3 else x.shape[-1]
+    x4 = x.reshape(b, t, n, d)
+    by_kernel = mhc_engages(b * t, n, d, x.dtype)
+    if by_kernel:
+        phi, alpha, bias = maps(x4, weights_only=True)
+    else:
+        pre, post, res, err = maps(x4)
+    if not maps.is_initializing():      # a shape trace runs nowhere
+        record_mhc_site(by_kernel)
+    if by_kernel:
+        out, err, aux = _kernel_sublayer(x.reshape(b * t, n * d), b, phi,
+                                         alpha, bias, maps.hc, sublayer)
+        return out.reshape(x.shape), err, aux
     with jax.named_scope("lm/mhc"):
-        xf = x.astype(F32)
+        xf = x4.astype(F32)
         u = jnp.sum(pre[..., None] * xf, 2).astype(dtype)
     y, aux = sublayer(u)
     with jax.named_scope("lm/mhc"):
         mixed = jnp.sum(res[..., None] * xf[:, :, None], 3)
         out = (mixed + post[..., None] * y.astype(F32)[:, :, None])
-    return out.astype(dtype), err, aux
+    return out.astype(dtype).reshape(x.shape), err, aux
 
 
-def streams_of(h, n: int):
-    """``[B, T, C]`` copied into ``n`` streams."""
-    return jnp.broadcast_to(h[:, :, None], (*h.shape[:2], n, h.shape[-1]))
+def _kernel_sublayer(x, b: int, phi, alpha, bias, hc, sublayer):
+    """:func:`hyper_sublayer` through ``ops/mhc.py`` on the streams side
+    by side, ``x [B T, n C]`` in bf16 both ways; ``sublayer`` takes ``[B,
+    T, C]``."""
+    from deepvision_tpu.ops import mhc
 
-
-def readout(x, dtype):
-    """The sum of the streams."""
+    tokens = x.shape[0]
     with jax.named_scope("lm/mhc"):
-        return jnp.sum(x.astype(F32), 2).astype(dtype)
+        u, h, xs = mhc.premix(x, phi, alpha, bias, hc)
+    y, aux = sublayer(u.reshape(b, -1, u.shape[-1]))
+    with jax.named_scope("lm/mhc"):
+        out = mhc.postmix(xs, y.reshape(tokens, -1), h)
+        err = jnp.max(mhc.sinkhorn_error(h).reshape(b, -1), -1)
+    return out, err, aux
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def streams_of(h, n: int):
+    """``[B, T, C]`` copied into ``n`` streams side by side: ``[B, T, n
+    C]``; the way back sums the streams' cotangents in float32."""
+    return jnp.concatenate([h] * n, -1)
+
+
+def _streams_of_fwd(h, n):
+    return streams_of(h, n), None
+
+
+def _streams_of_bwd(n, _, dx):
+    return (readout(dx, n, dx.dtype),)
+
+
+streams_of.defvjp(_streams_of_fwd, _streams_of_bwd)
+
+
+def readout(x, n: int, dtype):
+    """The sum of the ``n`` streams of ``[B, T, n C]``, in float32 one
+    stream at a time."""
+    d = x.shape[-1] // n
+    with jax.named_scope("lm/mhc"):
+        total = x[..., :d].astype(F32)
+        for s in range(1, n):
+            total = total + x[..., s * d:(s + 1) * d].astype(F32)
+        return total.astype(dtype)
 
 
 # ----------------------------------------------------------------- blocks
@@ -197,7 +270,8 @@ class HyperExpertBlock(nn.Module):
     @nn.compact
     def __call__(self, x, angles):
         c = self.cfg
-        b, t, _, d = x.shape
+        b, t = x.shape[:2]
+        d = x.shape[-1] // self.hc.streams
         x, e1, _ = hyper_sublayer(
             HyperMaps(self.hc, name="attn_hc"), x,
             lambda u: (_attention(c, u, angles), None), c.dtype)
@@ -244,7 +318,7 @@ class MtpModule(nn.Module):
         x, stats = self.block(c, self.hc, name="block")(
             streams_of(h, self.hc.streams), angles)
         return RMSNorm(c.rms_eps, name="final_norm")(
-            readout(x, c.dtype)), stats
+            readout(x, self.hc.streams, c.dtype)), stats
 
 
 # ------------------------------------------------------------------ model
@@ -343,7 +417,7 @@ class HyperLatentLM(nn.Module):
         # every expert block's statistic, stacked [layers, B, ...]
         x, stats = _stacked(layer, self.num_layers - self.dense_layers)(
             cfg, hc, name="layers")(x, angles)
-        main = readout(x, dt)
+        main = readout(x, n, dt)
         hidden = RMSNorm(self.rms_eps, name="final_norm")(main)
         head = self.param("lm_head", normal,
                           (self.hidden_size, self.vocab_size))

@@ -451,13 +451,15 @@ def record_token_step(metrics: dict, registry: Registry | None = None
 
 
 # Which lowering each call site of a traced token model took
-# (models/transformer.batched_sparse_attention and chunk_scores, and
-# models/latent_moe._LatentAttention, decide while the step is traced,
+# (models/transformer.batched_sparse_attention and chunk_scores,
+# models/latent_moe._LatentAttention and models/hyper_latent's
+# hyper-connections decide while the step is traced,
 # so these count sites of traced programs, not steps): (through the
 # Pallas kernels, through the XLA form).
 ATTENTION_SITE_COUNTERS = ("dsa_kernel_sites", "dsa_xla_sites")
 INDEXER_SITE_COUNTERS = ("indexer_kernel_sites", "indexer_xla_sites")
 LATENT_SITE_COUNTERS = ("mla_kernel_sites", "mla_xla_sites")
+MHC_SITE_COUNTERS = ("mhc_kernel_sites", "mhc_xla_sites")
 
 
 def _record_site(counters: tuple, by_kernel: bool,
@@ -486,3 +488,11 @@ def record_latent_site(by_kernel: bool, registry: Registry | None = None
     shape trace of ``init`` left out (``mla_kernel_sites`` or
     ``mla_xla_sites``)."""
     _record_site(LATENT_SITE_COUNTERS, by_kernel, registry)
+
+
+def record_mhc_site(by_kernel: bool, registry: Registry | None = None
+                    ) -> None:
+    """One hyper-connected sublayer of a program that will run, the shape
+    trace of ``init`` left out (``mhc_kernel_sites`` or
+    ``mhc_xla_sites``)."""
+    _record_site(MHC_SITE_COUNTERS, by_kernel, registry)

@@ -472,3 +472,33 @@ def test_the_indexer_kernels_compile_for_v5e_at_the_cells_widths(
                 shape((8192, dim), F32))
     compiled = jax.jit(fn).lower(*args).compile()
     assert f"dsa_indexer_{which}" in compiled.as_text()
+
+
+@pytest.mark.parametrize("which", ["forward", "backward"])
+def test_the_mhc_kernels_compile_for_v5e_at_the_cells_widths(
+        one_chip, no_compile_cache, which):
+    """``ops/mhc.py`` (its other tests: test_mhc_kernels.py; the compile
+    is here because one process of a test run describes the chip): the
+    xing cell's 2 x 2,048 tokens of 4 bf16 streams of 3,584, the maps'
+    product, the pre-mix and the post-mix, one way or both: the tiles of
+    256 tokens and the resident ``dphi`` block in VMEM."""
+    from deepvision_tpu.models import hyper_latent as H
+    from deepvision_tpu.ops import mhc
+
+    hc = H.HyperConfig(4, 20, 1e-6, 30.0, 1e-6)
+    tokens, c = 4096, 3584
+    shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    x, y = shape((tokens, 4 * c), BF16), shape((tokens, c), BF16)
+    phi, alpha, b = shape((4 * c, 24), F32), shape((3,), F32), \
+        shape((24,), F32)
+
+    def forward(x, phi, alpha, b, y):
+        u, h, xs = mhc.premix(x, phi, alpha, b, hc, interpret=False)
+        return mhc.postmix(xs, y + u, h, interpret=False)
+
+    fn = forward if which == "forward" else jax.grad(
+        lambda *a: jnp.sum(forward(*a).astype(F32)), argnums=(0, 1, 2, 3))
+    text = jax.jit(fn).lower(x, phi, alpha, b, y).compile().as_text()
+    names = (("mhc_pre_forward", "mhc_post_forward") if which == "forward"
+             else ("mhc_post_backward", "mhc_pre_backward"))
+    assert all(n in text for n in names)
