@@ -11,6 +11,7 @@ from deepvision_tpu.models import (  # noqa: F401
     latent_moe,
     lenet,
     mobilenet,
+    olmo_hybrid,
     resnet,
     shufflenet,
     transformer,

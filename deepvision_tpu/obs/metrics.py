@@ -47,6 +47,8 @@ import numpy as np
 
 __all__ = [
     "Counter",
+    "GEN_COUNTERS",
+    "GEN_TTFT",
     "Gauge",
     "Histogram",
     "Registry",
@@ -448,6 +450,15 @@ def record_token_step(metrics: dict, registry: Registry | None = None
     for name in TOKEN_STEP_GAUGES:
         if name in metrics:
             reg.gauge(name).set(metrics[name])
+
+
+# The token generator's (serve/generator.py) counters: prompt tokens
+# prefilled, tokens handed to requests, decode steps, and the requests
+# those steps served summed over steps; and its histogram of seconds from
+# submit to a request's first token.
+GEN_COUNTERS = ("gen_prefill_tokens", "gen_decode_tokens",
+                "gen_decode_steps", "gen_decode_rows")
+GEN_TTFT = "gen_ttft"
 
 
 # Which lowering each call site of a traced token model took

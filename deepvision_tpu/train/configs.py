@@ -433,6 +433,20 @@ TRAINING_CONFIG: dict[str, dict] = {
         "optimizer_params": {"lr": 1e-3},
         "total_epochs": 2,
     },
+    # Olmo-Hybrid-7B (models/olmo_hybrid.py) is served, not trained, at
+    # its published widths (benchmark cell olmo_hybrid_7b.serve_longprompt);
+    # its CPU preset trains on the seeded token set through the chunked
+    # delta rule with a plain next-token step (no experts, no bias rule)
+    "olmo_hybrid_tiny": {
+        "precision": "bf16",
+        "batch_size": 8,
+        "text_len": 65,
+        "dataset": "lm",
+        "steps": "dense_lm",
+        "optimizer": "adam",
+        "optimizer_params": {"lr": 1e-3},
+        "total_epochs": 2,
+    },
 }
 
 

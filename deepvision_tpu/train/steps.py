@@ -433,6 +433,25 @@ def lm_train_step(state: TrainState, batch: dict, key: jax.Array,
     return new_state, metrics
 
 
+def dense_lm_train_step(state: TrainState, batch: dict, key: jax.Array):
+    """One step of a text token model without experts
+    (``models/olmo_hybrid``) on {'tokens'}: mean next-token
+    cross-entropy over the vocabulary, no auxiliary loss; ``metrics``
+    carries the loss (and the ``mp_*`` numbers where loss scaling is on)."""
+    del key                                     # no dropout in this family
+    inputs = {"tokens": batch["tokens"]}
+
+    def loss_fn(params):
+        out = state.apply_fn({"params": params}, inputs, train=True)
+        loss = jnp.mean(out["nll"])
+        return state.scale_loss(loss), loss
+
+    (_, loss), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+        state.params)
+    new_state = state.apply_gradients(grads)
+    return new_state, {"loss": loss, **precision_metrics(new_state)}
+
+
 def lm_eval_step(state: TrainState, batch: dict) -> dict:
     """Count-weighted sums over one batch of {'tokens'}."""
     mask = batch.get("mask")

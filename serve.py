@@ -18,6 +18,11 @@ surfaces (``deepvision_tpu/serve/``):
     # serve a StableHLO artifact from predict.py export
     serve.py --artifact lenet5=lenet5.stablehlo
 
+    # token generation (a hybrid token model, greedy): stdin-JSONL
+    serve.py -m olmo_hybrid_tiny --slots 4 --max-len 3072
+    {"id": 1, "tokens": [5, 17, 3], "max_new_tokens": 8}
+    -> {"id": 1, "result": {"tokens": [...]}, "ms": 41.0}
+
     # serving FLEET: router front tier over N child-process replicas
     # (health-gated balancing, failover, circuit breaker, autoscaling)
     serve.py --fleet 2 -m lenet5 --http 8080
@@ -512,6 +517,86 @@ def run_stdin(engine, args, stdin=None, stdout=None):
         t.join(timeout=args.timeout_s)
 
 
+def run_generate(gen, args, stdin=None, stdout=None):
+    """Token generation over stdin-JSONL: one ``{"id", "tokens": [...],
+    "max_new_tokens": n}`` a line, responses ``{"id", "result":
+    {"tokens": [...]}, "ms"}`` in submission order. Requests keep flowing
+    while earlier ones generate, so the scheduler holds several at once."""
+    import time
+
+    stdin = stdin if stdin is not None else sys.stdin
+    stdout = stdout if stdout is not None else sys.stdout
+    pending: list = []      # (id, future, t0)
+
+    def emit(rid, fut, t0):
+        try:
+            result = fut.result(timeout=args.timeout_s + 1.0)
+            line = {"id": rid, "result": _jsonable(result),
+                    "ms": round((time.perf_counter() - t0) * 1e3, 2)}
+        except Exception as e:
+            line = {"id": rid, "error": f"{type(e).__name__}: {e}"}
+        print(json.dumps(line), file=stdout, flush=True)
+
+    for raw in stdin:
+        raw = raw.strip()
+        if not raw:
+            continue
+        try:
+            req = json.loads(raw)
+            tokens = np.asarray(req["tokens"], np.int64)
+            new = int(req.get("max_new_tokens", 16))
+        except (ValueError, KeyError, TypeError) as e:
+            print(json.dumps({"error": f"bad request: {e}"}), file=stdout,
+                  flush=True)
+            continue
+        rid, t0 = req.get("id"), time.perf_counter()
+        try:
+            fut = gen.submit(tokens, new, timeout_s=args.timeout_s)
+        except ShedError as e:
+            print(json.dumps({"id": rid, "error": str(e),
+                              "retry_after": e.retry_after_s}),
+                  file=stdout, flush=True)
+            continue
+        except ValueError as e:
+            print(json.dumps({"id": rid, "error": str(e)}), file=stdout,
+                  flush=True)
+            continue
+        pending.append((rid, fut, t0))
+        while len(pending) > 2 * gen.cache.slots:
+            emit(*pending.pop(0))
+    for item in pending:
+        emit(*item)
+
+
+def serve_generator(args) -> None:
+    """``-m <token model>``: the one model of this process, served by the
+    slot scheduler (``serve/generator.py``) over stdin-JSONL."""
+    from deepvision_tpu.serve.generator import load_generator
+    from deepvision_tpu.startup import mark_ready
+
+    if len(args.model) > 1 or args.artifact or args.pipelines \
+            or args.http is not None:
+        sys.exit("a token model is served alone, over stdin-JSONL "
+                 "(no --http, --artifact or --pipelines beside it)")
+    name, workdir = _parse_spec(args.model[0])
+    gen = load_generator(name, workdir, slots=args.slots,
+                         max_len=args.max_len,
+                         prefill_chunk=args.prefill_chunk,
+                         max_queue=args.max_queue)
+    mark_ready()
+    try:
+        run_generate(gen, args)
+    finally:
+        gen.close()
+        s = gen.stats()
+        # grep-stable exit line
+        print(f"[generate] prefill_tokens={s['gen_prefill_tokens']} "
+              f"decode_tokens={s['gen_decode_tokens']} "
+              f"decode_steps={s['gen_decode_steps']} "
+              f"decode_rows={s['gen_decode_rows']}",
+              file=sys.stderr, flush=True)
+
+
 # ----------------------------------------------------------------- HTTP
 
 
@@ -835,6 +920,14 @@ def main(argv=None):
     p.add_argument("--buckets", default="1,4,16,64",
                    help="batch bucket ladder (comma-separated)")
     p.add_argument("--max-queue", type=int, default=256)
+    p.add_argument("--slots", type=int, default=4,
+                   help="token models: requests held at once, one cache "
+                        "slot each")
+    p.add_argument("--max-len", type=int, default=3072,
+                   help="token models: positions a slot holds (prompt and "
+                        "generated tokens)")
+    p.add_argument("--prefill-chunk", type=int, default=512,
+                   help="token models: prompt tokens a prefill step runs")
     p.add_argument("--per-model-limit", type=int, default=None)
     p.add_argument("--batch-window-ms", type=float, default=2.0,
                    help="wait this long for a bucket to fill before "
@@ -943,6 +1036,12 @@ def main(argv=None):
     from deepvision_tpu.startup import init_runtime, mark_ready
 
     init_runtime()
+    from deepvision_tpu.serve.generator import is_generative
+
+    if args.model and any(is_generative(_parse_spec(m)[0])
+                          for m in args.model):
+        serve_generator(args)
+        return
     spool = _setup_obs(args, args.obs_role or "replica")
     # the profile starts before the model loads, so the start-up spans
     # (startup/load_model, startup/engine) land beside the warm-up's

@@ -502,3 +502,37 @@ def test_the_mhc_kernels_compile_for_v5e_at_the_cells_widths(
     names = (("mhc_pre_forward", "mhc_post_forward") if which == "forward"
              else ("mhc_post_backward", "mhc_pre_backward"))
     assert all(n in text for n in names)
+
+
+@pytest.mark.parametrize("which", ["prefill", "decode"])
+def test_the_generators_programs_fit_a_v5e_at_the_cells_shapes(
+        one_chip, no_compile_cache, which):
+    """``models/olmo_hybrid.py`` (its other tests: test_olmo_hybrid.py):
+    the olmo cell's prefill (a chunk of 2,048) and decode programs over
+    the stage preset's weights and a cache of 16 slots of 16,512
+    positions, donated: the chip's compiler takes both, with no copy of
+    the keys and values (a scan that wrote them did copy both, 8 GB)."""
+    import functools
+
+    from deepvision_tpu.models import olmo_hybrid as O
+
+    cfg = get_model("olmo_hybrid_pp4").config
+    shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    params = {g: {k: shape(s, F32 if k in O.FLOAT32_LEAVES else BF16)
+                  for k, s in leaves.items()}
+              for g, leaves in O.param_shapes(cfg).items()}
+    cache = {k: shape(s.shape, s.dtype)
+             for k, s in O.cache_shapes(cfg, 16, 16512).items()}
+    i32 = shape((), jnp.int32)
+    if which == "prefill":
+        fn, args = O.prefill, (i32, shape((2048,), jnp.int32), i32, i32)
+    else:
+        fn, args = O.decode, (shape((16,), jnp.int32),
+                              shape((16,), jnp.bool_))
+    compiled = jax.jit(functools.partial(fn, cfg=cfg),
+                       donate_argnums=(1,)).lower(params, cache,
+                                                  *args).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 3e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 15.75 * 2 ** 30

@@ -91,6 +91,11 @@ _EXTRA_SPECS: dict[str, ModelSpec] = {
     "dcgan_discriminator": ModelSpec((28, 28, 1)),
     "cyclegan_generator": ModelSpec((256, 256, 3), train_rngs=()),
     "cyclegan_discriminator": ModelSpec((256, 256, 3), train_rngs=()),
+    # served at their published widths, trained only as the CPU preset
+    # (olmo_hybrid_tiny): a short text as the preset's config has it
+    "olmo_hybrid": ModelSpec(None, extra_inputs={"tokens": ((65,), jnp.int32)}),
+    "olmo_hybrid_pp4": ModelSpec(
+        None, extra_inputs={"tokens": ((65,), jnp.int32)}),
 }
 
 # config names that exist for the CLI but are not registry entries

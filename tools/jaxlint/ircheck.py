@@ -658,6 +658,15 @@ def make_cases() -> dict[str, IRCase]:
         _token_build("xing4_tiny"),
         "text token model: latent attention on 4 hyper-connected streams "
         "+ MTP module, int32 tokens; no hbm baseline")
+    # likewise the three olmo_hybrid entries, served at their published
+    # widths (benchmark cell olmo_hybrid_7b.serve_longprompt): the CPU
+    # preset's dense next-token step lowers here
+    cases["olmo_hybrid_tiny"] = IRCase(
+        "olmo_hybrid_tiny",
+        ("olmo_hybrid_tiny", "olmo_hybrid_pp4", "olmo_hybrid"), 2,
+        _token_build("olmo_hybrid_tiny"),
+        "text token model: gated delta rule beside full attention, int32 "
+        "tokens; no hbm baseline")
     return cases
 
 

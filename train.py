@@ -429,7 +429,10 @@ def main():
                 "reader for them yet "
                 f"(this run: --data-dir {args.data_dir!r})")
         kind = cfg["dataset"]
-        step_fns = {"train_step": getattr(token_steps, f"{kind}_train_step"),
+        # the config's step family (a dense text model's is "dense_lm");
+        # the eval step is the dataset's
+        step_fns = {"train_step": getattr(
+                        token_steps, f"{cfg.get('steps', kind)}_train_step"),
                     "eval_step": getattr(token_steps, f"{kind}_eval_step")}
         n = args.synthetic_size
         if kind == "vlm":
